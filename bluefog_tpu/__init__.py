@@ -24,7 +24,6 @@ reference's per-process model.
 
 import jax as _jax
 
-from bluefog_tpu import compat as _compat  # install jax API shims first
 from bluefog_tpu.version import __version__
 from bluefog_tpu import topology
 from bluefog_tpu import topology as topology_util  # reference-style alias

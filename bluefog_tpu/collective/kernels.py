@@ -44,14 +44,25 @@ kernel-off is a bitwise pin, not a tolerance (asserted across the tier
 matrix in ``tests/test_wire_kernels.py``; the numpy oracle both paths
 pin against is :mod:`bluefog_tpu.collective.wire_ref`).
 
-Tiling: on TPU the kernels lower natively through Mosaic with one scale
-block per grid step (payload rows ``(1, 512)``/``(1, 256)``, scale
-cells ``(1, 1)``). Everywhere else they run under ``interpret=True``
-with a SINGLE whole-array block and no grid: the interpret lowering
-decomposes a grid into an XLA ``fori_loop`` whose carried output
-buffers are double-buffered full-width copies, which would *add*
-scratch instead of removing it — one block keeps the decomposition a
-straight-line fusion. The bodies are written rank-generically (axis-1
+Tiling: on TPU the kernels lower natively through Mosaic with
+``_ROWS`` scale blocks per grid step (payload blocks ``(_ROWS, 512)`` /
+``(_ROWS, 256)``, scale blocks ``(_ROWS, 1)``): Mosaic wants the last
+two block dims divisible by the dtype's tile (8x128 f32, 32x128 int8)
+or equal to the array's, which one-row blocks are not. Rows are
+independent, so the ragged last block needs no masking — its
+out-of-range rows read garbage and their writes are dropped. Scales
+cross the kernel boundary in f32 (the int4 wire's bf16 snap happens
+in-kernel; the cast to the bf16 sidecar outside is exact), and the
+nibble arithmetic runs in int32: Mosaic does not legalize int8 vector
+shifts on the v5e (``arith.shli`` on ``vector<..xi8>``, PR 21's chip
+probe). Everywhere else the SAME bodies run as
+plain XLA ops over one whole-array block (:class:`_Block`), not through
+``pallas_call(interpret=True)``: the Pallas interpreter evaluates an
+already-traced kernel jaxpr, which ``shard_map(check_vma=True)``
+rejects on jax 0.9 (no ``pvary`` is inserted between the varying blocks
+and the body's constants), and its grid loop would carry
+double-buffered full-width copies — *adding* the scratch the kernels
+exist to remove. The bodies are written rank-generically (axis-1
 keepdims reductions) so both tilings run the same arithmetic.
 
 One XLA:CPU quirk needs an explicit pin (:func:`_pin_wire_buffer`): the
@@ -67,11 +78,10 @@ the accumulate to READ the materialized wire buffer — exactly what the
 Mosaic custom-call boundary enforces for free on TPU. Bitwise
 identity: the taken branch returns the payload unchanged.
 
-Gating: ``BLUEFOG_WIRE_KERNELS`` = ``1``/``on`` (require Pallas, raise
-if unavailable), ``0``/``off`` (composite path), or ``auto`` (the
-default: on wherever Pallas imports). :func:`cache_token` joins every
-op/optimizer cache key whose program embeds a quantized wire, so
-toggling the flag can never dispatch a stale program.
+Gating: ``BLUEFOG_WIRE_KERNELS`` = ``0``/``off`` selects the composite
+path; anything else (the default) the kernels. :func:`cache_token`
+joins every op/optimizer cache key whose program embeds a quantized
+wire, so toggling the flag can never dispatch a stale program.
 """
 
 import os
@@ -81,13 +91,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pragma: no cover - exercised via wire_kernels_on()
-    from jax.experimental import pallas as pl
-except Exception:  # jaxlib built without Pallas
-    pl = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "pallas_available",
     "wire_kernels_on",
     "cache_token",
     "encode",
@@ -104,6 +111,11 @@ __all__ = [
 # composite quantizers share one scale grid.
 CHUNK = 512
 _HALF = CHUNK // 2
+# Scale blocks per native grid step: a multiple of every wire dtype's
+# sublane tile (32 for int8), and 512 KiB of f32 payload per block —
+# the widest kernel (decode_accumulate over several rounds) stays far
+# inside the default scoped-VMEM limit with double buffering.
+_ROWS = 256
 
 # Wire tiers with a packed integer payload a kernel can fuse. bf16 is a
 # pure dtype cast (nothing to fuse); the _ef spellings ride the same two
@@ -111,31 +123,14 @@ _HALF = CHUNK // 2
 _KERNEL_WIRES = ("int8", "int4", "int8_ef", "int4_ef")
 
 
-def pallas_available() -> bool:
-    """Whether this jaxlib ships ``jax.experimental.pallas``."""
-    return pl is not None
-
-
 def wire_kernels_on() -> bool:
-    """Resolve ``BLUEFOG_WIRE_KERNELS``: ``1``/``on``/``true`` forces the
-    kernels (raises if Pallas is unavailable — an explicit request must
-    not silently degrade), ``0``/``off``/``false`` forces the composite
-    path, anything else (the ``auto`` default) enables them wherever
-    Pallas imports. Read per call so tests can toggle per program; the
+    """Resolve ``BLUEFOG_WIRE_KERNELS``: ``0``/``off``/``false``/``no``
+    selects the composite path, anything else (the default) the fused
+    kernels. Read per call so tests can toggle per program; the
     :func:`cache_token` in every quantized cache key keeps toggles from
     dispatching stale programs."""
     raw = os.environ.get("BLUEFOG_WIRE_KERNELS", "auto").strip().lower()
-    if raw in ("0", "off", "false", "no"):
-        return False
-    if raw in ("1", "on", "true", "yes"):
-        if pl is None:
-            raise ImportError(
-                "BLUEFOG_WIRE_KERNELS=1 but jax.experimental.pallas is "
-                "not importable in this jaxlib; unset the flag (or set "
-                "it to 0/auto) to use the composite wire path."
-            )
-        return True
-    return pl is not None
+    return raw not in ("0", "off", "false", "no")
 
 
 def cache_token(wire: Optional[str]) -> tuple:
@@ -149,12 +144,26 @@ def cache_token(wire: Optional[str]) -> tuple:
     return ()
 
 
-def _interpret() -> bool:
-    """Native Mosaic lowering on TPU; interpret mode (the kernel body
-    decomposed to XLA ops over one whole-array block — see the module
-    docstring for why interpret mode must not grid) elsewhere, so every
-    backend runs the same kernel code path."""
-    return jax.default_backend() != "tpu"
+def _native() -> bool:
+    """Mosaic lowering on TPU; elsewhere the kernel bodies run as plain
+    XLA ops over one whole-array block (see the module docstring), so
+    every backend runs the same kernel arithmetic."""
+    return jax.default_backend() == "tpu"
+
+
+class _Block:
+    """Whole-array stand-in for a kernel ref off-TPU: loads index the
+    array, and the one store a body makes per output replaces it."""
+
+    def __init__(self, value=None):
+        self.value = value
+
+    def __getitem__(self, idx):
+        return self.value[idx]
+
+    def __setitem__(self, idx, value):
+        assert idx is Ellipsis, idx
+        self.value = value
 
 
 def pad_blocks(xf: jnp.ndarray) -> jnp.ndarray:
@@ -174,7 +183,7 @@ def unpad_blocks(x2: jnp.ndarray, n: int) -> jnp.ndarray:
 
 def _pin_wire_buffer(payload: jnp.ndarray, scales: jnp.ndarray):
     """Pin the sender's own wire buffer as a materialized READ on the
-    interpret path (no-op wrapper on TPU, where the Mosaic custom-call
+    XLA-ops path (no-op wrapper on TPU, where the Mosaic custom-call
     boundary already is one). The ``lax.cond`` predicate is
     data-dependent (scales are zero-guard-clipped strictly positive, so
     ``s[0] > -1`` always holds but cannot be constant-folded), the taken
@@ -184,7 +193,7 @@ def _pin_wire_buffer(payload: jnp.ndarray, scales: jnp.ndarray):
     chain from the f32 input and materializes its full-width ``divide``
     (16 KiB at payload 4096, the exact temporary this module removes;
     measured in BENCH_MODE=quant's kernel-vs-composite rows)."""
-    if not _interpret():
+    if _native():
         return payload
     pred = scales.reshape(-1)[0].astype(jnp.float32) > -1.0
     return lax.cond(pred, lambda: payload, lambda: jnp.zeros_like(payload))
@@ -193,81 +202,89 @@ def _pin_wire_buffer(payload: jnp.ndarray, scales: jnp.ndarray):
 # -- kernel bodies -------------------------------------------------------------
 #
 # Rank-generic: a block is ``(rows, CHUNK)`` payload-side (``(rows,
-# _HALF)`` packed) with ``(rows, 1)`` scale cells — ``rows`` is 1 per
-# grid step native, n_chunks on the gridless interpret path. The
+# _HALF)`` packed) with ``(rows, 1)`` f32 scale cells — ``rows`` is
+# ``_ROWS`` per grid step native, n_chunks on the XLA-ops path. The
 # arithmetic is copied from the composite quantizers op for op — the
-# bitwise kernel-on == kernel-off pin depends on it.
+# bitwise kernel-on == kernel-off pin depends on it. Quantized values
+# stay int32 until the store (and widen to int32 right after the load):
+# the same values as the composite's int8 ops.
 
 
 def _quant8(x):
-    """``(rows, CHUNK)`` f32 -> (int8 q, ``(rows, 1)`` f32 scale);
-    mirrors inner._chunk_quantize's per-row arithmetic."""
+    """``(rows, CHUNK)`` f32 -> (int32 q in [-127, 127], ``(rows, 1)``
+    f32 scale); mirrors inner._chunk_quantize's per-row arithmetic."""
     s = jnp.maximum(
         jnp.max(jnp.abs(x), axis=1, keepdims=True),
         jnp.finfo(jnp.float32).tiny,
     ) / 127.0
-    q = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int32)
     return q, s
 
 
 def _quant4(x):
-    """``(rows, CHUNK)`` f32 -> (int8 q in [-7, 7], ``(rows, 1)`` bf16
-    scale, widened f32 scale); mirrors inner._chunk_quantize4: the scale
-    snaps to bf16 FIRST and the quantize divides by the widened bf16
-    value, so sender and every receiver reconstruct identical bits."""
+    """``(rows, CHUNK)`` f32 -> (int32 q in [-7, 7], ``(rows, 1)`` scale
+    snapped to bf16 and widened back to f32); mirrors
+    inner._chunk_quantize4: the scale snaps to bf16 FIRST and the
+    quantize divides by the widened bf16 value, so sender and every
+    receiver reconstruct identical bits."""
     s = jnp.maximum(
         jnp.max(jnp.abs(x), axis=1, keepdims=True),
         jnp.finfo(jnp.float32).tiny,
     ) / 7.0
-    s16 = s.astype(jnp.bfloat16)
-    sw = s16.astype(jnp.float32)
-    q = jnp.clip(jnp.round(x / sw), -7, 7).astype(jnp.int8)
-    return q, s16, sw
+    sw = s.astype(jnp.bfloat16).astype(jnp.float32)
+    q = jnp.clip(jnp.round(x / sw), -7, 7).astype(jnp.int32)
+    return q, sw
 
 
 def _pack(q):
-    """``(rows, CHUNK)`` int4 values (int8 storage) -> ``(rows, _HALF)``
-    packed lanes: element ``k`` low nibble of lane ``k``, element
+    """``(rows, CHUNK)`` int32 values in [-8, 7] -> ``(rows, _HALF)``
+    packed int8 lanes: element ``k`` low nibble of lane ``k``, element
     ``_HALF + k`` the high nibble (the composite deinterleaved-halves
-    layout of inner._pack_nibbles)."""
-    lo = q[:, :_HALF] & jnp.int8(0x0F)
-    hi = jnp.left_shift(q[:, _HALF:], 4)
-    return lo | hi
+    layout of inner._pack_nibbles). The last step folds the unsigned
+    byte into int8's range so the narrowing cast is exact."""
+    byte = (q[:, :_HALF] & 0x0F) | ((q[:, _HALF:] & 0x0F) << 4)
+    return (byte - ((byte & 0x80) << 1)).astype(jnp.int8)
 
 
 def _unpack(p):
-    """Inverse of :func:`_pack`; the same arithmetic-shift sign
-    extension and two-piece concat as inner._unpack_nibbles (NOT the
-    rejected even/odd stack+reshape — tests/test_wire_kernels.py pins
-    both decoders lane-exhaustively over all 256 int8 values)."""
-    lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
-    hi = jnp.right_shift(p, 4)
-    return jnp.concatenate([lo, hi], axis=1)
+    """Inverse of :func:`_pack`, to ``(rows, CHUNK)`` int32 in [-8, 7]:
+    the packed lanes doubled side by side, then ONE per-lane shift pair
+    — the left half keeps the low nibble (``<< 28 >> 28``), the right
+    half the high one (``<< 24 >> 28``); the arithmetic right shift
+    sign-extends exactly as inner._unpack_nibbles does
+    (tests/test_wire_kernels.py pins both decoders lane-exhaustively
+    over all 256 int8 values). Doubling the int8 lanes BEFORE widening
+    keeps the one concatenate a byte per element, so the decode fuses
+    into its consumer instead of staging a full-width int32 block."""
+    p2 = jnp.concatenate([p, p], axis=1).astype(jnp.int32)
+    lane = lax.broadcasted_iota(jnp.int32, p2.shape, 1)
+    up = jnp.where(lane < _HALF, 28, 24)
+    return jnp.right_shift(jnp.left_shift(p2, up), 28)
 
 
 def _deq(payload, scales, packed):
-    """f32 reconstruction of one (payload, scales) block pair; the
+    """f32 reconstruction of one (payload, f32 scales) block pair; the
     composite _dequant8/_dequant4 arithmetic (every step exact in f32,
     so fusion order cannot perturb it)."""
-    q = (_unpack(payload) if packed else payload).astype(jnp.float32)
-    return q * scales.astype(jnp.float32)
+    q = _unpack(payload) if packed else payload.astype(jnp.int32)
+    return q.astype(jnp.float32) * scales
 
 
 def _encode8_body(x_ref, q_ref, s_ref):
     q, s = _quant8(x_ref[...])
-    q_ref[...] = q
+    q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = s
 
 
 def _encode4_body(x_ref, p_ref, s_ref):
-    q, s16, _sw = _quant4(x_ref[...])
+    q, sw = _quant4(x_ref[...])
     p_ref[...] = _pack(q)
-    s_ref[...] = s16
+    s_ref[...] = sw
 
 
 def _encode_diff8_body(x_ref, h_ref, q_ref, s_ref, o_ref):
     q, s = _quant8(x_ref[...] - h_ref[...])
-    q_ref[...] = q
+    q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = s
     # the sender-side copy integration h + Q(x - h): q pre-pack is
     # exactly what unpack(pack(q)) reconstructs (values in range), so
@@ -276,9 +293,9 @@ def _encode_diff8_body(x_ref, h_ref, q_ref, s_ref, o_ref):
 
 
 def _encode_diff4_body(x_ref, h_ref, p_ref, s_ref, o_ref):
-    q, s16, sw = _quant4(x_ref[...] - h_ref[...])
+    q, sw = _quant4(x_ref[...] - h_ref[...])
     p_ref[...] = _pack(q)
-    s_ref[...] = s16
+    s_ref[...] = sw
     o_ref[...] = h_ref[...] + q.astype(jnp.float32) * sw
 
 
@@ -308,11 +325,10 @@ def _make_dacc_body(n_rounds, packed, wdt):
         out_ref = refs[-1]
         deq_s = _deq(qs_ref[...], ss_ref[...], packed).astype(wdt)
         acc = acc_ref[...]
-        w = w_ref[...]
         for r in range(n_rounds):
             qr_ref, sr_ref = refs[4 + 2 * r], refs[5 + 2 * r]
             deq_r = _deq(qr_ref[...], sr_ref[...], packed).astype(wdt)
-            acc = acc + (deq_r - deq_s) * w[r, 0].astype(wdt)
+            acc = acc + (deq_r - deq_s) * w_ref[r, 0].astype(wdt)
         out_ref[...] = acc
 
     return body
@@ -330,44 +346,51 @@ def _payload_width(wire: str) -> int:
 
 
 def _scale_dtype(wire: str):
+    """The scale sidecar's dtype ON THE WIRE (inside the kernels scales
+    are always f32; the int4 values are bf16-exact)."""
     return jnp.bfloat16 if _is_packed(wire) else jnp.float32
+
+
+def _scale_cells(scales: jnp.ndarray) -> jnp.ndarray:
+    """Wire scales ``[n_chunks]`` -> the kernels' ``(n_chunks, 1)`` f32
+    cells (widening bf16 is exact)."""
+    return scales.astype(jnp.float32).reshape(-1, 1)
 
 
 def _call(body, operands, widths, out_widths, out_dtypes, n_chunks,
           aliases=None):
-    """Dispatch one kernel: native TPU grids one scale block per step
-    (width 0 marks a broadcast operand, e.g. the weight vector); the
-    interpret path runs ONE whole-array block (no grid — see module
-    docstring)."""
+    """Dispatch one kernel: native TPU grids ``_ROWS`` scale blocks per
+    step (width 0 marks the weight vector, read as scalars from SMEM);
+    elsewhere the body runs once over whole-array blocks."""
+    if not _native():
+        outs = tuple(_Block() for _ in out_widths)
+        body(*(_Block(op) for op in operands), *outs)
+        return tuple(o.value for o in outs)
+    # inside shard_map the outputs vary over the mesh axes the operands
+    # do; check_vma=True rejects an out_shape that does not say so
+    vma = frozenset().union(*(jax.typeof(op).vma for op in operands))
     out_shape = tuple(
-        jax.ShapeDtypeStruct((n_chunks, w), dt)
+        jax.ShapeDtypeStruct((n_chunks, w), dt, vma=vma)
         for w, dt in zip(out_widths, out_dtypes)
     )
-    kwargs = {}
-    if aliases:
-        kwargs["input_output_aliases"] = aliases
-    if _interpret():
-        out = pl.pallas_call(
-            body, out_shape=out_shape, interpret=True, **kwargs
-        )(*operands)
-    else:  # pragma: no cover - TPU-only lowering
-        in_specs = [
-            pl.BlockSpec(op.shape, lambda i: (0, 0)) if w == 0
-            else pl.BlockSpec((1, w), lambda i: (i, 0))
-            for op, w in zip(operands, widths)
-        ]
-        out_specs = tuple(
-            pl.BlockSpec((1, w), lambda i: (i, 0)) for w in out_widths
-        )
-        out = pl.pallas_call(
-            body,
-            grid=(n_chunks,),
-            in_specs=in_specs,
-            out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-            out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-            **kwargs,
-        )(*operands)
-    return out if isinstance(out, (tuple, list)) else (out,)
+    # a block spanning the whole array is legal at any row count; a
+    # partial one must be tile-aligned, which _ROWS is for every dtype
+    rows = min(_ROWS, n_chunks)
+
+    def block(w):
+        return pl.BlockSpec((rows, w), lambda i: (i, 0))
+
+    return pl.pallas_call(
+        body,
+        grid=(pl.cdiv(n_chunks, rows),),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM) if w == 0 else block(w)
+            for w in widths
+        ],
+        out_specs=tuple(block(w) for w in out_widths),
+        out_shape=out_shape,
+        input_output_aliases=aliases or {},
+    )(*operands)
 
 
 def encode(xf: jnp.ndarray, wire: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -384,11 +407,11 @@ def encode(xf: jnp.ndarray, wire: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
     w = _payload_width(wire)
     body = _encode4_body if w == _HALF else _encode8_body
     payload, s = _call(
-        body, (x2,), (CHUNK,), (w, 1), (jnp.int8, _scale_dtype(wire)),
-        n_chunks,
+        body, (x2,), (CHUNK,), (w, 1), (jnp.int8, jnp.float32), n_chunks,
     )
-    payload, s = lax.optimization_barrier((payload, s))
-    return payload, s.reshape(n_chunks)
+    return lax.optimization_barrier(
+        (payload, s.reshape(n_chunks).astype(_scale_dtype(wire)))
+    )
 
 
 def encode_diff(
@@ -406,10 +429,12 @@ def encode_diff(
     body = _encode_diff4_body if w == _HALF else _encode_diff8_body
     payload, s, h_new = _call(
         body, (x2, h2), (CHUNK, CHUNK), (w, 1, CHUNK),
-        (jnp.int8, _scale_dtype(wire), jnp.float32), n_chunks,
+        (jnp.int8, jnp.float32, jnp.float32), n_chunks,
     )
-    payload, s = lax.optimization_barrier((payload, s))
-    return payload, s.reshape(n_chunks), unpad_blocks(h_new, xf.size)
+    payload, s = lax.optimization_barrier(
+        (payload, s.reshape(n_chunks).astype(_scale_dtype(wire)))
+    )
+    return payload, s, unpad_blocks(h_new, xf.size)
 
 
 def decode(
@@ -422,7 +447,7 @@ def decode(
     pw = payload.shape[1]
     (out,) = _call(
         _make_decode_body(pw == _HALF),
-        (payload, scales.reshape(n_chunks, 1)), (pw, 1), (CHUNK,),
+        (payload, _scale_cells(scales)), (pw, 1), (CHUNK,),
         (jnp.float32,), n_chunks,
     )
     return unpad_blocks(out, n)
@@ -440,7 +465,7 @@ def decode_add(
     pw = payload.shape[1]
     (out,) = _call(
         _make_decode_add_body(pw == _HALF),
-        (b2, payload, scales.reshape(n_chunks, 1)), (CHUNK, pw, 1),
+        (b2, payload, _scale_cells(scales)), (CHUNK, pw, 1),
         (CHUNK,), (jnp.float32,), n_chunks, aliases={0: 0},
     )
     return unpad_blocks(out, n)
@@ -473,12 +498,11 @@ def decode_accumulate(
     pw = payload.shape[1]
     wvec = jnp.asarray(weights).reshape(len(rounds), 1)
     operands = [
-        wvec, x2, _pin_wire_buffer(payload, scales),
-        scales.reshape(n_chunks, 1),
+        wvec, x2, _pin_wire_buffer(payload, scales), _scale_cells(scales),
     ]
     widths = [0, CHUNK, pw, 1]
     for rq, rs in rounds:
-        operands += [rq, rs.reshape(n_chunks, 1)]
+        operands += [rq, _scale_cells(rs)]
         widths += [pw, 1]
     (out,) = _call(
         _make_dacc_body(len(rounds), pw == _HALF, wdt),

@@ -103,10 +103,9 @@ def default_nodes_per_machine(
 def _resolve_devices(requested: Optional[int]) -> List:
     """Device list honoring BLUEFOG_NUM_WORKERS (set by bfrun-tpu -np).
 
-    Falls back to the virtual CPU platform when the ambient platform has
-    fewer devices than requested (the launcher already raised the CPU
-    device count in XLA_FLAGS); pins the default device to CPU in that
-    case so eager ops cannot land on a different backend than the mesh.
+    Always the default backend's devices: a backend with fewer devices
+    than requested is an error, never a switch to another platform (CPU
+    is chosen from outside, with ``JAX_PLATFORMS=cpu``).
     """
     devices = jax.devices()
     if requested is None:
@@ -124,16 +123,34 @@ def _resolve_devices(requested: Optional[int]) -> List:
             )
         return list(devices)
     if len(devices) < requested:
-        devices = jax.devices("cpu")
-        if devices and len(devices) >= requested:
-            jax.config.update("jax_default_device", devices[0])
-    if len(devices) < requested:
         raise RuntimeError(
-            f"BLUEFOG_NUM_WORKERS={requested} but only {len(devices)} "
-            "devices exist; launch through bfrun-tpu or set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={requested}"
+            f"BLUEFOG_NUM_WORKERS={requested} but the "
+            f"{jax.default_backend()!r} backend has only {len(devices)} "
+            "devices; for a virtual CPU mesh set JAX_PLATFORMS=cpu and "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={requested}"
+            " (bfrun-tpu --platform cpu does both)"
         )
     return list(devices[:requested])
+
+
+# Fixed, so that a later process finds what an earlier one compiled: a
+# cache at a path that moves (temp name, pid, timestamp) never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins and then nothing is set in code
+    (jax reads the variable itself); otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR` inside the checkout."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 _ctx_uid = itertools.count()
@@ -355,6 +372,7 @@ def init(
     """
     global _context
     maybe_init_distributed()
+    configure_compile_cache()
     # An elastic session is bound to one context's membership; a re-init
     # must not leave it pointing at the torn-down mesh.
     from bluefog_tpu import elastic as _elastic

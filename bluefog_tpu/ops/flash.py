@@ -21,8 +21,8 @@ emits the per-row logsumexp) with FlashAttention-2-style backward kernels
 probabilities from Q/K and the saved logsumexp instead of materializing
 the T×T matrix).
 
-``flash_attention`` falls back to the dense XLA path off-TPU or for
-cross-attention (mismatched Q/KV shapes), so callers can use it
+``flash_attention`` lowers to the dense XLA path on non-TPU platforms
+and for cross-attention (mismatched Q/KV shapes), so callers can use it
 unconditionally. ``interpret=True`` runs the kernels in the Pallas
 interpreter (CPU CI).
 """
@@ -35,13 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from bluefog_tpu import compat
-
-try:  # pltpu is importable on CPU builds too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "flash_attention",
@@ -150,7 +144,7 @@ def _vma(x):
     # inside shard_map the outputs vary over the same mesh axes as the
     # inputs; pallas out_shapes must carry that or the vma check rejects
     # the trace (platform_dependent traces the kernel branch everywhere)
-    return getattr(jax.typeof(x), "vma", frozenset())
+    return jax.typeof(x).vma
 
 
 def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
@@ -172,8 +166,8 @@ def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
         out_shape=(
-            compat.shape_dtype_struct((bh, t_pad, d_pad), out_dtype, vma=vma),
-            compat.shape_dtype_struct((bh, t_pad, _SUB), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_pad, d_pad), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_pad, _SUB), jnp.float32, vma=vma),
         ),
         grid=grid,
         in_specs=[
@@ -346,8 +340,8 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
         out_shape=(
-            compat.shape_dtype_struct((bh_kv, t_pad, d_pad), kf.dtype, vma=vma),
-            compat.shape_dtype_struct((bh_kv, t_pad, d_pad), vf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), kf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), vf.dtype, vma=vma),
         ),
         grid=(bh_kv, t_pad // block_k, group * n_q),
         in_specs=[q_gqa, k_spec, k_spec, q_gqa, r_gqa, r_gqa, r_gqa],
@@ -371,8 +365,7 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
             _bwd_dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
-        out_shape=compat.shape_dtype_struct((bh, t_pad, d_pad), qf.dtype,
-                                       vma=vma),
+        out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), qf.dtype, vma=vma),
         grid=(bh, t_pad // block_q, t_pad // block_k),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2,
                   r_spec2],
@@ -521,17 +514,11 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     otherwise (selected per lowering platform)."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
-    if pltpu is None or not flash_attention_supported(q, k, v):
+    if not flash_attention_supported(q, k, v):
         return _dense_with_lse(q, k, v, causal, scale)
     if interpret:
         return _flash_with_lse(q, k, v, causal, float(scale), block_q,
                                block_k, True)
-    if not compat.PLATFORM_DEPENDENT_PRUNES:
-        # old jax lowers dead platform branches too (see flash_attention)
-        if jax.default_backend() == "tpu":
-            return _flash_with_lse(q, k, v, causal, float(scale), block_q,
-                                   block_k, False)
-        return _dense_with_lse(q, k, v, causal, scale)
     return jax.lax.platform_dependent(
         q, k, v,
         tpu=lambda q, k, v: _flash_with_lse(
@@ -637,28 +624,16 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = 1.0 / np.sqrt(q.shape[-1])
     from bluefog_tpu.ops.attention import reference_attention
 
-    if pltpu is None or not flash_attention_supported(
+    if not flash_attention_supported(
         q, k, v, block_q=block_q, block_k=block_k
     ):
         return reference_attention(q, k, v, causal=causal, scale=scale)
     if interpret:
         return _flash(q, k, v, causal, float(scale), block_q, block_k,
                       True)
-    # The kernel-vs-dense choice must follow the platform the computation
-    # actually LOWERS for, not the default backend: a CPU mesh inside a
-    # TPU-ambient process (the dev/test pattern) would otherwise try to
-    # lower the Mosaic kernel for CPU. platform_dependent resolves at
-    # lowering time, per backend — but only on a jax that prunes dead
-    # branches there; older versions lower every branch, so the choice
-    # degrades to the host-side default backend.
-    if not compat.PLATFORM_DEPENDENT_PRUNES:
-        if jax.default_backend() == "tpu":
-            return _flash(
-                q, k, v, causal, float(scale), block_q, block_k, False
-            )
-        return reference_attention(
-            q, k, v, causal=causal, scale=scale
-        ).astype(q.dtype)
+    # The kernel-vs-dense choice follows the platform the computation
+    # actually LOWERS for, not the default backend: platform_dependent
+    # resolves at lowering time, per backend, and prunes the dead branch.
     return jax.lax.platform_dependent(
         q, k, v,
         tpu=lambda q, k, v: _flash(

@@ -8,8 +8,9 @@ hosts) and process bring-up is one process per host handing control to
 ``jax.distributed.initialize`` — so this launcher:
 
 - single host, ``-np N``: prepares an environment in which exactly N
-  worker devices exist (the real chips, or a forced N-device virtual CPU
-  platform for development) and execs the command;
+  worker devices are used (the first N real chips, or with ``--platform
+  cpu`` a forced N-device virtual CPU platform for development) and
+  execs the command;
 - multi host (``-H``/``--hostfile``): starts one process per host over
   ssh, each with ``BLUEFOG_COORDINATOR/NUM_PROCESSES/PROCESS_ID`` set;
   :func:`bluefog_tpu.context.init` picks these up and calls
@@ -76,8 +77,9 @@ def parse_args(argv: Sequence[str] = None) -> argparse.Namespace:
         "--platform", action="store", dest="platform", default="auto",
         choices=("auto", "cpu", "tpu"),
         help="Backend for the workers. 'cpu' forces an -np-device virtual "
-        "CPU platform (development mode); 'auto' uses the real chips and "
-        "falls back to virtual CPU when fewer than -np exist.",
+        "CPU platform (development mode) and 'tpu' the chips; 'auto' "
+        "leaves JAX's own choice (JAX_PLATFORMS) alone. Fewer than -np "
+        "devices on the chosen backend is an error.",
     )
 
     group_hosts = parser.add_mutually_exclusive_group()
@@ -171,6 +173,10 @@ def _parse_extra_env(pairs: Sequence[str]) -> Dict[str, str]:
     return out
 
 
+def _cpu_platform(env: Dict[str, str]) -> bool:
+    return env.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
 def build_child_env(
     args, base_env: Dict[str, str], cpu_count: int = None
 ) -> Dict[str, str]:
@@ -183,11 +189,11 @@ def build_child_env(
     """
     env = dict(base_env)
     env["BLUEFOG_NUM_WORKERS"] = str(args.np)
-    if args.platform == "cpu":
-        env["JAX_PLATFORMS"] = "cpu"
-    if args.platform in ("auto", "cpu"):
-        # Make the virtual CPU platform available; on a healthy TPU host
-        # in 'auto' mode the flag is inert (it only affects CPU). 0 means
+    if args.platform != "auto":
+        env["JAX_PLATFORMS"] = args.platform
+    if _cpu_platform(env):
+        # The virtual CPU devices exist only where CPU was chosen — by
+        # --platform cpu or by an inherited JAX_PLATFORMS=cpu. 0 means
         # the caller sets a per-host count itself.
         count = args.np if cpu_count is None else cpu_count
         if count > 0:
@@ -344,7 +350,7 @@ def build_host_commands(
     commands = []
     for i, hs in enumerate(hosts):
         proc_env = dict(env)
-        if args.platform in ("auto", "cpu"):
+        if _cpu_platform(proc_env):
             # Each controller exposes EXACTLY its own host's worker
             # devices; an inherited larger count would break the pod-wide
             # device-count invariant checked by context._resolve_devices.

@@ -543,8 +543,8 @@ def weak_scaling_times(
 
     ``make_step(mesh)`` returns ``(fn, args)`` where ``fn(*args)`` runs one
     step and returns outputs whose first leaf is safe to read back (the
-    readback is the synchronization point — ``block_until_ready`` can be a
-    no-op on remote-tunneled platforms). Per-worker work must be constant
+    readback is the synchronization point of
+    :func:`bluefog_tpu.timing.timed_differenced`). Per-worker work must be constant
     across ``ns`` (weak scaling), so ``efficiency = t[0] / t[n]``.
     """
     from bluefog_tpu.timing import timed_differenced

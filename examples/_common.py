@@ -1,10 +1,11 @@
 # Copyright 2026. Licensed under the Apache License, Version 2.0.
-"""Shared example bootstrap: build a multi-worker device list.
+"""Shared example bootstrap: the device list an example runs on.
 
 The reference examples run under ``bfrun -np N`` (one MPI process per
-worker); here a single controller drives N mesh devices. On a machine
-without a multi-chip TPU the examples force an N-device virtual CPU
-platform — the same trick the test harness uses (tests/conftest.py).
+worker); here a single controller drives N mesh devices: every chip of
+an accelerator backend, or — with ``JAX_PLATFORMS=cpu`` — an N-device
+virtual CPU platform, the same trick the test harness uses
+(tests/conftest.py).
 
 Import and call :func:`setup_devices` BEFORE importing jax elsewhere.
 """
@@ -17,30 +18,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def setup_devices(default: int = 8):
-    """Return a list of >= 2 devices, forcing virtual CPU devices if the
-    ambient platform exposes fewer. Honors BLUEFOG_EXAMPLE_DEVICES.
-
-    When falling back to CPU, the JAX default device is pinned to CPU as
-    well so later *eager* ops can never touch a broken/mismatched ambient
-    accelerator backend (VERDICT r2 item 1)."""
+    """The default backend's devices: all the chips there are on an
+    accelerator, ``BLUEFOG_EXAMPLE_DEVICES`` (default 8) virtual devices
+    on CPU. The platform is JAX's choice (``JAX_PLATFORMS``), never
+    changed here."""
     n = int(os.environ.get("BLUEFOG_EXAMPLE_DEVICES", default))
     from bluefog_tpu.platforms import ensure_cpu_device_count
 
-    ensure_cpu_device_count(n)
+    ensure_cpu_device_count(n)  # read by the CPU backend only
     import jax
 
-    try:
-        devices = jax.devices()
-        if len(devices) >= n and devices[0].platform != "cpu":
-            # Backend init succeeding is not enough: MULTICHIP_r02's libtpu
-            # mismatch surfaced only on the first op. Probe op-time health.
-            import jax.numpy as jnp
-
-            (jnp.zeros(()) + 1).block_until_ready()
-            return devices[:n]
-    except Exception:
-        pass  # ambient backend unusable; CPU fallback below
-    devices = jax.devices("cpu")
+    devices = jax.devices()
+    if devices[0].platform != "cpu":
+        return devices
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} CPU devices, have {len(devices)}; the CPU backend "
@@ -48,6 +38,4 @@ def setup_devices(default: int = 8):
             f"--xla_force_host_platform_device_count={n} — call "
             "setup_devices() before any jax operation"
         )
-    devices = devices[:n]
-    jax.config.update("jax_default_device", devices[0])
-    return devices
+    return devices[:n]

@@ -99,8 +99,24 @@ def test_child_env_auto_keeps_platform_and_ambient_env_intact():
     env = build_child_env(args, base_env={"PATH": "/bin"})
     assert "JAX_PLATFORMS" not in env
     assert env["PATH"] == "/bin"
-    assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+    # no virtual CPU devices are prepared behind the chosen backend's
+    # back: a short chip count must surface as bf.init()'s error
+    assert "XLA_FLAGS" not in env
     assert os.environ.get("XLA_FLAGS") == before  # launcher env untouched
+
+
+def test_child_env_auto_inherits_cpu_choice_from_outside():
+    args = parse_args(["-np", "4", "x.py"])
+    env = build_child_env(args, base_env={"JAX_PLATFORMS": "cpu"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+
+
+def test_child_env_tpu_mode_pins_the_backend():
+    args = parse_args(["-np", "4", "--platform", "tpu", "x.py"])
+    env = build_child_env(args, base_env={"JAX_PLATFORMS": "cpu"})
+    assert env["JAX_PLATFORMS"] == "tpu"
+    assert "XLA_FLAGS" not in env
 
 
 def test_child_env_timeline_and_extra():
@@ -205,6 +221,45 @@ def test_default_nodes_per_machine():
     devs = [FakeDev(pi, i) for i, pi in enumerate([0, 0, 0, 1, 1, 1])]
     assert default_nodes_per_machine(devs, process_count=2) == 3
     assert default_nodes_per_machine(devs, process_count=1) is None
+
+
+def test_resolve_devices_raises_instead_of_switching_backend():
+    """A backend with fewer devices than BLUEFOG_NUM_WORKERS is an error:
+    no other platform is looked at and no default device is re-pinned."""
+    import jax
+
+    from bluefog_tpu.context import _resolve_devices
+
+    devices = jax.devices()
+    assert _resolve_devices(None) == list(devices)
+    assert _resolve_devices(2) == list(devices[:2])
+    with pytest.raises(RuntimeError, match="backend has only"):
+        _resolve_devices(len(devices) + 1)
+    assert jax.config.jax_default_device is None
+
+
+def test_compile_cache_obeys_env_else_fixed_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is there and nothing is
+    set in code; unset: one fixed, git-ignored path in the checkout."""
+    import jax
+
+    from bluefog_tpu import context as ctx
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert ctx.configure_compile_cache() == "/placed/outside"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert ctx.configure_compile_cache() == ctx.COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            REPO, ".jax_cache"
+        )
+        with open(os.path.join(REPO, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_maybe_init_distributed(monkeypatch):

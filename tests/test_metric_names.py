@@ -1,6 +1,6 @@
 # Copyright 2026. Licensed under the Apache License, Version 2.0.
-"""Metric-name drift guard (the tests/test_doc_claims.py discipline
-applied to series names): every ``bluefog.*`` series emitted anywhere
+"""Metric-name drift guard (docs parsed and machine-checked against
+the code, applied to series names): every ``bluefog.*`` series emitted anywhere
 in ``bluefog_tpu/`` must appear in the docs/metrics.md series-reference
 table, and every table row must correspond to a name the code can
 actually emit. A dashboard built from the docs must never silently

@@ -29,17 +29,9 @@ from bluefog_tpu import topology as tu
 from bluefog_tpu.collective import inner, plan as planlib, wire_ref
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from conftest import require_pallas
-
 pytestmark = pytest.mark.wire_kernels
 
 SIZE = 8
-
-
-@pytest.fixture(autouse=True)
-def pallas_or_skip():
-    require_pallas()
-    from bluefog_tpu.collective import kernels  # noqa: F401 (import proof)
 
 
 @pytest.fixture(autouse=True)
@@ -162,9 +154,8 @@ def test_nibble_decoders_agree_on_all_256_bytes(monkeypatch):
 
 def test_cache_token_semantics(monkeypatch):
     """Kernel-off keys must be byte-identical to pre-kernel keys (empty
-    token), the token only rides quantized-integer tiers, and forcing
-    the kernels on a Pallas-less jaxlib is a loud error path (here:
-    forcing on succeeds, since the suite skipped if Pallas is absent)."""
+    token), the token only rides quantized-integer tiers, and the
+    kernels are the default."""
     k = _kernels()
     monkeypatch.setenv("BLUEFOG_WIRE_KERNELS", "0")
     assert not k.wire_kernels_on()
@@ -176,7 +167,7 @@ def test_cache_token_semantics(monkeypatch):
     for wire in (None, "bf16", "fp32"):
         assert k.cache_token(wire) == ()
     monkeypatch.delenv("BLUEFOG_WIRE_KERNELS")
-    assert k.wire_kernels_on() == k.pallas_available()
+    assert k.wire_kernels_on()
 
 
 # -- the bitwise kernel-on == kernel-off matrix ---------------------------------

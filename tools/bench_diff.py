@@ -2,8 +2,7 @@
 """Pair two bench evidence artifacts and attribute their deltas.
 
 The perf-attribution harness of ROADMAP item 1: round-over-round bench
-movements (the r04 -> r05 ResNet50 headline drop, 2798.8 -> 2510.5
-img/s/chip) are only meaningful when the artifacts are *comparable* —
+movements are only meaningful when the artifacts are *comparable* —
 same jax/jaxlib, same CPU, same timing method — and the delta clears the
 run's own disclosed noise floor. This tool mechanizes that judgment:
 
@@ -31,7 +30,7 @@ future artifact pairs stay machine-comparable by default.
 
 Usage::
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json [--json]
+    python tools/bench_diff.py OLD.json NEW.json [--json]
         [--check] [--note "..."] [--out report.json]
 """
 
