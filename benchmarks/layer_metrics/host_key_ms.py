@@ -1,0 +1,18 @@
+"""host_key_ms — layer: optimizer_path (``optimizers.py``
+``make_train_step.train_step``); unit ms; moves ``throughput_per_chip``
+where the host is the limit; every cell. Mean time of the ``key`` phase of a
+``train_step`` call over the untraced window's calls (sum / calls:
+throughput follows the mean, and the phases add up to
+``host_train_step_mean_ms`` less the epilogue): ``_aval_key`` over every
+leaf and the ``op_cache`` lookup (on a miss, building the step). Read from
+the program's flight ring (``step_key`` to ``step_begin``; the span
+``bf.train_step/key`` in an open profiler session), through
+``harness/scopes.py``; ``None`` off the chip or from a program without the
+phases."""
+
+from benchmarks.harness import scopes
+
+
+def read(run):
+    stats = scopes.host_phases(run)
+    return stats and stats["key"]

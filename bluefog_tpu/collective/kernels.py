@@ -357,11 +357,13 @@ def _scale_cells(scales: jnp.ndarray) -> jnp.ndarray:
     return scales.astype(jnp.float32).reshape(-1, 1)
 
 
-def _call(body, operands, widths, out_widths, out_dtypes, n_chunks,
+def _call(what, body, operands, widths, out_widths, out_dtypes, n_chunks,
           aliases=None):
     """Dispatch one kernel: native TPU grids ``_ROWS`` scale blocks per
     step (width 0 marks the weight vector, read as scalars from SMEM);
-    elsewhere the body runs once over whole-array blocks."""
+    elsewhere the body runs once over whole-array blocks. ``what`` names
+    the Mosaic call ``bf_wire_<what>``, which is how a device trace tells
+    the wire kernels from each other and from flash."""
     if not _native():
         outs = tuple(_Block() for _ in out_widths)
         body(*(_Block(op) for op in operands), *outs)
@@ -390,6 +392,7 @@ def _call(body, operands, widths, out_widths, out_dtypes, n_chunks,
         out_specs=tuple(block(w) for w in out_widths),
         out_shape=out_shape,
         input_output_aliases=aliases or {},
+        name=f"bf_wire_{what}",
     )(*operands)
 
 
@@ -407,7 +410,7 @@ def encode(xf: jnp.ndarray, wire: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
     w = _payload_width(wire)
     body = _encode4_body if w == _HALF else _encode8_body
     payload, s = _call(
-        body, (x2,), (CHUNK,), (w, 1), (jnp.int8, jnp.float32), n_chunks,
+        "encode", body, (x2,), (CHUNK,), (w, 1), (jnp.int8, jnp.float32), n_chunks,
     )
     return lax.optimization_barrier(
         (payload, s.reshape(n_chunks).astype(_scale_dtype(wire)))
@@ -428,7 +431,7 @@ def encode_diff(
     w = _payload_width(wire)
     body = _encode_diff4_body if w == _HALF else _encode_diff8_body
     payload, s, h_new = _call(
-        body, (x2, h2), (CHUNK, CHUNK), (w, 1, CHUNK),
+        "encode_diff", body, (x2, h2), (CHUNK, CHUNK), (w, 1, CHUNK),
         (jnp.int8, jnp.float32, jnp.float32), n_chunks,
     )
     payload, s = lax.optimization_barrier(
@@ -446,7 +449,7 @@ def decode(
     n_chunks = payload.shape[0]
     pw = payload.shape[1]
     (out,) = _call(
-        _make_decode_body(pw == _HALF),
+        "decode", _make_decode_body(pw == _HALF),
         (payload, _scale_cells(scales)), (pw, 1), (CHUNK,),
         (jnp.float32,), n_chunks,
     )
@@ -464,7 +467,7 @@ def decode_add(
     n_chunks = payload.shape[0]
     pw = payload.shape[1]
     (out,) = _call(
-        _make_decode_add_body(pw == _HALF),
+        "decode_add", _make_decode_add_body(pw == _HALF),
         (b2, payload, _scale_cells(scales)), (CHUNK, pw, 1),
         (CHUNK,), (jnp.float32,), n_chunks, aliases={0: 0},
     )
@@ -505,7 +508,7 @@ def decode_accumulate(
         operands += [rq, _scale_cells(rs)]
         widths += [pw, 1]
     (out,) = _call(
-        _make_dacc_body(len(rounds), pw == _HALF, wdt),
+        "decode_accumulate", _make_dacc_body(len(rounds), pw == _HALF, wdt),
         tuple(operands), tuple(widths), (CHUNK,), (wdt,), n_chunks,
         aliases={1: 0},
     )

@@ -43,6 +43,11 @@ What gets recorded (event ``kind`` -> payload):
 - ``step_begin`` / ``step_dispatched`` — optimizer step boundaries with
   the communicating flag; the merge tool turns these into per-rank step
   spans and computes per-step critical paths over the plan's rounds.
+- ``step_resolve`` / ``step_key`` / ``step_enqueue`` / ``step_end`` — the
+  other boundaries of a fused ``train_step`` call (:class:`StepPhases`):
+  with the two above they cut the call into ``resolve``, ``key``,
+  ``stage``, ``enqueue`` and ``epilogue``; :func:`step_phases` reads
+  them back as microseconds per phase.
 - ``sync_begin`` / ``sync_ready`` — host blocking points (the moments a
   hang becomes observable).
 - ``window_op`` — one-sided window traffic (put/get/accumulate/update).
@@ -86,6 +91,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax import profiler as _profiler
+
 from bluefog_tpu import timeline as tl
 from bluefog_tpu import watchdog
 from bluefog_tpu.logging_util import logger
@@ -95,6 +102,8 @@ __all__ = [
     "enabled",
     "record",
     "events",
+    "StepPhases",
+    "step_phases",
     "note_plan",
     "note_fault",
     "note_advisory",
@@ -251,6 +260,108 @@ def events() -> List[dict]:
     if _recorder is None:
         return []
     return _recorder.events()
+
+
+# -- the phases of a fused train_step call -------------------------------------
+
+STEP_ROOT = "bf.train_step"
+# phase -> the ring event that opens it; ``step_end`` closes the last one.
+# ``step_begin`` / ``step_dispatched`` are the boundaries tools/trace_merge.py
+# has always read: they open ``stage`` and ``epilogue``.
+STEP_PHASES = {
+    "resolve": "step_resolve",
+    "key": "step_key",
+    "stage": "step_begin",
+    "enqueue": "step_enqueue",
+    "epilogue": "step_dispatched",
+}
+STEP_END = "step_end"
+
+
+class StepPhases:
+    """One ``train_step`` call cut into :data:`STEP_PHASES`, written to two
+    sinks at once. Each boundary is one ring event (so the phases are read
+    back in-process with :func:`step_phases`, profiler or not) and the
+    close of one ``jax.profiler.TraceAnnotation`` and the opening of the
+    next, ``bf.train_step/<phase>``, under a ``StepTraceAnnotation`` root
+    (so an open profiler session shows them on the device trace's clock,
+    grouped by step; an annotation is inert while no session is open).
+    Every event and annotation of a call carries the same ``step``.
+
+    A context manager: entering opens the root and ``resolve``; leaving
+    closes whatever is open, and writes ``step_end`` only when the call
+    returned — a call that raised leaves its last boundary as the ring's
+    word on where it died, and :func:`step_phases` skips it."""
+
+    __slots__ = ("step", "_root", "_phase")
+
+    def __init__(self, step: int):
+        self.step = step
+        self._root = _profiler.StepTraceAnnotation(STEP_ROOT, step_num=step)
+        self._phase = None
+
+    def __enter__(self):
+        self._root.__enter__()
+        self.enter("resolve")
+        return self
+
+    def enter(self, phase: str, **data) -> None:
+        """Close the open phase and open ``phase``; ``data`` joins the
+        ``step`` in the ring event's payload."""
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+        record(STEP_PHASES[phase], step=self.step, **data)
+        self._phase = _profiler.TraceAnnotation(
+            f"{STEP_ROOT}/{phase}", step=self.step
+        )
+        self._phase.__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._phase.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            record(STEP_END, step=self.step)
+        self._root.__exit__(exc_type, exc, tb)
+        return False
+
+
+def step_phases(t0_us: Optional[int] = None, t1_us: Optional[int] = None,
+                evs: Optional[List[dict]] = None) -> List[dict]:
+    """The whole fused ``train_step`` calls in the ring (or in ``evs``, a
+    list of ring events as :func:`events` gives them) that lie inside
+    ``[t0_us, t1_us]`` on the ring's clock (``time.monotonic_ns() //
+    1000``): one ``{"step", "t_us", "total", <phase>: us ...}`` per call,
+    oldest first. A phase runs from its opening event to the next
+    boundary, so the phases of a call sum to its ``total`` exactly. A
+    call is whole when its six boundaries follow each other under one
+    ``step``; anything else (a call that raised, one the ring has half
+    overwritten, the two-program ``opt.step``'s lone ``step_begin`` /
+    ``step_dispatched`` pair) is left out."""
+    order = list(STEP_PHASES.values()) + [STEP_END]
+    names = list(STEP_PHASES)
+    out, stamps, step = [], [], None
+    for e in (events() if evs is None else evs):
+        if e["kind"] not in order:
+            continue
+        s = e.get("data", {}).get("step")
+        if e["kind"] == order[0]:
+            stamps, step = [e["t_us"]], s
+        elif stamps and s == step and e["kind"] == order[len(stamps)]:
+            stamps.append(e["t_us"])
+            if len(stamps) == len(order):
+                if (t0_us is None or stamps[0] >= t0_us) and (
+                    t1_us is None or stamps[-1] <= t1_us
+                ):
+                    call = {
+                        "step": step, "t_us": stamps[0],
+                        "total": stamps[-1] - stamps[0],
+                    }
+                    for i, name in enumerate(names):
+                        call[name] = stamps[i + 1] - stamps[i]
+                    out.append(call)
+                stamps = []
+        else:
+            stamps = []
+    return out
 
 
 def note_plan(plan, topo_version: int, live_token=None,

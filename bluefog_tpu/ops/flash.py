@@ -185,6 +185,7 @@ def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="bf_flash_fwd",
     )(qf, kf, vf)
 
 
@@ -354,6 +355,7 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, d_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="bf_flash_dkv",
     )(qf, kf, vf, do, lse, delta, dlse_w)
     q_spec2 = pl.BlockSpec((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0))
     k_spec2 = pl.BlockSpec(
@@ -374,6 +376,7 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
         ),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
+        name="bf_flash_dq",
     )(qf, kf, vf, do, lse, delta, dlse_w)
     return dq, dk, dv
 

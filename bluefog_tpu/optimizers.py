@@ -44,6 +44,7 @@ worker-stacked pytrees (leading axis = worker), the same convention as
 :mod:`bluefog_tpu.collective.ops`.
 """
 
+import contextlib
 import enum
 import itertools
 import time
@@ -108,6 +109,7 @@ def _dtype_groups(leaves):
     return sorted(groups.items())
 
 
+@jax.named_scope("bf.gossip")
 def _bucketed_flat_gossip(flat, gossip_fn, step, wops, cap_bytes):
     """Gossip a flat payload in size-capped buckets (Horovod-style).
 
@@ -153,20 +155,23 @@ def _packed_gossip(tree, gossip_fn, step, wops, cap_bytes=0):
             l = leaves[i]
             bounds = inner.bucket_bounds(l.size, l.dtype.itemsize, cap_bytes)
             if len(bounds) == 1:
-                out[i] = gossip_fn(l, step, wops)
+                with jax.named_scope("bf.gossip"):
+                    out[i] = gossip_fn(l, step, wops)
             else:
                 res = _bucketed_flat_gossip(
                     l.reshape(-1), gossip_fn, step, wops, cap_bytes
                 )
                 out[i] = res.reshape(l.shape)
             continue
-        flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
+        with jax.named_scope("bf.pack"):
+            flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
         res = _bucketed_flat_gossip(flat, gossip_fn, step, wops, cap_bytes)
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            out[i] = res[off:off + n].reshape(leaves[i].shape)
-            off += n
+        with jax.named_scope("bf.unpack"):
+            off = 0
+            for i in idxs:
+                n = leaves[i].size
+                out[i] = res[off:off + n].reshape(leaves[i].shape)
+                off += n
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -182,33 +187,36 @@ def _packed_gossip_ef(tree, ef_blocks, ef_combine, cap_bytes=0):
     out = [None] * len(leaves)
     ef_out = []
     for gi, (_dt, idxs) in enumerate(_dtype_groups(leaves)):
-        flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
+        with jax.named_scope("bf.pack"):
+            flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
         bounds = inner.bucket_bounds(
             flat.size, flat.dtype.itemsize, cap_bytes
         )
         e_self, e_recv = ef_blocks[gi]
-        if len(bounds) == 1:
-            y, e_new = ef_combine(flat, (e_self, e_recv))
-        else:
-            ys, e_selfs, e_recvs = [], [], []
-            for a, b in bounds:
-                yb, (es, er) = ef_combine(
-                    flat[a:b], (e_self[a:b], e_recv[:, a:b])
+        with jax.named_scope("bf.gossip"):
+            if len(bounds) == 1:
+                y, e_new = ef_combine(flat, (e_self, e_recv))
+            else:
+                ys, e_selfs, e_recvs = [], [], []
+                for a, b in bounds:
+                    yb, (es, er) = ef_combine(
+                        flat[a:b], (e_self[a:b], e_recv[:, a:b])
+                    )
+                    ys.append(yb)
+                    e_selfs.append(es)
+                    e_recvs.append(er)
+                y = jnp.concatenate(ys)
+                e_new = (
+                    jnp.concatenate(e_selfs),
+                    jnp.concatenate(e_recvs, axis=1),
                 )
-                ys.append(yb)
-                e_selfs.append(es)
-                e_recvs.append(er)
-            y = jnp.concatenate(ys)
-            e_new = (
-                jnp.concatenate(e_selfs),
-                jnp.concatenate(e_recvs, axis=1),
-            )
         ef_out.append(e_new)
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            out[i] = y[off:off + n].reshape(leaves[i].shape)
-            off += n
+        with jax.named_scope("bf.unpack"):
+            off = 0
+            for i in idxs:
+                n = leaves[i].size
+                out[i] = y[off:off + n].reshape(leaves[i].shape)
+                off += n
     return jax.tree_util.tree_unflatten(treedef, out), tuple(ef_out)
 
 
@@ -240,11 +248,12 @@ def _shard_own_slices(tree, layout, axis):
     lidx = jnp.asarray(layout.live_index())
     i = lidx[jax.lax.axis_index(axis)]
     out = []
-    for gi, gsh in enumerate(layout.groups):
-        f = jnp.pad(packs[gi], (0, gsh.padded - packs[gi].shape[0]))
-        out.append(
-            jax.lax.dynamic_slice_in_dim(f, i * gsh.slot, gsh.slot)
-        )
+    with jax.named_scope("bf.pack"):
+        for gi, gsh in enumerate(layout.groups):
+            f = jnp.pad(packs[gi], (0, gsh.padded - packs[gi].shape[0]))
+            out.append(
+                jax.lax.dynamic_slice_in_dim(f, i * gsh.slot, gsh.slot)
+            )
     return tuple(out)
 
 
@@ -268,29 +277,31 @@ def _sharded_inner_update(tx, layout, p, s, g, own_g=None):
         _shard_check_groups(g, layout, "gradient")
         own_g = _shard_own_slices(g, layout, ctx_mod.WORKER_AXIS)
     own_p = _shard_own_slices(p, layout, ctx_mod.WORKER_AXIS)
-    if layout.master:
-        # fp32 master slices carry the reference values; the update
-        # runs in fp32 and the wire ships the narrowed result
-        own_g = tuple(x.astype(jnp.float32) for x in own_g)
-        updates, inner_s = tx.update(own_g, s.inner, s.master)
-        masters = optax.apply_updates(s.master, updates)
-        new_own = tuple(
-            m.astype(o.dtype) for m, o in zip(masters, own_p)
-        )
-        s_out = sharding.ShardedOptState(inner_s, tuple(masters))
-    else:
-        updates, inner_s = tx.update(own_g, s.inner, own_p)
-        new_own = optax.apply_updates(own_p, updates)
-        s_out = sharding.ShardedOptState(inner_s, ())
+    with jax.named_scope("bf.inner_update"):
+        if layout.master:
+            # fp32 master slices carry the reference values; the update
+            # runs in fp32 and the wire ships the narrowed result
+            own_g = tuple(x.astype(jnp.float32) for x in own_g)
+            updates, inner_s = tx.update(own_g, s.inner, s.master)
+            masters = optax.apply_updates(s.master, updates)
+            new_own = tuple(
+                m.astype(o.dtype) for m, o in zip(masters, own_p)
+            )
+            s_out = sharding.ShardedOptState(inner_s, tuple(masters))
+        else:
+            updates, inner_s = tx.update(own_g, s.inner, own_p)
+            new_own = optax.apply_updates(own_p, updates)
+            s_out = sharding.ShardedOptState(inner_s, ())
     live_rows = jnp.asarray(np.asarray(layout.live, np.int32))
     full = []
-    for gi, gsh in enumerate(layout.groups):
-        gathered = jax.lax.all_gather(
-            new_own[gi], ctx_mod.WORKER_AXIS
-        )  # [size, slot]
-        full.append(
-            jnp.take(gathered, live_rows, axis=0).reshape(-1)[:gsh.elems]
-        )
+    with jax.named_scope("bf.gossip"):
+        for gi, gsh in enumerate(layout.groups):
+            gathered = jax.lax.all_gather(
+                new_own[gi], ctx_mod.WORKER_AXIS
+            )  # [size, slot]
+            full.append(
+                jnp.take(gathered, live_rows, axis=0).reshape(-1)[:gsh.elems]
+            )
     return _unpack_groups(p, tuple(full)), s_out
 
 
@@ -317,22 +328,31 @@ def _scatter_own_grads(g, layout, wire, chunks, ef_blocks):
     )
     own, ef_out = [], []
     for gi, gsh in enumerate(layout.groups):
-        f = jnp.pad(packs[gi], (0, gsh.padded - packs[gi].shape[0]))
+        with jax.named_scope("bf.pack"):
+            f = jnp.pad(packs[gi], (0, gsh.padded - packs[gi].shape[0]))
         k = chunks[gi] if gi < len(chunks) else 1
-        if wire in ("int8_ef", "int4_ef"):
-            y, e_new = inner.reduce_scatter(
-                f, ctx_mod.WORKER_AXIS, live_index, gsh.slot,
-                average=True, wire=wire, chunks=k,
-                ef=ef_blocks[gi], live_mask=live_mask,
-            )
-            ef_out.append(e_new)
-        else:
-            y = inner.reduce_scatter(
-                f, ctx_mod.WORKER_AXIS, live_index, gsh.slot,
-                average=True, wire=wire, chunks=k,
-            )
+        with jax.named_scope("bf.gossip"):
+            if wire in ("int8_ef", "int4_ef"):
+                y, e_new = inner.reduce_scatter(
+                    f, ctx_mod.WORKER_AXIS, live_index, gsh.slot,
+                    average=True, wire=wire, chunks=k,
+                    ef=ef_blocks[gi], live_mask=live_mask,
+                )
+                ef_out.append(e_new)
+            else:
+                y = inner.reduce_scatter(
+                    f, ctx_mod.WORKER_AXIS, live_index, gsh.slot,
+                    average=True, wire=wire, chunks=k,
+                )
         own.append(y)
     return tuple(own), tuple(ef_out)
+
+
+@jax.named_scope("bf.inner_update")
+def _inner_update(tx, g, s, p):
+    """The optax step on UNSTACKED trees: ``(p', s')``."""
+    updates, s = tx.update(g, s, p)
+    return optax.apply_updates(p, updates), s
 
 
 def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
@@ -436,13 +456,13 @@ def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
 
     if order == "cta":
         p, ef_state = communicate(p, ef_state)
-    updates, s = tx.update(g, s, p)
-    p = optax.apply_updates(p, updates)
+    p, s = _inner_update(tx, g, s, p)
     if order == "atc":
         p, ef_state = communicate(p, ef_state)
     return p, s, ef_state, mvec
 
 
+@jax.named_scope("bf.pack")
 def _pack_groups(tree):
     """Per-dtype-group flat packed payloads of an UNSTACKED tree, in
     :func:`_dtype_groups` order — the wire layout `_packed_gossip` uses."""
@@ -455,6 +475,7 @@ def _pack_groups(tree):
     )
 
 
+@jax.named_scope("bf.pack")
 def _packed_prefix(tree, cap):
     """``[(sub_flat, scale)]`` per dtype group: a 512-aligned prefix of
     the group's PACKED flat, built directly from whole input leaves
@@ -485,6 +506,7 @@ def _packed_prefix(tree, cap):
     return out
 
 
+@jax.named_scope("bf.unpack")
 def _unpack_groups(tree, groups):
     """Scatter per-dtype-group flat packed values back onto a tree's
     leaves; the inverse of :func:`_pack_groups`."""
@@ -513,6 +535,18 @@ def _aval_key(tree):
     ) + (str(jax.tree_util.tree_structure(tree)),)
 
 
+def _first_difference(old, new):
+    """Index of the first component at which two cache keys differ (the
+    shorter key's length when one is a prefix of the other); ``None``
+    with no key to compare against."""
+    if old is None:
+        return None
+    for i, (a, b) in enumerate(zip(old, new)):
+        if a != b:
+            return i
+    return min(len(old), len(new))
+
+
 def _timed_dispatch(name, fn, *args):
     """ENQUEUE-span dispatch, the analogue of the reference's optimizer
     timeline hooks (torch/optimizers.py:112-165); same plumbing as the
@@ -522,16 +556,12 @@ def _timed_dispatch(name, fn, *args):
     inside this bracket too — the ``compile`` phase the watermark
     decomposition reports is exactly that first-dispatch growth). With
     both the timeline and the observatory off — the common case — the
-    fast path is two reads and a direct call."""
+    fast path is two reads, an empty context and a direct call."""
     if memory_mod.active() is None:
-        if not tl.timeline_enabled():
-            return fn(*args)
-        t0 = tl.timeline_now_us()
-        out = fn(*args)
-        tl.timeline_record_complete(name, "ENQUEUE", t0,
-                                    tl.timeline_now_us() - t0)
-        return out
-    with memory_mod.phase_scope("dispatch"):
+        scope = contextlib.nullcontext()
+    else:
+        scope = memory_mod.phase_scope("dispatch")
+    with scope:
         if not tl.timeline_enabled():
             return fn(*args)
         t0 = tl.timeline_now_us()
@@ -2034,8 +2064,14 @@ class _GossipOptimizer:
         # Per-builder cache-key component: two builders over the same
         # optimizer may close over different loss functions.
         fused_uid = next(_opt_uid)
+        # the cache key of the call before: what a miss is compared with
+        last_key = [None]
 
         def train_step(params, opt_state, *batch):
+            with flight.StepPhases(self._step_count) as phases:
+                return run_step(phases, params, opt_state, *batch)
+
+        def run_step(phases, params, opt_state, *batch):
             ctx = ctx_mod.get_context()
             if delayed and self.compression in ("int8_ef", "int4_ef"):
                 raise ValueError(
@@ -2083,6 +2119,7 @@ class _GossipOptimizer:
                 self._comm_count % metrics_mod.metrics_interval() == 0
             )
             wire_now = self._metrics_wire(comm_now, hier, gossip_key)
+            phases.enter("key")
             key = (
                 "opt_fused_step", fused_uid, self.order,
                 self.communication_type, self._uid, self._tx_version, ef,
@@ -2095,13 +2132,19 @@ class _GossipOptimizer:
             fn = ctx.op_cache.get(key)
             if fn is None:
                 metrics_mod.counter("bluefog.recompiles").inc()
-                flight.record("compile", name="opt_fused_step")
+                flight.record(
+                    "compile", name="opt_fused_step",
+                    differs_at=_first_difference(last_key[0], key),
+                )
                 order = self.order
                 tx = self._tx
                 has_accum = accum is not None
 
-                def body(params_b, state_b, step, wops, ef_b, buf_b,
-                         accum_b, *batch_b):
+                # its own name, not one more `body`: the name is the compiled
+                # module's (`jit_bf_step` in a device trace) and part of the
+                # persistent compile cache's key, which leaves metadata out
+                def bf_step(params_b, state_b, step, wops, ef_b, buf_b,
+                            accum_b, *batch_b):
                     p = _tree_block(params_b)
                     s = _tree_block(state_b)
                     bat = tuple(_tree_block(b) for b in batch_b)
@@ -2120,15 +2163,18 @@ class _GossipOptimizer:
                             )
                             for b in bufs
                         )
-                        sw = self_weight_fn(step, wops)
+                        with jax.named_scope("bf.gossip"):
+                            sw = self_weight_fn(step, wops)
 
                         def stale_mix(tree):
                             fresh = _pack_groups(tree)
-                            return _unpack_groups(tree, tuple(
-                                c + sw.astype(c.dtype)
-                                * (x.astype(c.dtype) - b.astype(c.dtype))
-                                for c, x, b in zip(combined, fresh, bufs)
-                            ))
+                            with jax.named_scope("bf.gossip"):
+                                mixed = tuple(
+                                    c + sw.astype(c.dtype)
+                                    * (x.astype(c.dtype) - b.astype(c.dtype))
+                                    for c, x, b in zip(combined, fresh, bufs)
+                                )
+                            return _unpack_groups(tree, mixed)
 
                         def delayed_probe(tree, grads):
                             """Metrics sub-gossip for the stale mix
@@ -2158,11 +2204,12 @@ class _GossipOptimizer:
                                 _packed_prefix(grads, cap),
                                 wire=None,
                             )
-                    if has_aux:
-                        (loss, aux), grads = value_and_grad(p, *bat)
-                    else:
-                        loss, grads = value_and_grad(p, *bat)
-                        aux = ()
+                    with jax.named_scope("bf.loss_grad"):
+                        if has_aux:
+                            (loss, aux), grads = value_and_grad(p, *bat)
+                        else:
+                            loss, grads = value_and_grad(p, *bat)
+                            aux = ()
                     if order == "grad" and not comm_now:
                         # accumulation call: params/state untouched, the
                         # gradient comes OUT to the host-side accumulator
@@ -2187,11 +2234,9 @@ class _GossipOptimizer:
                                 # docs/metrics.md)
                                 mvec = delayed_probe(p, grads)
                             p = stale_mix(p)
-                            updates, s = tx.update(grads, s, p)
-                            p = optax.apply_updates(p, updates)
+                            p, s = _inner_update(tx, grads, s, p)
                         else:  # atc
-                            updates, s = tx.update(grads, s, p)
-                            p = optax.apply_updates(p, updates)
+                            p, s = _inner_update(tx, grads, s, p)
                             new_buf = _pack_groups(p)
                             if met:
                                 mvec = delayed_probe(p, grads)
@@ -2231,7 +2276,7 @@ class _GossipOptimizer:
                 n_batch = len(batch)
                 fn = jax.jit(
                     jax.shard_map(
-                        body,
+                        bf_step,
                         mesh=mesh,
                         in_specs=(spec, spec, P(), P(), spec, spec, spec)
                         + (spec,) * n_batch,
@@ -2241,11 +2286,9 @@ class _GossipOptimizer:
                     )
                 )
                 ctx.op_cache[key] = fn
+            last_key[0] = key
+            phases.enter("stage", comm=comm_now, fused=True)  # step_begin
             step_idx = jnp.asarray([self._comm_count], jnp.int32)
-            flight.record(
-                "step_begin", step=self._step_count, comm=comm_now,
-                fused=True,
-            )
             # the comm index THIS dispatch runs at, and the age of the
             # payload its combine consumes: 0 on the fresh path, comm
             # steps since the delay buffer was written on the delayed
@@ -2279,26 +2322,22 @@ class _GossipOptimizer:
                 for op in (wops, ef_in, buf_in, accum_in)
             )
             doc_t0 = attribution.dispatch_timer(comm_now)
-            if self.order == "grad" and not comm_now:
-                params_o, state_o, loss, aux, _ef_o, grads_o, _met_o = (
-                    _timed_dispatch(
-                        "fused_train_step", fn, params, opt_state,
-                        step_idx, wops, ef_in, buf_in, accum_in,
-                        *batch,
-                    )
+            phases.enter("enqueue")
+            params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
+                _timed_dispatch(
+                    "fused_train_step", fn, params, opt_state,
+                    step_idx, wops, ef_in, buf_in, accum_in, *batch,
                 )
+            )
+            phases.enter("epilogue")  # step_dispatched
+            if self.order == "grad" and not comm_now:
+                # accumulation call: the gradient comes out where the
+                # delay buffer would
                 self._grad_accum = (
-                    grads_o if self._grad_accum is None
-                    else self._tree_add(ctx, self._grad_accum, grads_o)
+                    buf_o if self._grad_accum is None
+                    else self._tree_add(ctx, self._grad_accum, buf_o)
                 )
             else:
-                params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
-                    _timed_dispatch(
-                        "fused_train_step", fn, params, opt_state,
-                        step_idx, wops, ef_in, buf_in, accum_in,
-                        *batch,
-                    )
-                )
                 if ef:
                     self._ef = ef_o
                 elif scatter_ef:
@@ -2313,7 +2352,6 @@ class _GossipOptimizer:
                     self._drain_after_sample(
                         None if delay_now else wire_now, met_o[0]
                     )
-            flight.record("step_dispatched", step=self._step_count - 1)
             if comm_now:
                 # attribution doctor: host-side only, program untouched
                 attribution.observe_step(

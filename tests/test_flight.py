@@ -105,6 +105,87 @@ def test_concurrent_writers_never_corrupt():
     assert len(set(seqs)) == len(seqs)  # unique slots: no torn writes
 
 
+# -- the phases of a fused train_step call, read back from plain events ---------
+
+
+def _call_events(step, t0, gaps=(5, 7, 11, 13, 17), seq0=0, upto=6):
+    """The six boundary events of one fused call, ``gaps`` us apart."""
+    kinds = list(flight.STEP_PHASES.values()) + [flight.STEP_END]
+    stamps = [t0]
+    for g in gaps:
+        stamps.append(stamps[-1] + g)
+    return [
+        {"seq": seq0 + i, "t_us": t, "kind": k, "data": {"step": step}}
+        for i, (k, t) in enumerate(zip(kinds, stamps))
+    ][:upto]
+
+
+def test_step_phases_of_a_whole_call():
+    (call,) = flight.step_phases(evs=_call_events(7, 1000))
+    assert call == {
+        "step": 7, "t_us": 1000, "total": 53, "resolve": 5, "key": 7,
+        "stage": 11, "enqueue": 13, "epilogue": 17,
+    }
+
+
+@pytest.mark.parametrize("case", [
+    "raised", "half_overwritten", "two_program", "other_kinds_between",
+])
+def test_step_phases_keeps_whole_calls_only(case):
+    whole = _call_events(3, 5000, seq0=100)
+    if case == "raised":
+        # died in `enqueue`, then the same step number ran again
+        evs = _call_events(3, 1000, upto=4) + whole
+    elif case == "half_overwritten":
+        # the ring wrapped inside the call before: its first half is gone
+        evs = _call_events(2, 1000)[3:] + whole
+    elif case == "two_program":
+        # opt.step writes step_begin / step_dispatched alone
+        evs = [
+            {"seq": 1, "t_us": 10, "kind": "step_begin",
+             "data": {"step": 3, "comm": True}},
+            {"seq": 2, "t_us": 20, "kind": "step_dispatched",
+             "data": {"step": 3}},
+        ] + whole
+    else:
+        evs = list(whole)
+        evs.insert(2, {"seq": 99, "t_us": 5006, "kind": "compile",
+                       "data": {"name": "opt_fused_step", "differs_at": 5}})
+        evs.insert(4, {"seq": 98, "t_us": 5020, "kind": "plan_compile"})
+    calls = flight.step_phases(evs=evs)
+    assert [(c["step"], c["t_us"], c["total"]) for c in calls] == [(3, 5000, 53)]
+
+
+def test_fused_step_events_sit_beside_the_two_program_ones():
+    """``opt.step`` keeps its lone pair; a fused call brackets the same two
+    kinds with the four new boundaries, and trace_merge folds both."""
+    import optax
+
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import trace_merge
+
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(0.1))
+    params = {"w": bf.worker_values(lambda r: np.float32([r]))}
+    state = opt.init(params)
+    params, state = opt.step(params, state, {"w": jnp.zeros_like(params["w"])})
+    fused = bf.make_train_step(opt, lambda p, x: jnp.sum(p["w"] * x))
+    x = bf.worker_values(lambda r: np.float32([1.0]))
+    params, state, loss = fused(params, state, x)
+    loss.block_until_ready()
+    kinds = [e["kind"] for e in flight.events() if e["kind"].startswith("step_")]
+    assert kinds == ["step_begin", "step_dispatched"] + list(
+        flight.STEP_PHASES.values()
+    ) + [flight.STEP_END]
+    (call,) = flight.step_phases()
+    assert call["step"] == 1
+    steps = trace_merge._steps_of({"events": flight.events(), "comm_plans": []})
+    assert [s["step"] for s in steps] == [0, 1]
+    # the merge tool's span of the fused call is stage + enqueue
+    assert steps[1]["t_end_us"] - steps[1]["t_begin_us"] == (
+        call["stage"] + call["enqueue"]
+    )
+
+
 # -- session events + explicit dump ---------------------------------------------
 
 

@@ -89,5 +89,4 @@ def test_tools_enumerated():
         "shard_plan.py", "slo_report.py", "staleness_report.py",
         "trace_merge.py",
         "hlo_overlap_scan.py", "hlo_dump.py", "perf_probe.py",
-        "resnet_layer_profile.py", "transformer_stage_profile.py",
     } <= names
