@@ -71,7 +71,7 @@ from bluefog_tpu import windows as win_mod
 from bluefog_tpu.collective import compiler, inner, ops as col_ops
 from bluefog_tpu.collective.plan import SchedulePlan, plan_from_topology
 from bluefog_tpu.logging_util import warn_once
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = [
     "CommunicationType",
@@ -547,6 +547,51 @@ def _first_difference(old, new):
     return min(len(old), len(new))
 
 
+def _replicated(mesh, host_value):
+    """A small operand the host builds for a step (the step index, a
+    weight vector): numpy value -> array committed to and replicated over
+    the step's own ``mesh``, the sharding ``in_specs ... P()`` asks for.
+    Each chip gets it from its host (every process puts to its own chips;
+    ``jax.device_put`` would first compare the value across processes).
+    ``jnp.asarray`` instead makes it on device 0 alone, in order behind
+    that device's previous step, and the jitted call then reshards it to
+    the others on its slow path: on four v5e chips that hand-over cost
+    every step 20-25 ms (PERF.md, PR 25)."""
+    return jax.make_array_from_callback(
+        host_value.shape, NamedSharding(mesh, P()),
+        lambda index: host_value[index],
+    )
+
+
+def _stage_operands(mesh, comm_count, wops):
+    """The host-built operands of one dispatch -> ``(step_idx, wops)`` on
+    the chips: the communication index as ``int32[1]`` and each weight
+    array the resolvers returned (numpy), every one through
+    :func:`_replicated`, per call — so a weight reassigned between steps
+    is the next step's operand of the same compiled program."""
+    step_idx = _replicated(mesh, np.asarray([comm_count], np.int32))
+    wops = tuple(_replicated(mesh, w) for w in wops)
+    _count_resharded(mesh, (step_idx, wops))
+    return step_idx, wops
+
+
+def _count_resharded(mesh, operands):
+    """``bluefog.step_operands_resharded``: how many of a dispatch's
+    host-built operands did NOT come through :func:`_replicated` (not
+    committed, or not replicated over exactly the step's mesh). 0 on
+    every path of this module; it guards the next operand added."""
+    want = NamedSharding(mesh, P())
+    n = sum(
+        not (
+            isinstance(a, jax.Array) and a.committed
+            and a.sharding.is_equivalent_to(want, a.ndim)
+        )
+        for a in jax.tree_util.tree_leaves(operands)
+    )
+    if n:
+        metrics_mod.counter("bluefog.step_operands_resharded").inc(n)
+
+
 def _timed_dispatch(name, fn, *args):
     """ENQUEUE-span dispatch, the analogue of the reference's optimizer
     timeline hooks (torch/optimizers.py:112-165); same plumbing as the
@@ -860,7 +905,6 @@ class _GossipOptimizer:
         docs/sharding.md) and re-slice it under the new owner map.
         Non-slot leaves (step counts) are replicated and carried over.
         """
-        from jax.sharding import NamedSharding
 
         leaves, treedef = jax.tree_util.tree_flatten(opt_state)
         nd_sharding = NamedSharding(ctx.mesh, P(ctx_mod.WORKER_AXIS))
@@ -954,7 +998,6 @@ class _GossipOptimizer:
         would integrate against the wrong coordinates, while zeroed
         ones merely re-transmit full magnitude for a few steps (same
         reset discipline as :meth:`_ensure_ef_state`)."""
-        from jax.sharding import NamedSharding
 
         sig = (layout.sig(), self.compression)
         if getattr(self, "_scatter_ef_sig", None) == sig:
@@ -1044,8 +1087,10 @@ class _GossipOptimizer:
         weight operands).
 
         The block fn signature is ``fn(t, step, wops)``. Weight *values*
-        for plan-based gossip ride in ``wops`` as replicated device
-        operands, so the reference's per-iteration weight-reassignment
+        for plan-based gossip are returned here as host arrays and ride
+        in ``wops`` as replicated device operands (each dispatch puts
+        them through :func:`_stage_operands` on the step's mesh), so
+        the reference's per-iteration weight-reassignment
         idiom (README.rst:108-123) reuses ONE compiled program per edge
         structure instead of compiling per weight vector.
 
@@ -1160,7 +1205,7 @@ class _GossipOptimizer:
                                 wire=ef_wire,
                             )
                         ),
-                        (jnp.asarray(recv_w),),
+                        (recv_w,),
                     )
                 return (
                     ("na_q", wire, perms, chunks, inject)
@@ -1171,7 +1216,7 @@ class _GossipOptimizer:
                             wire=wire, chunks=chunks, inject=inject,
                         )
                     ),
-                    (jnp.asarray(recv_w),),
+                    (recv_w,),
                 )
             return (
                 ("na", perms, chunks, inject),
@@ -1179,7 +1224,7 @@ class _GossipOptimizer:
                     t, perms, wops[0], wops[1], ctx_mod.WORKER_AXIS,
                     chunks=chunks, inject=inject,
                 ),
-                (jnp.asarray(self_w), jnp.asarray(recv_w)),
+                (self_w, recv_w),
             )
         raise AssertionError(comm)
 
@@ -1242,7 +1287,7 @@ class _GossipOptimizer:
                             wire=wire, chunks=chunks, inject=inject,
                         )
                     ),
-                    (jnp.asarray(recv_w),),
+                    (recv_w,),
                 )
             return (
                 ("fed", "ici", None, perms, chunks, inject),
@@ -1250,7 +1295,7 @@ class _GossipOptimizer:
                     t, perms, wops[0], wops[1], axis,
                     chunks=chunks, inject=inject,
                 ),
-                (jnp.asarray(self_w), jnp.asarray(recv_w)),
+                (self_w, recv_w),
             )
         # DCN step: the gateway leg composes AFTER the intra leg inside
         # one fn, giving the x -> W_dcn^T (W_ici^T x) composed step the
@@ -1288,7 +1333,7 @@ class _GossipOptimizer:
         )
         if wire is not None:
             n_intra = 1
-            intra_ops = (jnp.asarray(recv_w),)
+            intra_ops = (recv_w,)
 
             def intra_leg(t, wops):
                 return inner.weighted_combine_quantized_operands(
@@ -1297,7 +1342,7 @@ class _GossipOptimizer:
                 )
         else:
             n_intra = 2
-            intra_ops = (jnp.asarray(self_w), jnp.asarray(recv_w))
+            intra_ops = (self_w, recv_w)
 
             def intra_leg(t, wops):
                 return inner.weighted_combine_operands(
@@ -1312,7 +1357,7 @@ class _GossipOptimizer:
                     inject=inter_inject,
                 )
 
-            wops = intra_ops + (jnp.asarray(inter_recv),)
+            wops = intra_ops + (inter_recv,)
         else:
             def fed_fn(t, step, wops):
                 return inner.weighted_combine_operands(
@@ -1321,9 +1366,7 @@ class _GossipOptimizer:
                     inject=inter_inject,
                 )
 
-            wops = intra_ops + (
-                jnp.asarray(inter_self), jnp.asarray(inter_recv),
-            )
+            wops = intra_ops + (inter_self, inter_recv)
         return key, fed_fn, wops
 
     def _self_weight_fn(self, ctx):
@@ -1477,7 +1520,7 @@ class _GossipOptimizer:
                         wire=wire,
                     )
                 ),
-                (jnp.asarray(recv_w),),
+                (recv_w,),
             )
         return (
             ("hier", perms),
@@ -1485,7 +1528,7 @@ class _GossipOptimizer:
                 t, perms, wops[0], wops[1],
                 ctx_mod.MACHINE_AXIS, ctx_mod.LOCAL_AXIS
             ),
-            (jnp.asarray(self_w), jnp.asarray(recv_w)),
+            (self_w, recv_w),
         )
 
     def _machine_plan(self, ctx):
@@ -1533,7 +1576,6 @@ class _GossipOptimizer:
         bit-identical-replica invariant; zeroed copies merely
         re-transmit full magnitude a few rounds), and copies integrated
         under one quantizer must not seed the other tier's recursion."""
-        from jax.sharding import NamedSharding
 
         leaves = jax.tree_util.tree_leaves(params)
         sig = (
@@ -1899,7 +1941,7 @@ class _GossipOptimizer:
             self._grad_accum = None
         # dynamic schedules advance per COMMUNICATION, not per call, so a
         # K>1 optimizer still walks every topology in the schedule
-        step_idx = jnp.asarray([self._comm_count], jnp.int32)
+        step_idx, wops = _stage_operands(mesh, self._comm_count, wops)
         flight.record("step_begin", step=self._step_count, comm=comm_now)
         self._step_count += 1
         if comm_now:
@@ -1985,7 +2027,6 @@ class _GossipOptimizer:
         parameter avals or the communication structure change (a stale
         buffer under a new edge set would mix against the wrong sources,
         same invalidation rule as the error-feedback copies)."""
-        from jax.sharding import NamedSharding
 
         leaves = jax.tree_util.tree_leaves(params)
         sig = (
@@ -2288,7 +2329,6 @@ class _GossipOptimizer:
                 ctx.op_cache[key] = fn
             last_key[0] = key
             phases.enter("stage", comm=comm_now, fused=True)  # step_begin
-            step_idx = jnp.asarray([self._comm_count], jnp.int32)
             # the comm index THIS dispatch runs at, and the age of the
             # payload its combine consumes: 0 on the fresh path, comm
             # steps since the delay buffer was written on the delayed
@@ -2314,13 +2354,27 @@ class _GossipOptimizer:
             # (lower_last_fused_hlo): the compiled fn plus exactly the
             # operand structure this dispatch used — as avals, not live
             # arrays, so the hook never pins a superseded model-sized
-            # buffer generation in device memory
-            self._last_fused = (fn,) + tuple(
-                jax.tree_util.tree_map(
-                    lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype), op
+            # buffer generation in device memory. The host-built operands
+            # carry the sharding they are staged with just below (so the
+            # lowering is the dispatched module, not a twin of it), and
+            # are recorded first: staging needs a device to put to, which
+            # an off-chip compile for a described topology does not have
+            def avals(op, sharding=None):
+                return jax.tree_util.tree_map(
+                    lambda t: jax.ShapeDtypeStruct(
+                        t.shape, t.dtype, sharding=sharding
+                    ), op,
                 )
-                for op in (wops, ef_in, buf_in, accum_in)
+
+            replicated = NamedSharding(mesh, P())
+            self._last_fused_step = jax.ShapeDtypeStruct(
+                (1,), jnp.int32, sharding=replicated
             )
+            self._last_fused = (
+                fn, avals(wops, replicated), avals(ef_in), avals(buf_in),
+                avals(accum_in),
+            )
+            step_idx, wops = _stage_operands(mesh, cur_comm, wops)
             doc_t0 = attribution.dispatch_timer(comm_now)
             phases.enter("enqueue")
             params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
@@ -2431,7 +2485,7 @@ class _GossipOptimizer:
         the compiled fn's operand structure so callers never have to
         poke cache-key internals."""
         fn, wops, ef_in, buf_in, accum_in = self._last_fused
-        step_idx = jnp.asarray([0], jnp.int32)
+        step_idx = self._last_fused_step
         return (
             fn.lower(
                 params, opt_state, step_idx, wops, ef_in, buf_in,
