@@ -1275,12 +1275,19 @@ def run_overlap() -> int:
             line["degenerate"] = True
         print(json.dumps(line))
 
-    bounds = col_inner.bucket_bounds(n_elems, 4, bucket_bytes)
+    # the fused_buckets variant's messages a round (exact wire): a leaf at
+    # or over the cap alone, the smaller ones packed and cut into buckets
+    from bluefog_tpu import optimizers as opt_mod
+
+    elems = [
+        n for _itemsize, n in
+        opt_mod._gossip_messages(make_params(), bucket_bytes, True)
+    ]
     print(json.dumps({
         "metric": "overlap_buckets",
         "bucket_bytes_cap": bucket_bytes,
-        "n_buckets": len(bounds),
-        "bucket_elems": [b - a for a, b in bounds[:16]],
+        "n_buckets": len(elems),
+        "bucket_elems": elems[:16],
     }))
 
     for variant, txt in hlo_texts.items():

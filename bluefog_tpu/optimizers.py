@@ -128,14 +128,20 @@ def _bucketed_flat_gossip(flat, gossip_fn, step, wops, cap_bytes):
     )
 
 
-def _packed_gossip(tree, gossip_fn, step, wops, cap_bytes=0):
-    """Apply a gossip combine to a whole pytree, packed per dtype group
-    and split into size-capped wire buckets.
+def _travels_alone(n_elems, itemsize, cap_bytes):
+    """The fusion threshold: a leaf of at least ``cap_bytes`` (it would
+    fill a whole wire bucket by itself) gains nothing from packing."""
+    return cap_bytes > 0 and n_elems * itemsize >= cap_bytes
+
+
+def _packed_gossip(tree, gossip_fn, step, wops, cap_bytes=0, direct=False):
+    """Apply a gossip combine to a whole pytree: small leaves packed per
+    dtype group into size-capped wire buckets, large leaves alone.
 
     XLA does not combine per-leaf collective-permutes (a 6-leaf ATC step
     over a 3-round plan compiles to 18 of them — verified by
     tests/test_fusion.py), so a model-sized tree would pay
-    O(leaves x rounds) message latencies. Packing every same-dtype leaf
+    O(leaves x rounds) message latencies. Packing the same-dtype leaves
     into one flat vector before the combine is the TPU-native analogue of
     the reference's tensor-fusion buffer (``tensor_queue.h:75-124``, 8 MiB
     threshold, ``global_state.h:91``): the many-leaf gossip becomes a
@@ -146,10 +152,27 @@ def _packed_gossip(tree, gossip_fn, step, wops, cap_bytes=0):
     ``cap_bytes`` > 0 re-splits each packed payload into independent
     buckets (:func:`bluefog_tpu.collective.inner.bucket_bounds`) so the
     scheduler can pipeline them; 0 keeps one payload per dtype group.
+
+    ``direct`` (the exact wire, whose combine is elementwise over any
+    shape) makes the cap the reference's fusion *threshold*: a leaf that
+    :func:`_travels_alone` is combined whole and in its own shape — no
+    flatten, bucket slices, concatenate and unpack, four parameter-sized
+    copies on the TPU (PERF.md, PR 28) — and only the smaller leaves are
+    packed. Without it (quantized wires, whose 512-element scale chunks
+    run over the flat order) every leaf is packed.
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     out = [None] * len(leaves)
     for _dt, idxs in _dtype_groups(leaves):
+        if direct:
+            for i in idxs:
+                l = leaves[i]
+                if _travels_alone(l.size, l.dtype.itemsize, cap_bytes):
+                    with jax.named_scope("bf.gossip"):
+                        out[i] = gossip_fn(l, step, wops)
+            idxs = [i for i in idxs if out[i] is None]
+            if not idxs:
+                continue
         if len(idxs) == 1:
             i = idxs[0]
             l = leaves[i]
@@ -357,7 +380,8 @@ def _inner_update(tx, g, s, p):
 
 def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
                     ef, ef_state, p, s, g, wire=None, with_metrics=False,
-                    shard=None, scatter_wire=None, scatter_chunks=()):
+                    shard=None, scatter_wire=None, scatter_chunks=(),
+                    direct=False):
     """The gossip+inner-update core shared by :meth:`_GossipOptimizer.step`
     and the fused builder (:meth:`_GossipOptimizer.make_train_step`).
 
@@ -372,7 +396,9 @@ def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
     the values that feed ``p``/``s``, so metrics on/off stays
     bitwise-identical for the training state (tests/test_metrics.py);
     ``mvec`` is None when off. ``wire`` names the quantized wire in use
-    so the metric row can include its quantization error.
+    so the metric row can include its quantization error. ``direct`` is
+    :meth:`_GossipOptimizer._direct_route`'s verdict for
+    :func:`_packed_gossip`.
     """
     mvec = None
     allreduce_fn = lambda t, _s, _w: inner.allreduce(
@@ -431,7 +457,7 @@ def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
                 tx, shard, p, s, g, own_g=own_g
             )
             return p, s, ef_state, mvec
-        g = _packed_gossip(g, allreduce_fn, step, wops, cap_bytes)
+        g = _packed_gossip(g, allreduce_fn, step, wops, cap_bytes, direct)
 
     if shard is not None:
         # BLUEFOG_SHARD=1: the allreduce above made the gradient
@@ -452,7 +478,9 @@ def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
                 lambda flat, e: gossip_fn(flat, e, wops),
                 cap_bytes,
             )
-        return _packed_gossip(tree, gossip_fn, step, wops, cap_bytes), ef_st
+        return _packed_gossip(
+            tree, gossip_fn, step, wops, cap_bytes, direct
+        ), ef_st
 
     if order == "cta":
         p, ef_state = communicate(p, ef_state)
@@ -533,6 +561,55 @@ def _aval_key(tree):
         (tuple(l.shape), str(l.dtype))
         for l in jax.tree_util.tree_leaves(tree)
     ) + (str(jax.tree_util.tree_structure(tree)),)
+
+
+def _gossip_routes(params, cap_bytes, direct):
+    """How :func:`_packed_gossip` routes a worker-STACKED tree, per dtype
+    group: ``(itemsize, alone, packed)`` — the per-worker element counts
+    of the leaves that travel alone and the packed rest's total."""
+    leaves = jax.tree_util.tree_leaves(params)
+    out = []
+    for dt, idxs in _dtype_groups(leaves):
+        itemsize = np.dtype(dt).itemsize
+        sizes = [int(np.prod(leaves[i].shape[1:])) for i in idxs]
+        alone = [
+            n for n in sizes
+            if direct and _travels_alone(n, itemsize, cap_bytes)
+        ]
+        out.append((itemsize, alone, sum(sizes) - sum(alone)))
+    return out
+
+
+def _gossip_messages(params, cap_bytes, direct):
+    """``(itemsize, n_elems)`` of every message one combine round of a
+    worker-STACKED tree sends: each leaf that travels alone, then the
+    buckets of each dtype group's packed rest."""
+    out = []
+    for itemsize, alone, packed in _gossip_routes(params, cap_bytes, direct):
+        out += [(itemsize, n) for n in alone]
+        if packed:
+            out += [
+                (itemsize, b - a)
+                for a, b in inner.bucket_bounds(packed, itemsize, cap_bytes)
+            ]
+    return out
+
+
+def _record_step_built(name, params, cap_bytes, direct, **data):
+    """A step program is about to be built: count it, say how its gossip
+    routes the parameters (gauges ``bluefog.gossip_direct_bytes`` /
+    ``bluefog.gossip_packed_bytes``, per worker), and put both on the
+    flight ring's ``compile`` event."""
+    routes = _gossip_routes(params, cap_bytes, direct)
+    direct_bytes = sum(item * sum(alone) for item, alone, _ in routes)
+    packed_bytes = sum(item * packed for item, _, packed in routes)
+    metrics_mod.counter("bluefog.recompiles").inc()
+    metrics_mod.gauge("bluefog.gossip_direct_bytes").set(direct_bytes)
+    metrics_mod.gauge("bluefog.gossip_packed_bytes").set(packed_bytes)
+    flight.record(
+        "compile", name=name, direct_bytes=direct_bytes,
+        packed_bytes=packed_bytes, **data,
+    )
 
 
 def _first_difference(old, new):
@@ -1039,24 +1116,46 @@ class _GossipOptimizer:
 
     # -- gossip resolution ---------------------------------------------------
 
-    def _wire_payload(self, params):
-        """``(payload_bytes, n_elems)`` of the largest wire bucket this
-        dispatch ships — the payload the compiler's chunk chooser prices
-        (PR-2 buckets are the chunking grain: each bucket is split into
-        the chosen chunk count inside the combine)."""
-        leaves = jax.tree_util.tree_leaves(params)
-        cap = inner.bucket_bytes_cap()
-        best = None
-        for dt, idxs in _dtype_groups(leaves):
-            n = sum(int(np.prod(leaves[i].shape[1:])) for i in idxs)
-            if n == 0:
-                continue
-            itemsize = np.dtype(dt).itemsize
-            bounds = inner.bucket_bounds(n, itemsize, cap)
-            elems = max(b - a for a, b in bounds)
-            if best is None or elems * itemsize > best[0]:
-                best = (elems * itemsize, elems)
-        return best
+    def _fabric(self, ctx):
+        """The federation fabric a flat neighbor_allreduce dispatch rides
+        (docs/federation.md), or None: a schedule or explicit per-step
+        weights are the caller's own topology, and the other
+        communication types have no federated form."""
+        if (
+            self.communication_type != CommunicationType.neighbor_allreduce
+            or self.schedule is not None
+            or self.self_weight is not None
+            or self.src_weights is not None
+            or self.dst_weights is not None
+        ):
+            return None
+        from bluefog_tpu import federation
+
+        return federation.get_fabric(ctx.size)
+
+    def _direct_route(self, ctx) -> bool:
+        """Whether :func:`_packed_gossip` may send a large leaf alone and
+        in its own shape: only on the exact wire, whose combine is
+        elementwise over any shape. The quantized wires (this
+        optimizer's, or a fabric's DCN tier) scale 512-element chunks of
+        the flat order, and the ZeRO paths slice the flat vector: they
+        keep every leaf packed."""
+        if self.compression is not None or self._shard_active():
+            return False
+        fed = self._fabric(ctx)
+        return fed is None or fed.wire is None
+
+    def _wire_payload(self, params, direct=False):
+        """``(payload_bytes, n_elems)`` of the largest message this
+        dispatch ships — the payload the compiler's chunk chooser prices:
+        the largest leaf that travels alone (``direct``) or the largest
+        bucket of the packed rest, whichever is bigger (each message is
+        split into the chosen chunk count inside the combine)."""
+        messages = _gossip_messages(params, inner.bucket_bytes_cap(), direct)
+        if not messages:
+            return None
+        itemsize, elems = max(messages, key=lambda m: m[0] * m[1])
+        return itemsize * elems, elems
 
     def _plan_chunks(self, plan, payload) -> int:
         """The (rounds, chunks, route) Pareto chooser for one static-plan
@@ -1144,19 +1243,9 @@ class _GossipOptimizer:
                     ),
                     (),
                 )
-            if (
-                self.self_weight is None
-                and self.src_weights is None
-                and self.dst_weights is None
-            ):
-                from bluefog_tpu import federation
-
-                fed = (
-                    federation.get_fabric(ctx.size)
-                    if federation.enabled() else None
-                )
-                if fed is not None:
-                    return self._federated_key_and_fn(ctx, fed, payload)
+            fed = self._fabric(ctx)
+            if fed is not None:
+                return self._federated_key_and_fn(ctx, fed, payload)
             plan = col_ops._resolve_plan(
                 ctx,
                 self.self_weight,
@@ -1404,15 +1493,7 @@ class _GossipOptimizer:
             return from_schedule
         compression = self.compression
         if compression in ("int8_ef", "int4_ef"):
-            from bluefog_tpu import federation
-
-            if (
-                self.self_weight is None
-                and self.src_weights is None
-                and self.dst_weights is None
-                and federation.enabled()
-                and federation.get_fabric(ctx.size) is not None
-            ):
+            if self._fabric(ctx) is not None:
                 # federated EF fallback: the dispatch degraded to the
                 # memoryless base tier, whose wops carry only recv_w
                 compression = compression[:-3]
@@ -1616,14 +1697,17 @@ class _GossipOptimizer:
             )
         return self._step_count % k == k - 1
 
-    def _resolve_dispatch(self, ctx, params, comm_now):
+    def _resolve_dispatch(self, ctx, params, comm_now, flat=False):
         """The dispatch prologue shared by :meth:`step` and the fused
         builder: mesh/spec selection, gossip resolution, error-feedback
         state. One implementation so a new communication type or
         validation rule cannot reach one path and skip the other.
+        ``flat`` is a caller whose state is the packed flat payload (the
+        ``delayed=True`` buffers): no leaf travels alone there.
         Returns ``(hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-        cap_bytes)``."""
+        cap_bytes, direct)``."""
         self._validate_compression()
+        direct = not flat and self._direct_route(ctx)
         hier = (
             self.communication_type
             == CommunicationType.hierarchical_neighbor_allreduce
@@ -1644,7 +1728,7 @@ class _GossipOptimizer:
             gossip_key, gossip_fn, wops = self._hier_key_and_fn(ctx)
         else:
             gossip_key, gossip_fn, wops = self._gossip_key_and_fn(
-                ctx, self._wire_payload(params)
+                ctx, self._wire_payload(params, direct)
             )
         ef = comm_now and not hier and self.compression in (
             "int8_ef", "int4_ef",
@@ -1653,7 +1737,7 @@ class _GossipOptimizer:
             self._ensure_ef_state(ctx, params, spec, gossip_key[2])
         return (
             hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-            inner.bucket_bytes_cap(),
+            inner.bucket_bytes_cap(), direct,
         )
 
     # -- device-tier metrics plumbing ----------------------------------------
@@ -1867,6 +1951,7 @@ class _GossipOptimizer:
             return params, opt_state
         (
             hier, mesh, spec, gossip_key, gossip_fn, wops, ef, cap_bytes,
+            direct,
         ) = self._resolve_dispatch(ctx, params, comm_now)
         shard_l = None
         if comm_now and self._shard_active():
@@ -1887,7 +1972,7 @@ class _GossipOptimizer:
         wire_now = self._metrics_wire(comm_now, hier, gossip_key)
         key = (
             "opt_step", self.order, self.communication_type, self._uid,
-            self._tx_version, ef, cap_bytes, met,
+            self._tx_version, ef, cap_bytes, direct, met,
         ) + tuple(gossip_key) + (
             # BLUEFOG_SHARD=0 leaves the key verbatim (bitwise shard-off
             # pin); an active layout keys on its full signature so a
@@ -1896,8 +1981,7 @@ class _GossipOptimizer:
         ) + scatter_key + _aval_key(params)
         fn = ctx.op_cache.get(key)
         if fn is None:
-            metrics_mod.counter("bluefog.recompiles").inc()
-            flight.record("compile", name="opt_step")
+            _record_step_built("opt_step", params, cap_bytes, direct)
             order = self.order
             tx = self._tx
 
@@ -1913,7 +1997,7 @@ class _GossipOptimizer:
                     order, tx, gossip_fn, wops, step, cap_bytes,
                     ef, ef_in, p, s, g, wire=wire_now, with_metrics=met,
                     shard=shard_l, scatter_wire=scatter_wire,
-                    scatter_chunks=scatter_chunks,
+                    scatter_chunks=scatter_chunks, direct=direct,
                 )
                 ef_out = jax.tree_util.tree_map(
                     lambda a: jnp.expand_dims(a, 0), ef_out
@@ -2125,8 +2209,8 @@ class _GossipOptimizer:
             comm_now = self._comm_now()
             (
                 hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-                cap_bytes,
-            ) = self._resolve_dispatch(ctx, params, comm_now)
+                cap_bytes, direct,
+            ) = self._resolve_dispatch(ctx, params, comm_now, flat=delayed)
             shard_l = None
             if comm_now and self._shard_active():
                 shard_l, opt_state = self._shard_prepare(
@@ -2164,7 +2248,7 @@ class _GossipOptimizer:
             key = (
                 "opt_fused_step", fused_uid, self.order,
                 self.communication_type, self._uid, self._tx_version, ef,
-                delay_now, cap_bytes, accum is not None, met,
+                delay_now, cap_bytes, direct, accum is not None, met,
             ) + tuple(gossip_key) + (
                 # same shard-key discipline as step(): absent when off
                 # (bitwise pin), full layout signature when on
@@ -2172,9 +2256,8 @@ class _GossipOptimizer:
             ) + scatter_key + _aval_key((params, opt_state, batch))
             fn = ctx.op_cache.get(key)
             if fn is None:
-                metrics_mod.counter("bluefog.recompiles").inc()
-                flight.record(
-                    "compile", name="opt_fused_step",
+                _record_step_built(
+                    "opt_fused_step", params, cap_bytes, direct,
                     differs_at=_first_difference(last_key[0], key),
                 )
                 order = self.order
@@ -2298,7 +2381,7 @@ class _GossipOptimizer:
                             ef, ef_in, p, s, grads,
                             wire=wire_now, with_metrics=met,
                             shard=shard_l, scatter_wire=scatter_wire,
-                            scatter_chunks=scatter_chunks,
+                            scatter_chunks=scatter_chunks, direct=direct,
                         )
                         ef_out = jax.tree_util.tree_map(
                             lambda a: jnp.expand_dims(a, 0), ef_out

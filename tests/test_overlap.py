@@ -336,6 +336,9 @@ def test_delayed_convergence_smoke(order):
     start_loss, start_dis = global_loss(p), disagreement(p)
     for _ in range(80):
         p, s, _loss = train_step(p, s, cvals)
+        # dependent steps queued without a sync can deadlock the CPU
+        # mesh's collective rendezvous (it aborts the worker after 40 s)
+        jax.block_until_ready(p)
     assert global_loss(p) < 0.05 * start_loss
     assert disagreement(p) < 0.1 and disagreement(p) < start_dis
 
@@ -391,6 +394,9 @@ def test_delayed_int8_quantized_converges():
     start = global_loss(p)
     for _ in range(80):
         p, s, _loss = train_step(p, s, cvals)
+        # dependent steps queued without a sync can deadlock the CPU
+        # mesh's collective rendezvous (it aborts the worker after 40 s)
+        jax.block_until_ready(p)
     assert global_loss(p) < 0.05 * start
 
 
@@ -417,19 +423,34 @@ def test_fused_is_one_cached_program():
     assert sum(1 for k in cache if k[0] == "opt_fused_step") == 1
 
 
-def test_fused_program_buckets_permutes(monkeypatch):
+# leaves (f32 element counts) -> messages a round, whether every one is capped
+PERMUTE_CASES = {
+    # many small leaves: packed and cut into capped buckets
+    "many_small": ([300] * 10, 6, True),
+    # one large leaf: alone and whole, one permute a round, over the cap
+    "one_large": ([3000], 1, False),
+    # both: the large leaf whole beside the small ones' buckets
+    "mixed": ([3000] + [300] * 10, 7, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PERMUTE_CASES))
+def test_fused_program_buckets_permutes(case, monkeypatch):
     """With a small cap the fused program's permute count is
-    n_buckets x rounds (each bucket issues its own plan rounds), and
-    every permute is over a capped payload."""
+    messages x rounds (each message issues its own plan rounds): the
+    leaves under the cap travel packed, in capped buckets; a leaf at or
+    over it travels alone and whole (PR 28 — before it this test pinned
+    "every permute <= cap" on the one 3000-element leaf, which the cap
+    then split into six)."""
     monkeypatch.setenv("BLUEFOG_BUCKET_BYTES", "2048")  # 512 f32 elems
-    n_elems = 3000
+    sizes, messages, all_capped = PERMUTE_CASES[case]
     rng = np.random.RandomState(0)
-    c = rng.randn(SIZE, n_elems).astype(np.float32)
-    params = {"w": bf.worker_values(lambda r: c[r])}
-    cvals = bf.worker_values(lambda r: c[r])
+    c = [rng.randn(SIZE, n).astype(np.float32) for n in sizes]
+    params = [bf.worker_values(lambda r, v=v: v[r]) for v in c]
+    cvals = [bf.worker_values(lambda r, v=v: v[r]) for v in c]
 
     def loss_fn(p, cv):
-        return 0.5 * jnp.sum((p["w"] - cv) ** 2)
+        return 0.5 * sum(jnp.sum((a - b) ** 2) for a, b in zip(p, cv))
 
     opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(0.1))
     p, s = params, opt.init(params)
@@ -438,13 +459,16 @@ def test_fused_program_buckets_permutes(monkeypatch):
     txt = _fused_hlo(opt, p, s, cvals)
     scan = scan_overlap(txt)
     rounds = 3  # ExponentialTwoGraph(8) -> log2(8) rounds
-    n_buckets = len(inner.bucket_bounds(n_elems, 4, 2048))
-    assert n_buckets == 6
+    small = sum(n for n in sizes if n * 4 < 2048)
+    n_alone = sum(n * 4 >= 2048 for n in sizes)
+    n_buckets = len(inner.bucket_bounds(small, 4, 2048)) if small else 0
+    assert n_alone + n_buckets == messages
     total = scan["async_pairs"] + scan["sync_collective_permutes"]
-    assert total == rounds * n_buckets, scan
-    assert all(
-        pm["payload_bytes"] <= 2048 for pm in scan["permutes"]
-    ), scan["permutes"]
+    assert total == rounds * messages, scan
+    payloads = sorted(pm["payload_bytes"] for pm in scan["permutes"])
+    assert all(b <= 2048 for b in payloads) == all_capped, payloads
+    if n_alone:
+        assert payloads[-rounds:] == [3000 * 4] * rounds, payloads
 
 
 def test_delayed_program_permutes_independent_of_compute():

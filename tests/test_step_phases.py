@@ -198,7 +198,11 @@ def test_differs_at_names_the_component_a_forced_miss_changed():
 
     params, state, loss = step(params, state, x)
     jax.block_until_ready(loss)
-    assert compiles() == [{"name": "opt_fused_step", "differs_at": None}]
+    # the toy's three small leaves are all packed (PR 28's two numbers)
+    assert compiles() == [{
+        "name": "opt_fused_step", "differs_at": None,
+        "direct_bytes": 0, "packed_bytes": 60,
+    }]
     params, state, loss = step(params, state, x)
     jax.block_until_ready(loss)
     assert len(compiles()) == 1  # a hit writes nothing
