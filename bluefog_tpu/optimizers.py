@@ -48,7 +48,7 @@ import contextlib
 import enum
 import itertools
 import time
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
@@ -382,13 +382,11 @@ def _combine_update(order, tx, gossip_fn, wops, step, cap_bytes,
                     ef, ef_state, p, s, g, wire=None, with_metrics=False,
                     shard=None, scatter_wire=None, scatter_chunks=(),
                     direct=False):
-    """The gossip+inner-update core shared by :meth:`_GossipOptimizer.step`
-    and the fused builder (:meth:`_GossipOptimizer.make_train_step`).
-
-    One implementation, two callers, so the fused train step is
-    bitwise-identical math to the legacy two-program path by construction
-    (pinned by tests/test_overlap.py). Runs inside a shard_map block on
-    UNSTACKED (per-worker) trees; returns ``(p, s, ef_state', mvec)``.
+    """The gossip+inner-update of one step, called from the one step body
+    (:meth:`_GossipOptimizer._build_step`) that :meth:`_GossipOptimizer.step`
+    and the fused ``train_step`` both dispatch. Runs inside a shard_map
+    block on UNSTACKED (per-worker) trees; returns ``(p, s, ef_state',
+    mvec)``.
 
     ``with_metrics=True`` additionally computes the gossip-health metric
     row (:func:`bluefog_tpu.metrics.build_probe_payload`) from the
@@ -691,6 +689,36 @@ def _timed_dispatch(name, fn, *args):
         tl.timeline_record_complete(name, "ENQUEUE", t0,
                                     tl.timeline_now_us() - t0)
         return out
+
+
+class _StepPlan(NamedTuple):
+    """What one call of :meth:`_GossipOptimizer.step` or of the fused
+    ``train_step`` resolved before it looks its program up
+    (:meth:`_GossipOptimizer._plan_step`): where the step runs, how it
+    gossips, which state rides it, and the parts of the cache key the two
+    program families share. Each family puts its own name and fields
+    round them, in its own order (``tests`` and ``flight``'s ``compile``
+    event read names and positions)."""
+
+    comm_now: bool
+    hier: bool
+    mesh: Any
+    spec: Any
+    gossip_key: tuple
+    gossip_fn: Any
+    wops: tuple  # host numpy; `_stage_operands` puts them on the chips
+    ef: bool  # the gossip's CHOCO copies ride this program
+    cap_bytes: int
+    direct: bool
+    shard_l: Any  # the active ZeRO layout, or None
+    scatter_wire: Optional[str]
+    scatter_chunks: tuple
+    scatter_ef: bool  # ZeRO-2's per-slot residuals ride this program
+    met_enabled: bool
+    met: bool  # this dispatch is the sampled one
+    wire_now: Optional[str]
+    key_ident: tuple  # (order, communication_type, uid, tx_version, ef)
+    key_tail: tuple  # gossip key + shard signature + scatter key
 
 
 _opt_uid = itertools.count()
@@ -1697,15 +1725,16 @@ class _GossipOptimizer:
             )
         return self._step_count % k == k - 1
 
-    def _resolve_dispatch(self, ctx, params, comm_now, flat=False):
-        """The dispatch prologue shared by :meth:`step` and the fused
-        builder: mesh/spec selection, gossip resolution, error-feedback
-        state. One implementation so a new communication type or
-        validation rule cannot reach one path and skip the other.
-        ``flat`` is a caller whose state is the packed flat payload (the
-        ``delayed=True`` buffers): no leaf travels alone there.
-        Returns ``(hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-        cap_bytes, direct)``."""
+    def _plan_step(self, ctx, params, opt_state, comm_now, flat=False):
+        """The dispatch prologue of :meth:`step` and of the fused
+        ``train_step``, as one :class:`_StepPlan`: mesh/spec selection,
+        gossip resolution, error-feedback state, the shard / scatter
+        prologue, metric sampling, and the cache-key parts the two
+        program families share. One implementation so a new
+        communication type or validation rule cannot reach one entry
+        point and skip the other. ``flat`` is a caller whose state is the
+        packed flat payload (the ``delayed=True`` buffers): no leaf
+        travels alone there."""
         self._validate_compression()
         direct = not flat and self._direct_route(ctx)
         hier = (
@@ -1735,10 +1764,47 @@ class _GossipOptimizer:
         ) and not self._scatter_active() and gossip_key[0] != "fed"
         if ef:
             self._ensure_ef_state(ctx, params, spec, gossip_key[2])
-        return (
-            hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-            inner.bucket_bytes_cap(), direct,
+        shard_l = None
+        if comm_now and self._shard_active():
+            shard_l, opt_state = self._shard_prepare(ctx, params, opt_state)
+        (
+            scatter_key, scatter_wire, scatter_chunks, scatter_ef,
+        ) = self._scatter_prologue(ctx, shard_l, spec)
+        met_enabled = metrics_mod.enabled() and comm_now
+        # Two-program sampling: only the 1-in-interval sampled step pays
+        # the metric computation — every other step dispatches a program
+        # whose cache key EQUALS the metrics-off key, so 9 of 10 steps
+        # are the metrics-off program by construction (the design that
+        # keeps BENCH_MODE=metrics under its 2% bound; an in-graph
+        # lax.cond was measured to drag every step).
+        met = met_enabled and (
+            self._comm_count % metrics_mod.metrics_interval() == 0
         )
+        plan = _StepPlan(
+            comm_now=comm_now, hier=hier, mesh=mesh, spec=spec,
+            gossip_key=gossip_key, gossip_fn=gossip_fn, wops=wops, ef=ef,
+            cap_bytes=inner.bucket_bytes_cap(), direct=direct,
+            shard_l=shard_l, scatter_wire=scatter_wire,
+            scatter_chunks=scatter_chunks, scatter_ef=scatter_ef,
+            met_enabled=met_enabled, met=met,
+            # the delayed probe measures the stale mix without a wire
+            # payload (no quant/EF slots): see _build_step's delayed_probe
+            wire_now=(
+                None if flat
+                else self._metrics_wire(comm_now, hier, gossip_key)
+            ),
+            key_ident=(
+                self.order, self.communication_type, self._uid,
+                self._tx_version, ef,
+            ),
+            # BLUEFOG_SHARD=0 leaves the key verbatim (bitwise shard-off
+            # pin); an active layout keys on its full signature so a
+            # membership change can never dispatch a stale owner map
+            key_tail=tuple(gossip_key) + (
+                shard_l.sig() if shard_l is not None else ()
+            ) + scatter_key,
+        )
+        return plan, opt_state
 
     # -- device-tier metrics plumbing ----------------------------------------
 
@@ -1949,156 +2015,295 @@ class _GossipOptimizer:
                 else self._tree_add(ctx, self._grad_accum, grads)
             )
             return params, opt_state
-        (
-            hier, mesh, spec, gossip_key, gossip_fn, wops, ef, cap_bytes,
-            direct,
-        ) = self._resolve_dispatch(ctx, params, comm_now)
-        shard_l = None
-        if comm_now and self._shard_active():
-            shard_l, opt_state = self._shard_prepare(ctx, params, opt_state)
-        (
-            scatter_key, scatter_wire, scatter_chunks, scatter_ef,
-        ) = self._scatter_prologue(ctx, shard_l, spec)
-        met_enabled = metrics_mod.enabled() and comm_now
-        # Two-program sampling: only the 1-in-interval sampled step pays
-        # the metric computation — every other step dispatches a program
-        # whose cache key EQUALS the metrics-off key, so 9 of 10 steps
-        # are the metrics-off program by construction (the design that
-        # keeps BENCH_MODE=metrics under its 2% bound; an in-graph
-        # lax.cond was measured to drag every step).
-        met = met_enabled and (
-            self._comm_count % metrics_mod.metrics_interval() == 0
-        )
-        wire_now = self._metrics_wire(comm_now, hier, gossip_key)
+        plan, opt_state = self._plan_step(ctx, params, opt_state, comm_now)
         key = (
-            "opt_step", self.order, self.communication_type, self._uid,
-            self._tx_version, ef, cap_bytes, direct, met,
-        ) + tuple(gossip_key) + (
-            # BLUEFOG_SHARD=0 leaves the key verbatim (bitwise shard-off
-            # pin); an active layout keys on its full signature so a
-            # membership change can never dispatch a stale owner map
-            shard_l.sig() if shard_l is not None else ()
-        ) + scatter_key + _aval_key(params)
+            "opt_step", *plan.key_ident, plan.cap_bytes, plan.direct,
+            plan.met, *plan.key_tail, *_aval_key(params),
+        )
         fn = ctx.op_cache.get(key)
         if fn is None:
-            _record_step_built("opt_step", params, cap_bytes, direct)
-            order = self.order
-            tx = self._tx
+            _record_step_built("opt_step", params, plan.cap_bytes, plan.direct)
+            fn = ctx.op_cache[key] = self._build_step(plan)
+        if comm_now and self.order == "grad" and self._grad_accum is not None:
+            grads = self._tree_add(ctx, self._grad_accum, grads)
+            self._grad_accum = None
+        flight.record("step_begin", step=self._step_count, comm=comm_now)
+        cur_comm, ef_in = self._begin_step(ctx, plan, key, params)
+        step_idx, wops = _stage_operands(plan.mesh, cur_comm, plan.wops)
+        doc_t0 = attribution.dispatch_timer(comm_now)
+        params_out, opt_state, ef_out, met_out = _timed_dispatch(
+            "optimizer_step", fn, params, opt_state, grads, step_idx,
+            wops, ef_in,
+        )
+        flight.record("step_dispatched", step=self._step_count - 1)
+        # this path always gossips the fresh iterate: payload age 0
+        self._finish_step(
+            ctx, plan, doc_t0, params, params_out, params_out, opt_state,
+            ef_out, met_out, grads=grads,
+        )
+        return params_out, opt_state
 
-            def body(params_b, state_b, grads_b, step, wops, ef_b):
-                p = _tree_block(params_b)
-                s = _tree_block(state_b)
-                g = _tree_block(grads_b)
-                step = step[0]
-                # unstack whichever EF state rides this program: the
-                # gossip CHOCO pairs or the ZeRO-2 per-slot residuals
+    # -- the step core: what step() and the fused train_step share -----------
+
+    def _build_step(self, plan, value_and_grad=None, has_aux=False,
+                    self_weight_fn=None, has_accum=False, n_batch=0):
+        """The compiled step program of ``plan``: block the operands, get
+        the gradients, run :func:`_combine_update` (or the
+        ``delayed=True`` stale mix), restack — one ``shard_map`` body and
+        one jit site for both entry points, so a change to the step
+        reaches both. What tells the two programs apart is where the
+        gradients come from. With ``value_and_grad`` (of the caller's
+        loss) they are computed inside the program: the fused
+        ``bf_step``, ``(params, state, step, wops, ef, delay buffers,
+        accumulator, *batch) -> (params, state, loss, aux, ef, delay
+        buffers — or the gradient, on an accumulation call —, metrics)``.
+        Without it they are an operand: :meth:`step`'s program,
+        ``(params, state, grads, step, wops, ef) -> (params, state, ef,
+        metrics)``. ``self_weight_fn`` makes it the ``delayed=True``
+        program; ``has_accum`` adds the host-side gradient accumulator
+        to the gradient inside it."""
+        order = self.order
+        tx = self._tx
+        comm_now, met = plan.comm_now, plan.met
+        gossip_fn, cap_bytes = plan.gossip_fn, plan.cap_bytes
+        fused = value_and_grad is not None
+        delay_now = self_weight_fn is not None
+
+        # its own name, not one more `body`: the name is the compiled
+        # module's (`jit_bf_step` in a device trace) and part of the
+        # persistent compile cache's key, which leaves metadata out
+        def bf_step(params_b, state_b, step, wops, ef_b, buf_b, accum_b,
+                    *batch_b):
+            p = _tree_block(params_b)
+            s = _tree_block(state_b)
+            bat = tuple(_tree_block(b) for b in batch_b)
+            step = step[0]
+            if delay_now:
+                # The stale combine's wire legs FIRST, on the carried
+                # buffers: these ppermutes depend on nothing this step
+                # computes, so the scheduler is free to run them under
+                # the forward/backward below. Only the cheap elementwise
+                # self-swap (see _self_weight_fn) touches fresh values.
+                bufs = tuple(b[0] for b in buf_b)
+                combined = tuple(
+                    _bucketed_flat_gossip(
+                        b, gossip_fn, step, wops, cap_bytes
+                    )
+                    for b in bufs
+                )
+                with jax.named_scope("bf.gossip"):
+                    sw = self_weight_fn(step, wops)
+
+                def stale_mix(tree):
+                    fresh = _pack_groups(tree)
+                    with jax.named_scope("bf.gossip"):
+                        mixed = tuple(
+                            c + sw.astype(c.dtype)
+                            * (x.astype(c.dtype) - b.astype(c.dtype))
+                            for c, x, b in zip(combined, fresh, bufs)
+                        )
+                    return _unpack_groups(tree, mixed)
+
+                def delayed_probe(tree, grads):
+                    """Metrics sub-gossip for the stale mix (same
+                    rationale as _combine_update's probe: never consume
+                    the big combine's outputs): re-run the mix on a
+                    512-aligned prefix of the carried buffer + fresh
+                    packs — bitwise the restriction of the full stale
+                    combine."""
+                    cap = metrics_mod.sample_elems_cap()
+                    pairs = []
+                    for gi, (f_sub, scale) in enumerate(
+                        _packed_prefix(tree, cap)
+                    ):
+                        k = f_sub.shape[0]
+                        b_sub = bufs[gi][:k]
+                        c_sub = _bucketed_flat_gossip(
+                            b_sub, gossip_fn, step, wops, cap_bytes,
+                        )
+                        y_sub = c_sub + sw.astype(c_sub.dtype) * (
+                            f_sub.astype(c_sub.dtype)
+                            - b_sub.astype(c_sub.dtype)
+                        )
+                        pairs.append((f_sub, y_sub, scale, None))
+                    return metrics_mod.build_probe_payload(
+                        pairs,
+                        _packed_prefix(grads, cap),
+                        wire=None,
+                    )
+            if fused:
+                with jax.named_scope("bf.loss_grad"):
+                    if has_aux:
+                        (loss, aux), grads = value_and_grad(p, *bat)
+                    else:
+                        loss, grads = value_and_grad(p, *bat)
+                        aux = ()
+            else:
+                (grads,) = bat
+            if fused and order == "grad" and not comm_now:
+                # accumulation call (step() accumulates on the host and
+                # builds no program for it): params/state untouched, the
+                # gradient comes OUT to the host-side accumulator
+                return (
+                    _tree_restack(p), _tree_restack(s),
+                    jnp.reshape(loss, (1,)),
+                    _tree_restack(aux) if has_aux else (),
+                    (), _tree_restack(grads), (),
+                )
+            if has_accum:
+                grads = jax.tree_util.tree_map(
+                    jnp.add, _tree_block(accum_b), grads
+                )
+            mvec = None
+            if delay_now:
+                if order == "cta":
+                    new_buf = _pack_groups(p)
+                    if met:
+                        # delayed mix: delta measured against the FRESH
+                        # iterate (wire/EF metrics have no stale-payload
+                        # form, see docs/metrics.md)
+                        mvec = delayed_probe(p, grads)
+                    p = stale_mix(p)
+                    p, s = _inner_update(tx, grads, s, p)
+                else:  # atc
+                    p, s = _inner_update(tx, grads, s, p)
+                    new_buf = _pack_groups(p)
+                    if met:
+                        mvec = delayed_probe(p, grads)
+                    p = stale_mix(p)
+                buf_out = tuple(jnp.expand_dims(b, 0) for b in new_buf)
+                ef_out = ()
+            else:
+                # unstack whichever EF state rides this program: gossip
+                # CHOCO pairs or the ZeRO-2 per-slot scatter residuals
                 ef_in = jax.tree_util.tree_map(lambda a: a[0], ef_b)
                 p, s, ef_out, mvec = _combine_update(
                     order, tx, gossip_fn, wops, step, cap_bytes,
-                    ef, ef_in, p, s, g, wire=wire_now, with_metrics=met,
-                    shard=shard_l, scatter_wire=scatter_wire,
-                    scatter_chunks=scatter_chunks, direct=direct,
+                    plan.ef, ef_in, p, s, grads,
+                    wire=plan.wire_now, with_metrics=met,
+                    shard=plan.shard_l, scatter_wire=plan.scatter_wire,
+                    scatter_chunks=plan.scatter_chunks, direct=plan.direct,
                 )
                 ef_out = jax.tree_util.tree_map(
                     lambda a: jnp.expand_dims(a, 0), ef_out
                 )
-                met_out = (
-                    (_tree_restack(mvec),) if met else ()
-                )
-                return _tree_restack(p), _tree_restack(s), ef_out, met_out
+                buf_out = ()
+            met_out = (_tree_restack(mvec),) if met else ()
+            p_out, s_out = _tree_restack(p), _tree_restack(s)
+            if not fused:
+                return p_out, s_out, ef_out, met_out
+            return (
+                p_out, s_out, jnp.reshape(loss, (1,)),
+                _tree_restack(aux) if has_aux else (),
+                ef_out, buf_out, met_out,
+            )
 
-            # "compile" phase watermark: the wrapper build is traced
-            # here; the XLA compile itself lands in the first
-            # dispatch's bracket (jit is lazy) — both attributed
-            with memory_mod.phase_scope("compile"):
-                fn = jax.jit(
-                    jax.shard_map(
-                        body,
-                        mesh=mesh,
-                        in_specs=(spec, spec, spec, P(), P(), spec),
-                        out_specs=(spec, spec, spec, spec),
-                    )
-                )
-            ctx.op_cache[key] = fn
-        if comm_now and self.order == "grad" and self._grad_accum is not None:
-            grads = self._tree_add(ctx, self._grad_accum, grads)
-            self._grad_accum = None
-        # dynamic schedules advance per COMMUNICATION, not per call, so a
-        # K>1 optimizer still walks every topology in the schedule
-        step_idx, wops = _stage_operands(mesh, self._comm_count, wops)
-        flight.record("step_begin", step=self._step_count, comm=comm_now)
-        self._step_count += 1
-        if comm_now:
-            self._comm_count += 1
-        if scatter_ef:
-            ef_in = self._scatter_ef
+        spec = plan.spec
+        if fused:
+            body, n_out = bf_step, 7
+            in_specs = (spec, spec, P(), P(), spec, spec, spec) + (
+                (spec,) * n_batch
+            )
         else:
-            ef_in = self._ef if ef else ()
-        if met_enabled:
+            # step()'s operand order; no delay buffer, no accumulator
+            def body(params_b, state_b, grads_b, step, wops, ef_b):
+                return bf_step(
+                    params_b, state_b, step, wops, ef_b, (), (), grads_b
+                )
+
+            n_out = 4
+            in_specs = (spec, spec, spec, P(), P(), spec)
+        # "compile" phase watermark: the wrapper build is traced here;
+        # the XLA compile itself lands in the first dispatch's bracket
+        # (jit is lazy) — both attributed
+        with memory_mod.phase_scope("compile"):
+            return jax.jit(
+                jax.shard_map(
+                    body, mesh=plan.mesh, in_specs=in_specs,
+                    out_specs=(spec,) * n_out,
+                )
+            )
+
+    def _begin_step(self, ctx, plan, key, params):
+        """Count this call and account its wire, before the dispatch (a
+        dispatch that raises has still taken its step). Returns the
+        communication index the dispatch runs at — dynamic schedules
+        advance per COMMUNICATION, not per call, so a K>1 optimizer
+        still walks every topology in the schedule — and the
+        error-feedback state that rides the program."""
+        cur_comm = self._comm_count
+        self._step_count += 1
+        if plan.comm_now:
+            self._comm_count += 1
+        if plan.met_enabled:
             self._record_comm_accounting(
-                key, gossip_key, params, ctx, shard=shard_l
+                key, plan.gossip_key, params, ctx, shard=plan.shard_l
             )
-        doc_t0 = attribution.dispatch_timer(comm_now)
-        params_out, opt_state, ef_out, met_out = _timed_dispatch(
-            "optimizer_step", fn, params, opt_state, grads, step_idx, wops,
-            ef_in,
-        )
-        flight.record("step_dispatched", step=self._step_count - 1)
-        if comm_now:
-            # attribution doctor (BLUEFOG_DOCTOR): purely host-side
-            # observation — the dispatched program above is untouched
-            attribution.observe_step(
-                ctx, step=self._step_count - 1, outputs=params_out,
-                plan=self._last_plan, params=params,
-                wire=self.compression,
-                dispatch_s=(
-                    time.perf_counter() - doc_t0
-                    if doc_t0 is not None else None
-                ),
-            )
-            # fleet health plane (BLUEFOG_HEALTH): same discipline —
-            # host arithmetic + its own tiny lane dispatches only
-            health_mod.observe_step(
-                ctx, step=self._step_count - 1, plan=self._last_plan,
-            )
-            # staleness observatory (BLUEFOG_STALENESS): the two-program
-            # path always gossips the fresh iterate — delivered age 0,
-            # the lane's per-sample self-check
-            staleness_mod.observe_step(
-                ctx, step=self._step_count - 1, plan=self._last_plan,
-                payload_age=0, surface="sync",
-            )
-            # autotune controller (BLUEFOG_AUTOTUNE): host-side
-            # decision logic only; a migration it makes lands as a
-            # topology-version bump this step path re-resolves next
-            # dispatch, exactly like an elastic repair
-            autotune_mod.observe_step(
-                ctx, step=self._step_count - 1, optimizer=self,
-                plan=self._last_plan,
-            )
-            # memory observatory (BLUEFOG_MEMORY): host-side census of
-            # the buffers THIS dispatch left live — the program above
-            # is untouched (same cache key, bitwise pin)
-            memory_mod.observe_step(
-                ctx, step=self._step_count - 1, optimizer=self,
-                params=params_out, opt_state=opt_state, grads=grads,
-            )
-            # SLO engine (BLUEFOG_SLO): evaluates LAST so its sampled
-            # pass reads the gauges the tiers above just refreshed;
-            # its canary probe dispatches in its own op-cache family —
-            # the training program above is untouched (same cache
-            # key, bitwise pin)
-            slo_mod.observe_step(
-                ctx, step=self._step_count - 1, plan=self._last_plan,
-                wire=self.compression,
-            )
-        if ef:
+        if plan.scatter_ef:
+            return cur_comm, self._scatter_ef
+        return cur_comm, (self._ef if plan.ef else ())
+
+    def _finish_step(self, ctx, plan, doc_t0, params, outputs, params_out,
+                     state_out, ef_out, met_out, grads=None, payload_age=0,
+                     surface="sync"):
+        """The epilogue of a dispatched step, for both entry points: keep
+        the error-feedback state the program returned, start the sampled
+        metric row's drain, and after a communicating step call each of
+        the six observers once, in this order. Every one of them is
+        host-side observation (plus, at most, tiny probe dispatches in
+        an op-cache family of its own): the training program above is
+        untouched — same cache key, same bits. ``outputs`` is what the
+        attribution doctor waits on when it times a step (``step()``'s
+        new parameters, the fused step's loss); ``grads`` joins the
+        memory census where they are a buffer of the caller's;
+        ``payload_age`` / ``surface`` say what the combine consumed
+        (the fresh iterate, or ``delayed=True``'s double buffer)."""
+        if plan.ef:
             self._ef = ef_out
-        elif scatter_ef:
+        elif plan.scatter_ef:
             self._scatter_ef = ef_out
-        if met:
-            self._drain_after_sample(wire_now, met_out[0])
-        return params_out, opt_state
+        if plan.met:
+            self._drain_after_sample(plan.wire_now, met_out[0])
+        if not plan.comm_now:
+            return
+        step = self._step_count - 1
+        last_plan = self._last_plan
+        # attribution doctor (BLUEFOG_DOCTOR)
+        attribution.observe_step(
+            ctx, step=step, outputs=outputs, plan=last_plan, params=params,
+            wire=self.compression,
+            dispatch_s=(
+                time.perf_counter() - doc_t0 if doc_t0 is not None else None
+            ),
+        )
+        # fleet health plane (BLUEFOG_HEALTH): host arithmetic + its own
+        # tiny lane dispatches only
+        health_mod.observe_step(ctx, step=step, plan=last_plan)
+        # staleness observatory (BLUEFOG_STALENESS): stamps the payload's
+        # birth and folds the delivered ages; age 0 is the lane's
+        # per-sample self-check
+        staleness_mod.observe_step(
+            ctx, step=step, plan=last_plan, payload_age=payload_age,
+            surface=surface,
+        )
+        # autotune controller (BLUEFOG_AUTOTUNE): decision logic only; a
+        # migration it makes lands as a topology-version bump that the
+        # next dispatch re-resolves, exactly like an elastic repair
+        autotune_mod.observe_step(
+            ctx, step=step, optimizer=self, plan=last_plan,
+        )
+        # memory observatory (BLUEFOG_MEMORY): census of the buffers THIS
+        # dispatch left live (params + optax state + EF/delay copies)
+        memory_mod.observe_step(
+            ctx, step=step, optimizer=self, params=params_out,
+            opt_state=state_out, grads=grads,
+        )
+        # SLO engine (BLUEFOG_SLO): LAST, so its sampled pass reads the
+        # gauges the tiers above just refreshed; its canary probe
+        # dispatches in its own op-cache family
+        slo_mod.observe_step(
+            ctx, step=step, plan=last_plan, wire=self.compression,
+        )
 
     # -- the fused train step (overlap layer) --------------------------------
 
@@ -2159,9 +2364,16 @@ class _GossipOptimizer:
         hiding the transfer (the in-XLA analogue of the reference's
         backward-hook overlap, torch/optimizers.py:166-1554, and of the
         fused weight-update design in "Automatic Cross-Replica Sharding
-        of Weight Update in Data-Parallel Training"). The math is the
-        shared :func:`_combine_update` core, so fused and two-program
-        paths are bitwise-identical (tests/test_overlap.py).
+        of Weight Update in Data-Parallel Training"). This callable and
+        :meth:`step` are two entry points over one step core
+        (:meth:`_plan_step`, :meth:`_build_step`, :meth:`_finish_step`):
+        the same body, with the gradient computed inside the program
+        here and an operand there. Where no matmul precedes the update
+        the two agree to the bit (tests/test_step_core.py); behind a
+        model's backward pass, under momentum, to a few float32 ulp,
+        because XLA may round the update differently when it fuses it
+        into the gradient's last kernel (tests/test_overlap.py states
+        the tolerance).
 
         ``delayed=True`` (ATC/CTA only) takes communication off the
         critical path entirely: the combine at step k mixes the payload
@@ -2207,19 +2419,10 @@ class _GossipOptimizer:
                     "wire (None/'int8'/'bf16'/'int4')"
                 )
             comm_now = self._comm_now()
-            (
-                hier, mesh, spec, gossip_key, gossip_fn, wops, ef,
-                cap_bytes, direct,
-            ) = self._resolve_dispatch(ctx, params, comm_now, flat=delayed)
-            shard_l = None
-            if comm_now and self._shard_active():
-                shard_l, opt_state = self._shard_prepare(
-                    ctx, params, opt_state
-                )
-            (
-                scatter_key, scatter_wire, scatter_chunks, scatter_ef,
-            ) = self._scatter_prologue(ctx, shard_l, spec)
-            if delayed and hier:
+            plan, opt_state = self._plan_step(
+                ctx, params, opt_state, comm_now, flat=delayed
+            )
+            if delayed and plan.hier:
                 raise ValueError(
                     "delayed=True is not supported for hierarchical "
                     "communication (the intra-machine psum leg has no "
@@ -2231,208 +2434,42 @@ class _GossipOptimizer:
                 self._self_weight_fn(ctx) if delay_now else None
             )
             if delay_now:
-                self._ensure_delay_state(ctx, mesh, params, spec, gossip_key)
+                self._ensure_delay_state(
+                    ctx, plan.mesh, params, plan.spec, plan.gossip_key
+                )
             accum = (
                 self._grad_accum
                 if comm_now and self.order == "grad" else None
             )
-            met_enabled = metrics_mod.enabled() and comm_now
-            # two-program sampling, same rationale as in step(): only
-            # the 1-in-interval sampled dispatch compiles/pays for the
-            # metric outputs; the rest share the metrics-off program
-            met = met_enabled and (
-                self._comm_count % metrics_mod.metrics_interval() == 0
-            )
-            wire_now = self._metrics_wire(comm_now, hier, gossip_key)
             phases.enter("key")
             key = (
-                "opt_fused_step", fused_uid, self.order,
-                self.communication_type, self._uid, self._tx_version, ef,
-                delay_now, cap_bytes, direct, accum is not None, met,
-            ) + tuple(gossip_key) + (
-                # same shard-key discipline as step(): absent when off
-                # (bitwise pin), full layout signature when on
-                shard_l.sig() if shard_l is not None else ()
-            ) + scatter_key + _aval_key((params, opt_state, batch))
+                "opt_fused_step", fused_uid, *plan.key_ident, delay_now,
+                plan.cap_bytes, plan.direct, accum is not None, plan.met,
+                *plan.key_tail,
+                *_aval_key((params, opt_state, batch)),
+            )
             fn = ctx.op_cache.get(key)
             if fn is None:
                 _record_step_built(
-                    "opt_fused_step", params, cap_bytes, direct,
+                    "opt_fused_step", params, plan.cap_bytes, plan.direct,
                     differs_at=_first_difference(last_key[0], key),
                 )
-                order = self.order
-                tx = self._tx
-                has_accum = accum is not None
-
-                # its own name, not one more `body`: the name is the compiled
-                # module's (`jit_bf_step` in a device trace) and part of the
-                # persistent compile cache's key, which leaves metadata out
-                def bf_step(params_b, state_b, step, wops, ef_b, buf_b,
-                            accum_b, *batch_b):
-                    p = _tree_block(params_b)
-                    s = _tree_block(state_b)
-                    bat = tuple(_tree_block(b) for b in batch_b)
-                    step = step[0]
-                    if delay_now:
-                        # The stale combine's wire legs FIRST, on the
-                        # carried buffers: these ppermutes depend on
-                        # nothing this step computes, so the scheduler is
-                        # free to run them under the forward/backward
-                        # below. Only the cheap elementwise self-swap
-                        # (see _self_weight_fn) touches fresh values.
-                        bufs = tuple(b[0] for b in buf_b)
-                        combined = tuple(
-                            _bucketed_flat_gossip(
-                                b, gossip_fn, step, wops, cap_bytes
-                            )
-                            for b in bufs
-                        )
-                        with jax.named_scope("bf.gossip"):
-                            sw = self_weight_fn(step, wops)
-
-                        def stale_mix(tree):
-                            fresh = _pack_groups(tree)
-                            with jax.named_scope("bf.gossip"):
-                                mixed = tuple(
-                                    c + sw.astype(c.dtype)
-                                    * (x.astype(c.dtype) - b.astype(c.dtype))
-                                    for c, x, b in zip(combined, fresh, bufs)
-                                )
-                            return _unpack_groups(tree, mixed)
-
-                        def delayed_probe(tree, grads):
-                            """Metrics sub-gossip for the stale mix
-                            (same rationale as _combine_update's probe:
-                            never consume the big combine's outputs):
-                            re-run the mix on a 512-aligned prefix of
-                            the carried buffer + fresh packs — bitwise
-                            the restriction of the full stale combine."""
-                            cap = metrics_mod.sample_elems_cap()
-                            pairs = []
-                            for gi, (f_sub, scale) in enumerate(
-                                _packed_prefix(tree, cap)
-                            ):
-                                k = f_sub.shape[0]
-                                b_sub = bufs[gi][:k]
-                                c_sub = _bucketed_flat_gossip(
-                                    b_sub, gossip_fn, step, wops,
-                                    cap_bytes,
-                                )
-                                y_sub = c_sub + sw.astype(c_sub.dtype) * (
-                                    f_sub.astype(c_sub.dtype)
-                                    - b_sub.astype(c_sub.dtype)
-                                )
-                                pairs.append((f_sub, y_sub, scale, None))
-                            return metrics_mod.build_probe_payload(
-                                pairs,
-                                _packed_prefix(grads, cap),
-                                wire=None,
-                            )
-                    with jax.named_scope("bf.loss_grad"):
-                        if has_aux:
-                            (loss, aux), grads = value_and_grad(p, *bat)
-                        else:
-                            loss, grads = value_and_grad(p, *bat)
-                            aux = ()
-                    if order == "grad" and not comm_now:
-                        # accumulation call: params/state untouched, the
-                        # gradient comes OUT to the host-side accumulator
-                        return (
-                            _tree_restack(p), _tree_restack(s),
-                            jnp.reshape(loss, (1,)),
-                            _tree_restack(aux) if has_aux else (),
-                            (), _tree_restack(grads), (),
-                        )
-                    if has_accum:
-                        grads = jax.tree_util.tree_map(
-                            jnp.add, _tree_block(accum_b), grads
-                        )
-                    mvec = None
-                    if delay_now:
-                        if order == "cta":
-                            new_buf = _pack_groups(p)
-                            if met:
-                                # delayed mix: delta measured against
-                                # the FRESH iterate (wire/EF metrics
-                                # have no stale-payload form, see
-                                # docs/metrics.md)
-                                mvec = delayed_probe(p, grads)
-                            p = stale_mix(p)
-                            p, s = _inner_update(tx, grads, s, p)
-                        else:  # atc
-                            p, s = _inner_update(tx, grads, s, p)
-                            new_buf = _pack_groups(p)
-                            if met:
-                                mvec = delayed_probe(p, grads)
-                            p = stale_mix(p)
-                        buf_out = tuple(
-                            jnp.expand_dims(b, 0) for b in new_buf
-                        )
-                        ef_out = ()
-                    else:
-                        # unstack whichever EF state rides this
-                        # program: gossip CHOCO pairs or the ZeRO-2
-                        # per-slot scatter residuals
-                        ef_in = jax.tree_util.tree_map(
-                            lambda a: a[0], ef_b
-                        )
-                        p, s, ef_out, mvec = _combine_update(
-                            order, tx, gossip_fn, wops, step, cap_bytes,
-                            ef, ef_in, p, s, grads,
-                            wire=wire_now, with_metrics=met,
-                            shard=shard_l, scatter_wire=scatter_wire,
-                            scatter_chunks=scatter_chunks, direct=direct,
-                        )
-                        ef_out = jax.tree_util.tree_map(
-                            lambda a: jnp.expand_dims(a, 0), ef_out
-                        )
-                        buf_out = ()
-                    met_out = (
-                        (_tree_restack(mvec),) if met else ()
-                    )
-                    return (
-                        _tree_restack(p), _tree_restack(s),
-                        jnp.reshape(loss, (1,)),
-                        _tree_restack(aux) if has_aux else (),
-                        ef_out, buf_out, met_out,
-                    )
-
-                n_batch = len(batch)
-                fn = jax.jit(
-                    jax.shard_map(
-                        bf_step,
-                        mesh=mesh,
-                        in_specs=(spec, spec, P(), P(), spec, spec, spec)
-                        + (spec,) * n_batch,
-                        out_specs=(
-                            spec, spec, spec, spec, spec, spec, spec,
-                        ),
-                    )
+                fn = ctx.op_cache[key] = self._build_step(
+                    plan, value_and_grad, has_aux, self_weight_fn,
+                    accum is not None, len(batch),
                 )
-                ctx.op_cache[key] = fn
             last_key[0] = key
             phases.enter("stage", comm=comm_now, fused=True)  # step_begin
-            # the comm index THIS dispatch runs at, and the age of the
-            # payload its combine consumes: 0 on the fresh path, comm
-            # steps since the delay buffer was written on the delayed
-            # path (1 in steady state, 0 right after a reseed)
-            cur_comm = self._comm_count
+            cur_comm, ef_in = self._begin_step(ctx, plan, key, params)
+            # the age of the payload this dispatch's combine consumes: 0
+            # on the fresh path, comm steps since the delay buffer was
+            # written on the delayed path (1 in steady state, 0 right
+            # after a reseed)
             payload_age = (
                 cur_comm - self._delay_birth_comm if delay_now else 0
             )
-            self._step_count += 1
-            if comm_now:
-                self._comm_count += 1
-            if scatter_ef:
-                ef_in = self._scatter_ef
-            else:
-                ef_in = self._ef if ef else ()
             buf_in = self._delay_buf if delay_now else ()
             accum_in = accum if accum is not None else ()
-            if met_enabled:
-                self._record_comm_accounting(
-                    key, gossip_key, params, ctx, shard=shard_l
-                )
             # single source of truth for debug/evidence lowering
             # (lower_last_fused_hlo): the compiled fn plus exactly the
             # operand structure this dispatch used — as avals, not live
@@ -2449,15 +2486,15 @@ class _GossipOptimizer:
                     ), op,
                 )
 
-            replicated = NamedSharding(mesh, P())
+            replicated = NamedSharding(plan.mesh, P())
             self._last_fused_step = jax.ShapeDtypeStruct(
                 (1,), jnp.int32, sharding=replicated
             )
             self._last_fused = (
-                fn, avals(wops, replicated), avals(ef_in), avals(buf_in),
-                avals(accum_in),
+                fn, avals(plan.wops, replicated), avals(ef_in),
+                avals(buf_in), avals(accum_in),
             )
-            step_idx, wops = _stage_operands(mesh, cur_comm, wops)
+            step_idx, wops = _stage_operands(plan.mesh, cur_comm, plan.wops)
             doc_t0 = attribution.dispatch_timer(comm_now)
             phases.enter("enqueue")
             params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
@@ -2467,77 +2504,32 @@ class _GossipOptimizer:
                 )
             )
             phases.enter("epilogue")  # step_dispatched
-            if self.order == "grad" and not comm_now:
-                # accumulation call: the gradient comes out where the
-                # delay buffer would
-                self._grad_accum = (
-                    buf_o if self._grad_accum is None
-                    else self._tree_add(ctx, self._grad_accum, buf_o)
-                )
-            else:
-                if ef:
-                    self._ef = ef_o
-                elif scatter_ef:
-                    self._scatter_ef = ef_o
-                if delay_now:
-                    self._delay_buf = buf_o
-                if comm_now and self.order == "grad":
+            if self.order == "grad":
+                # an accumulation call's gradient comes out where the
+                # delay buffer would; the communicating call consumed
+                # the accumulator
+                if comm_now:
                     self._grad_accum = None
-                if met:
-                    # the delayed probe measures the stale mix without a
-                    # wire payload (no quant/EF slots) — see delayed_probe
-                    self._drain_after_sample(
-                        None if delay_now else wire_now, met_o[0]
+                elif self._grad_accum is None:
+                    self._grad_accum = buf_o
+                else:
+                    self._grad_accum = self._tree_add(
+                        ctx, self._grad_accum, buf_o
                     )
-            if comm_now:
-                # attribution doctor: host-side only, program untouched
-                attribution.observe_step(
-                    ctx, step=self._step_count - 1, outputs=loss,
-                    plan=self._last_plan, params=params,
-                    wire=self.compression,
-                    dispatch_s=(
-                        time.perf_counter() - doc_t0
-                        if doc_t0 is not None else None
-                    ),
-                )
-                # fleet health plane: same host-side-only discipline
-                health_mod.observe_step(
-                    ctx, step=self._step_count - 1,
-                    plan=self._last_plan,
-                )
-                # staleness observatory: stamp the payload's REAL birth
-                # (the delayed path gossips the double-buffered
-                # previous iterate) and fold the delivered ages
-                staleness_mod.observe_step(
-                    ctx, step=self._step_count - 1,
-                    plan=self._last_plan, payload_age=payload_age,
-                    surface="delayed" if delay_now else "sync",
-                )
-                # autotune controller: host-side decision logic only —
-                # a migration lands as a topology-version bump the
-                # fused path re-resolves next dispatch
-                autotune_mod.observe_step(
-                    ctx, step=self._step_count - 1, optimizer=self,
-                    plan=self._last_plan,
-                )
-                # memory observatory: census of this dispatch's live
-                # buffers (params + optax state + EF/delay copies),
-                # host-side only
-                memory_mod.observe_step(
-                    ctx, step=self._step_count - 1, optimizer=self,
-                    params=params_o, opt_state=state_o,
-                )
-                # SLO engine: last, same discipline as the two-program
-                # path — reads the tiers above, canary in its own
-                # op-cache family, training program untouched
-                slo_mod.observe_step(
-                    ctx, step=self._step_count - 1,
-                    plan=self._last_plan, wire=self.compression,
-                )
-                if delay_now:
-                    # the dispatch above refilled the double buffer
-                    # with this step's payload
-                    self._delay_birth_comm = cur_comm
+            elif delay_now:
+                self._delay_buf = buf_o
+            # the staleness observatory gets the payload's REAL birth:
+            # the delayed path gossips the double-buffered previous
+            # iterate
+            self._finish_step(
+                ctx, plan, doc_t0, params, loss, params_o, state_o, ef_o,
+                met_o, payload_age=payload_age,
+                surface="delayed" if delay_now else "sync",
+            )
+            if delay_now:
+                # the dispatch above refilled the double buffer with
+                # this step's payload
+                self._delay_birth_comm = cur_comm
             if has_aux:
                 return params_o, state_o, (loss, aux)
             return params_o, state_o, loss

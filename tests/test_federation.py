@@ -470,11 +470,10 @@ def test_fed_ef_wire_falls_back_memoryless(fresh_context, monkeypatch):
     key, _fn, _wops = opt._gossip_key_and_fn(ctx)
     assert key[2] == "int8"  # memoryless base tier
     assert "fed-ef-wire" in logging_util._warned_once
-    # _resolve_dispatch must not allocate CHOCO state on a fed key
+    # _plan_step must not allocate CHOCO state on a fed key
     params = {"w": bf.worker_values(lambda r: jnp.zeros((8,)))}
-    out = opt._resolve_dispatch(ctx, params, True)
-    ef = out[6]
-    assert ef is False
+    plan, _state = opt._plan_step(ctx, params, None, True)
+    assert plan.ef is False
 
 
 def test_flat_run_after_fed_env_removed(fresh_context, monkeypatch):

@@ -2,12 +2,18 @@
 """Overlap-layer tests: the fused train step, bucketed gossip, the
 delayed (one-step-stale) combine, and the static HLO overlap scan.
 
-The load-bearing guarantee is bitwise equivalence: ``make_train_step``
-fuses forward/backward/update/gossip into one program for SCHEDULING
-reasons only — the math must be byte-for-byte the legacy two-program
-path (grad program + ``opt.step``), with and without wire bucketing.
-Fusing or bucketing that changed a single ULP would silently break the
-bit-identical-replica invariant the compression paths rely on.
+The load-bearing guarantee: ``make_train_step`` fuses
+forward/backward/update/gossip into one program for SCHEDULING reasons
+only — it runs the body the two-program path runs (grad program +
+``opt.step``; one builder, ``_GossipOptimizer._build_step``), with and
+without wire bucketing. Bucketing never changes a bit, nor does fusing
+wherever the compiler has nothing to round differently (plain SGD, the
+int4 wire, gradient allreduce, the first momentum step); under momentum
+behind a model's backward pass the two agree to a stated few ulp
+(``test_fused_bitwise_matches_two_program`` says why). A wire that changed
+a single ULP between replicas would break the bit-identical-replica
+invariant the compression paths rely on: an invariant across the
+replicas of one program, not across two programs.
 """
 
 import numpy as np
@@ -101,15 +107,48 @@ FACTORIES = {
 }
 
 
+FUSION_ULPS = 16
+
+
+def assert_trees_within_ulps(a, b, ulps=FUSION_ULPS):
+    """Leaf by leaf: max |a - b| <= ``ulps`` float32 ulp at the leaf's
+    largest magnitude."""
+    la = jax.tree_util.tree_leaves(a)
+    lb = jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype == np.float32
+        ulp = np.spacing(np.float32(max(np.abs(x).max(), np.abs(y).max())))
+        worst = np.abs(x - y).max()
+        assert worst <= ulps * ulp, (worst, ulp)
+
+
 @pytest.mark.parametrize("order", ["cta", "atc"])
 @pytest.mark.parametrize("schedule", ["static", "dynamic"])
 @pytest.mark.parametrize("bucketed", [False, True])
 def test_fused_bitwise_matches_two_program(order, schedule, bucketed,
                                            monkeypatch):
-    """make_train_step == grad-program + opt.step, to the bit, on a small
-    transformer — for ATC and CTA, static and dynamic schedules, with
-    and without wire bucketing (the fusion is a scheduling change, never
-    a numerics change)."""
+    """make_train_step == grad-program + opt.step on a small transformer
+    under momentum SGD — for ATC and CTA, static and dynamic schedules,
+    with and without wire bucketing: to the bit after the first step, and
+    after three within ``FUSION_ULPS`` float32 ulp of each leaf's largest
+    magnitude.
+
+    Why not to the bit throughout: both entry points run ONE body
+    (``_GossipOptimizer._build_step``), so the bits that can differ are
+    the compiler's. Inside one program XLA:CPU fuses the momentum update
+    ``g + 0.9 t`` behind the gradient's last kernel and rounds it
+    differently than it does as a program of its own. On the first step
+    ``t`` is zero and there is nothing to round; after the second the two
+    differ by 1 ulp in under 1 % of the elements; the third step's
+    backward pass then starts from parameters 1 ulp apart. Read when the
+    tolerance was set, over the eight cases: 3.25 ulp on the parameters,
+    6 on the momentum, largest |a - b| 1.8e-07. The pin that stays exact
+    over three steps, and fails if the two entry points' math ever parts,
+    is tests/test_step_core.py's linear loss, where no matmul precedes
+    the update. The sibling pins below (plain SGD, int4, gradient
+    allreduce) stay bitwise."""
     monkeypatch.setenv(
         "BLUEFOG_BUCKET_BYTES", "2048" if bucketed else "0"
     )
@@ -136,12 +175,15 @@ def test_fused_bitwise_matches_two_program(order, schedule, bucketed,
     s2 = opt2.init(p2)
     train_step = opt2.make_train_step(loss_fn)
 
-    for _ in range(3):
+    for k in range(3):
         g = grad_fn(p1, tokens)
         p1, s1 = opt1.step(p1, s1, g)
         p2, s2, loss = train_step(p2, s2, tokens)
-    assert_trees_bitwise(p1, p2)
-    assert_trees_bitwise(s1, s2)
+        if k == 0:
+            assert_trees_bitwise(p1, p2)
+            assert_trees_bitwise(s1, s2)
+    assert_trees_within_ulps(p1, p2)
+    assert_trees_within_ulps(s1, s2)
     assert np.isfinite(np.asarray(loss)).all()
 
 
