@@ -138,7 +138,7 @@ from bluefog_tpu.collective.ops import (
 
 
 def make_train_step(optimizer, loss_fn, has_aux: bool = False,
-                    delayed: bool = False):
+                    delayed: bool = False, donate: bool = True):
     """Compile ``loss_fn`` + backward + inner update + gossip into ONE
     program so XLA can overlap the ppermute rounds with compute.
 
@@ -153,9 +153,19 @@ def make_train_step(optimizer, loss_fn, has_aux: bool = False,
     (one-step-stale gossip), removing communication from the critical path
     entirely; see :meth:`bluefog_tpu.optimizers._GossipOptimizer.make_train_step`
     and docs/performance.md for semantics and the staleness caveat.
+
+    The call **consumes** ``params`` and ``opt_state`` (``donate=True``,
+    the default): the compiled step writes the new parameters and state
+    into the buffers it was given, so after the call the arrays passed in
+    are deleted and the returned ones take their place — rebind both, as
+    the loop above does. To read an input again after the call, copy it
+    first (``jax.tree_util.tree_map(jnp.copy, params)``) or pass
+    ``donate=False``, which keeps the inputs alive at the cost of a second
+    copy of parameters and state on the device and one buffer allocation
+    per output leaf per call. ``optimizer.step`` never donates.
     """
     return optimizer.make_train_step(
-        loss_fn, has_aux=has_aux, delayed=delayed
+        loss_fn, has_aux=has_aux, delayed=delayed, donate=donate
     )
 
 

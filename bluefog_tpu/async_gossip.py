@@ -919,7 +919,8 @@ def make_async_train_step(opt, loss_fn, has_aux: bool = False,
                           max_age: Optional[int] = None,
                           policy: Optional[str] = None,
                           wire: Optional[str] = None,
-                          enabled: Optional[bool] = None):
+                          enabled: Optional[bool] = None,
+                          donate: bool = True):
     """Build the fully asynchronous train step (``bf.
     make_async_train_step``): per-rank-cadence push-sum gossip where no
     rank ever waits on a peer.
@@ -928,8 +929,11 @@ def make_async_train_step(opt, loss_fn, has_aux: bool = False,
     optax transformation drives the local updates, and its
     ``compression`` knob seeds the wire tier. With async OFF
     (``enabled=False`` or ``BLUEFOG_ASYNC=0``) this returns
-    ``opt.make_train_step(loss_fn, has_aux=...)`` — the current
-    synchronous path, bitwise identical by construction.
+    ``opt.make_train_step(loss_fn, has_aux=..., donate=...)`` — the
+    current synchronous path, bitwise identical by construction, which
+    like it consumes ``params`` and ``opt_state`` unless ``donate=False``
+    (the asynchronous engine's own programs keep their inputs, and
+    ``donate`` does not reach them).
 
     With async ON the returned callable has the same signature
     (``step(params, opt_state, *batch) -> (params, opt_state, loss)``)
@@ -941,7 +945,7 @@ def make_async_train_step(opt, loss_fn, has_aux: bool = False,
     """
     on = async_enabled() if enabled is None else bool(enabled)
     if not on:
-        return opt.make_train_step(loss_fn, has_aux=has_aux)
+        return opt.make_train_step(loss_fn, has_aux=has_aux, donate=donate)
     global _active
     engine = AsyncGossipEngine(
         opt, loss_fn, has_aux=has_aux, cadence=cadence,

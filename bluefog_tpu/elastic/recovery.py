@@ -694,9 +694,9 @@ class ElasticGuard:
         return self.optimizer.step(*args, **kwargs)
 
     def make_train_step(self, loss_fn, has_aux: bool = False,
-                        delayed: bool = False):
+                        delayed: bool = False, donate: bool = True):
         inner = self.optimizer.make_train_step(
-            loss_fn, has_aux=has_aux, delayed=delayed
+            loss_fn, has_aux=has_aux, delayed=delayed, donate=donate
         )
 
         def train_step(params, opt_state, *batch):

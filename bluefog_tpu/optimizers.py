@@ -47,7 +47,9 @@ worker-stacked pytrees (leading axis = worker), the same convention as
 import contextlib
 import enum
 import itertools
+import re
 import time
+import warnings
 from typing import Any, NamedTuple, Optional
 
 import numpy as np
@@ -593,21 +595,61 @@ def _gossip_messages(params, cap_bytes, direct):
     return out
 
 
-def _record_step_built(name, params, cap_bytes, direct, **data):
+def _record_step_built(name, params, cap_bytes, direct, donated=(),
+                       **data):
     """A step program is about to be built: count it, say how its gossip
     routes the parameters (gauges ``bluefog.gossip_direct_bytes`` /
-    ``bluefog.gossip_packed_bytes``, per worker), and put both on the
-    flight ring's ``compile`` event."""
+    ``bluefog.gossip_packed_bytes``, per worker) and how much of its
+    carry it writes in place (``bluefog.step_donated_bytes``: per worker,
+    the bytes of the ``donated`` operands; 0 for a program that keeps its
+    inputs), and put all three on the flight ring's ``compile`` event."""
     routes = _gossip_routes(params, cap_bytes, direct)
     direct_bytes = sum(item * sum(alone) for item, alone, _ in routes)
     packed_bytes = sum(item * packed for item, _, packed in routes)
+    donated_bytes = sum(
+        leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(donated)
+    )
     metrics_mod.counter("bluefog.recompiles").inc()
     metrics_mod.gauge("bluefog.gossip_direct_bytes").set(direct_bytes)
     metrics_mod.gauge("bluefog.gossip_packed_bytes").set(packed_bytes)
+    metrics_mod.gauge("bluefog.step_donated_bytes").set(donated_bytes)
+    # nothing known unusable yet: the first dispatch lowers the program
+    metrics_mod.gauge("bluefog.step_donation_unused").set(0)
     flight.record(
         "compile", name=name, direct_bytes=direct_bytes,
-        packed_bytes=packed_bytes, **data,
+        packed_bytes=packed_bytes, donated_bytes=donated_bytes, **data,
     )
+
+
+def _check_donated_once(donated):
+    """Raise if two of the leaves a step is about to donate are one
+    buffer (a state that holds the parameters themselves, a tied weight
+    stored twice): the runtime refuses such a dispatch only in
+    ``Execute()``, on some backends after part of the mesh has started,
+    and names no leaf. Read where the step is built — a cache miss — so
+    the hot path walks nothing."""
+    seen = {}
+    for i, leaf in enumerate(jax.tree_util.tree_leaves(donated)):
+        if not isinstance(leaf, jax.Array) or leaf.is_deleted():
+            # a shape (an off-chip compile calls the step with
+            # ShapeDtypeStructs) has no buffer; an array handed in again
+            # after an earlier call consumed it is the dispatch's to
+            # refuse, with jax's own error, which names it
+            continue
+        for shard in leaf.addressable_shards:
+            ptr = shard.data.unsafe_buffer_pointer()
+            first = seen.setdefault(ptr, i)
+            if first != i:
+                raise ValueError(
+                    "the train step donates its carry (parameters, "
+                    "optimizer state and the optimizer's own buffers) and "
+                    f"donated leaves {first} and {i} of it, "
+                    f"{leaf.dtype}{list(leaf.shape)}, are one buffer: a "
+                    "buffer cannot be donated twice. Give each leaf its "
+                    "own array (jnp.copy), or build the step with "
+                    "make_train_step(..., donate=False)"
+                )
 
 
 def _first_difference(old, new):
@@ -691,6 +733,37 @@ def _timed_dispatch(name, fn, *args):
         return out
 
 
+_UNUSED_DONATION = "Some donated buffers were not usable:"
+
+
+@contextlib.contextmanager
+def _unused_donations_counted():
+    """Round the first dispatch of a freshly built program, which is where
+    jax traces, lowers and compiles it: jax's "Some donated buffers were
+    not usable" warning, raised while it lowers, becomes the gauge
+    ``bluefog.step_donation_unused`` (the number of donated leaves the
+    program could not alias to an output; 0 without the warning) instead
+    of a line on stderr. Any other warning is raised again. A context
+    and not a function round the dispatch: one more Python frame under
+    the trace cost the GPT-2-medium step 2 s of set-up on the v5e's host
+    (PERF.md, PR 31)."""
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.filterwarnings("always", message=_UNUSED_DONATION)
+        yield
+    unused = 0
+    for w in caught:
+        text = str(w.message)
+        if text.startswith(_UNUSED_DONATION):
+            # "...: float32[8,3], int32[8].\nSee an explanation at ..."
+            unused += len(re.findall(r"\w+\[[^\]]*\]", text.split("\n")[0]))
+        else:
+            warnings.warn_explicit(
+                w.message, w.category, w.filename, w.lineno
+            )
+    if unused:
+        metrics_mod.gauge("bluefog.step_donation_unused").set(unused)
+
+
 class _StepPlan(NamedTuple):
     """What one call of :meth:`_GossipOptimizer.step` or of the fused
     ``train_step`` resolved before it looks its program up
@@ -722,6 +795,16 @@ class _StepPlan(NamedTuple):
 
 
 _opt_uid = itertools.count()
+
+# The operands of the fused program (``bf_step``) that a donating train
+# step gives up: parameters (0) and optimizer state (1), which the caller
+# rebinds from the outputs, and the two states the optimizer alone owns
+# and replaces from the outputs in the same call — error feedback (4) and
+# the ``delayed=True`` double buffer (5). Not the step index, ``wops`` or
+# the batch, and not the gradient accumulator (6): the call that consumes
+# it returns nothing of its shape that parameters and state have not
+# already taken, so its donation would only ever be reported unusable.
+_DONATED = (0, 1, 4, 5)
 
 
 class _GossipOptimizer:
@@ -2002,6 +2085,17 @@ class _GossipOptimizer:
 
         The whole step is one compiled SPMD program (reference splits it
         across hooks + synchronize + inner step, optimizers.py:362-482).
+
+        This entry point keeps its inputs: ``params``, ``opt_state`` and
+        ``grads`` are all alive after the call. The fused
+        :meth:`make_train_step` donates its carry by default and this
+        does not, though both run one step core, because of who holds
+        the operands: a fused step's caller has nothing to do with the
+        old parameters but rebind them, while ``step``'s caller computed
+        ``grads`` from ``params`` in a program of their own and commonly
+        still holds both (to log, to clip against, to take the next
+        gradient while this step runs). Same jit site, empty
+        ``donate_argnums`` for this program.
         """
         ctx = ctx_mod.get_context()
         comm_now = self._comm_now()
@@ -2046,7 +2140,8 @@ class _GossipOptimizer:
     # -- the step core: what step() and the fused train_step share -----------
 
     def _build_step(self, plan, value_and_grad=None, has_aux=False,
-                    self_weight_fn=None, has_accum=False, n_batch=0):
+                    self_weight_fn=None, has_accum=False, n_batch=0,
+                    donate=False):
         """The compiled step program of ``plan``: block the operands, get
         the gradients, run :func:`_combine_update` (or the
         ``delayed=True`` stale mix), restack — one ``shard_map`` body and
@@ -2061,7 +2156,10 @@ class _GossipOptimizer:
         ``(params, state, grads, step, wops, ef) -> (params, state, ef,
         metrics)``. ``self_weight_fn`` makes it the ``delayed=True``
         program; ``has_accum`` adds the host-side gradient accumulator
-        to the gradient inside it."""
+        to the gradient inside it. ``donate`` (the fused program only)
+        donates the carry, :data:`_DONATED`: in and out specs are both
+        ``spec``, so each of its leaves has an output of its shape,
+        dtype and sharding to be written into."""
         order = self.order
         tx = self._tx
         comm_now, met = plan.comm_now, plan.met
@@ -2221,7 +2319,8 @@ class _GossipOptimizer:
                 jax.shard_map(
                     body, mesh=plan.mesh, in_specs=in_specs,
                     out_specs=(spec,) * n_out,
-                )
+                ),
+                donate_argnums=_DONATED if fused and donate else (),
             )
 
     def _begin_step(self, ctx, plan, key, params):
@@ -2239,9 +2338,15 @@ class _GossipOptimizer:
             self._record_comm_accounting(
                 key, plan.gossip_key, params, ctx, shard=plan.shard_l
             )
+        return cur_comm, self._ef_operand(plan)
+
+    def _ef_operand(self, plan):
+        """The error-feedback state that rides ``plan``'s program: the
+        ZeRO-2 scatter's per-slot residuals, the gossip's CHOCO copies,
+        or nothing."""
         if plan.scatter_ef:
-            return cur_comm, self._scatter_ef
-        return cur_comm, (self._ef if plan.ef else ())
+            return self._scatter_ef
+        return self._ef if plan.ef else ()
 
     def _finish_step(self, ctx, plan, doc_t0, params, outputs, params_out,
                      state_out, ef_out, met_out, grads=None, payload_age=0,
@@ -2331,8 +2436,14 @@ class _GossipOptimizer:
         size = ctx.size
         bufs = []
         for _dt, idxs in _dtype_groups(leaves):
-            flat = jnp.concatenate(
-                [jnp.reshape(leaves[i], (size, -1)) for i in idxs], axis=1
+            parts = [jnp.reshape(leaves[i], (size, -1)) for i in idxs]
+            # the seed owns its memory: a one-leaf group's reshape and
+            # concatenate are the identity and hand back the parameter's
+            # own buffer, which the step would then be given twice to
+            # donate
+            flat = (
+                jnp.concatenate(parts, axis=1) if len(parts) > 1
+                else jnp.copy(parts[0])
             )
             bufs.append(jax.device_put(flat, sharding))
         self._delay_buf = tuple(bufs)
@@ -2344,7 +2455,7 @@ class _GossipOptimizer:
         self._delay_birth_comm = self._comm_count
 
     def make_train_step(self, loss_fn, has_aux: bool = False,
-                        delayed: bool = False):
+                        delayed: bool = False, donate: bool = True):
         """Build the fused train step: forward, backward, inner optax
         update, and the gossip combine in ONE compiled shard_map program.
 
@@ -2354,6 +2465,34 @@ class _GossipOptimizer:
 
             train_step = opt.make_train_step(loss_fn)
             params, opt_state, loss = train_step(params, opt_state, *batch)
+
+        **The call consumes its carry** (``donate=True``, the default):
+        ``params`` and ``opt_state`` are donated to the compiled program
+        (``jax.jit``'s ``donate_argnums``), which writes the new
+        parameters and state into the very buffers it was given. After
+        the call every array of the two trees passed in is deleted
+        (``is_deleted()``; reading one raises jax's own error) and the
+        returned trees take their place — which is what the loop above
+        does by rebinding both names. It saves a second copy of
+        parameters and state on the device while a step is in flight,
+        and on the host one buffer allocation per output leaf per call
+        (PERF.md, PR 31). The batch operands (and a ``has_aux`` loss's
+        mutable collections, which are batch operands) are never
+        donated. Two leaves of the carry must not be one buffer (a state
+        that holds a parameter itself): that raises ``ValueError`` when
+        the step is built. A caller that reads ``params`` or
+        ``opt_state`` again after the call — to compare, to roll back,
+        to feed a second optimizer — either copies first
+        (``jax.tree_util.tree_map(jnp.copy, params)``) or builds the
+        step with ``donate=False``, which keeps every input alive at the
+        old cost; both compute the same bits. It is an argument and not
+        an environment variable because whether the caller reads an
+        input again is the one thing the step cannot observe.
+        :meth:`step` keeps its inputs: its callers compute ``grads`` from
+        ``params`` outside the program and commonly hold both. What a
+        built program donates is the gauge ``bluefog.step_donated_bytes``
+        (per worker), and ``bluefog.step_donation_unused`` counts the
+        donated leaves it could not write in place (docs/metrics.md).
 
         Why this exists: ``opt.step`` is its own program, so the caller's
         backward pass and the gossip collective live in different XLA
@@ -2444,20 +2583,30 @@ class _GossipOptimizer:
             phases.enter("key")
             key = (
                 "opt_fused_step", fused_uid, *plan.key_ident, delay_now,
-                plan.cap_bytes, plan.direct, accum is not None, plan.met,
+                donate, plan.cap_bytes, plan.direct, accum is not None,
+                plan.met,
                 *plan.key_tail,
                 *_aval_key((params, opt_state, batch)),
             )
             fn = ctx.op_cache.get(key)
+            first_dispatch = contextlib.nullcontext()
+            buf_in = self._delay_buf if delay_now else ()
             if fn is None:
+                donated = (
+                    (params, opt_state, self._ef_operand(plan), buf_in)
+                    if donate else ()
+                )
+                _check_donated_once(donated)
                 _record_step_built(
                     "opt_fused_step", params, plan.cap_bytes, plan.direct,
+                    donated=donated,
                     differs_at=_first_difference(last_key[0], key),
                 )
                 fn = ctx.op_cache[key] = self._build_step(
                     plan, value_and_grad, has_aux, self_weight_fn,
-                    accum is not None, len(batch),
+                    accum is not None, len(batch), donate,
                 )
+                first_dispatch = _unused_donations_counted()
             last_key[0] = key
             phases.enter("stage", comm=comm_now, fused=True)  # step_begin
             cur_comm, ef_in = self._begin_step(ctx, plan, key, params)
@@ -2468,7 +2617,6 @@ class _GossipOptimizer:
             payload_age = (
                 cur_comm - self._delay_birth_comm if delay_now else 0
             )
-            buf_in = self._delay_buf if delay_now else ()
             accum_in = accum if accum is not None else ()
             # single source of truth for debug/evidence lowering
             # (lower_last_fused_hlo): the compiled fn plus exactly the
@@ -2497,12 +2645,13 @@ class _GossipOptimizer:
             step_idx, wops = _stage_operands(plan.mesh, cur_comm, plan.wops)
             doc_t0 = attribution.dispatch_timer(comm_now)
             phases.enter("enqueue")
-            params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
-                _timed_dispatch(
-                    "fused_train_step", fn, params, opt_state,
-                    step_idx, wops, ef_in, buf_in, accum_in, *batch,
+            with first_dispatch:
+                params_o, state_o, loss, aux, ef_o, buf_o, met_o = (
+                    _timed_dispatch(
+                        "fused_train_step", fn, params, opt_state,
+                        step_idx, wops, ef_in, buf_in, accum_in, *batch,
+                    )
                 )
-            )
             phases.enter("epilogue")  # step_dispatched
             if self.order == "grad":
                 # an accumulation call's gradient comes out where the

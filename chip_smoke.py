@@ -387,7 +387,10 @@ class ResNetJob:
             )
             return (params, state, stats), loss
 
-        carry = (self.params, opt.init(self.params), self.batch_stats)
+        # the fused step consumes its parameters and state, and every
+        # phase starts from the job's: each start trains a copy
+        params = jax.tree_util.tree_map(jnp.copy, self.params)
+        carry = (params, opt.init(params), self.batch_stats)
         return opt, step, carry
 
     def hlo(self, opt, carry):
@@ -400,7 +403,8 @@ def timing_honesty(step, carry, k):
     jitted gather and a host read), and what a settle still waits for
     after ``block_until_ready`` has returned: nothing, if it is honest."""
     by_block, after_block, by_settle = [], [], []
-    settle(step(carry)[1])  # settle's own gather compiles here
+    carry, loss = step(carry)
+    settle(loss)  # settle's own gather compiles here
     for _ in range(k):
         t0 = time.perf_counter()
         carry, loss = step(carry)

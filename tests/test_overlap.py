@@ -198,7 +198,8 @@ def test_bucketed_gossip_bitwise_matches_monolithic(monkeypatch):
         opt = bf.DistributedNeighborAllreduceOptimizer(
             optax.sgd(0.1, momentum=0.9)
         )
-        p = params
+        # the step consumes its carry: each cap trains its own copy
+        p = jax.tree_util.tree_map(jnp.copy, params)
         s = opt.init(p)
         train_step = opt.make_train_step(loss_fn)
         for _ in range(2):

@@ -54,6 +54,12 @@ def _tree(seed):
     return out
 
 
+def _copy(tree):
+    """The fused step consumes its carry: what a test reads again after
+    the call, or hands to a second optimizer, goes in as a copy."""
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
 def linear_loss(p, c):
     return sum(jnp.sum(p[k] * c[k]) for k in sorted(p))
 
@@ -75,7 +81,7 @@ def test_linear_loss_fused_equals_opt_step_to_the_bit(order, schedule):
     opt1 = _optimizer(order, schedule)
     p1, s1 = params, opt1.init(params)
     opt2 = _optimizer(order, schedule)
-    p2, s2 = params, opt2.init(params)
+    p2, s2 = _copy(params), opt2.init(params)
     train_step = opt2.make_train_step(linear_loss)
     for _ in range(3):
         p1, s1 = opt1.step(p1, s1, c)
@@ -120,7 +126,7 @@ def test_epilogue_calls_each_hook_once_in_order(path, monkeypatch):
 
     opt = _optimizer("cta", "static")
     step = _stepper(opt, path, c)
-    p, s = params, opt.init(params)
+    p, s = _copy(params), opt.init(params)
     for k in range(2):
         del calls[:]
         p, s = step(p, s)
@@ -142,7 +148,7 @@ def test_epilogue_calls_each_hook_once_in_order(path, monkeypatch):
     for order in ("cta", "atc"):
         opt = _optimizer(order, "static", num_steps_per_communication=2)
         step = _stepper(opt, path, c)
-        p, s = params, opt.init(params)
+        p, s = _copy(params), opt.init(params)
         del calls[:]
         p, s = step(p, s)  # call 0 of K = 2: local update only
         assert calls == []

@@ -202,6 +202,8 @@ def test_differs_at_names_the_component_a_forced_miss_changed():
     assert compiles() == [{
         "name": "opt_fused_step", "differs_at": None,
         "direct_bytes": 0, "packed_bytes": 60,
+        # the carry, consumed: the parameters (plain SGD has no state)
+        "donated_bytes": 60,
     }]
     params, state, loss = step(params, state, x)
     jax.block_until_ready(loss)
