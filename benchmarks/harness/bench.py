@@ -25,7 +25,7 @@ import bluefog_tpu as bf
 from bluefog_tpu import metrics as bf_metrics
 from bluefog_tpu.collective.plan import schedule_from_dynamic
 
-from benchmarks.harness import cells, flops, hlo_text, reference, trace_reduce
+from benchmarks.harness import cells, hlo_text, reference, scopes, trace_reduce
 
 STEPS_PER_BLOCK = 10   # a user who logs (and so syncs) every 10 steps
 WARM_STEPS = 3         # PR 21: the second call of a fused step compiles again
@@ -362,11 +362,18 @@ def run_cell(cell, seed, seconds, trace, spans, info, devices=None):
             p_sys, loss = step(p_sys, batches[k % N_BATCHES])
             sys_losses.append(loss)
             dispatched += 1
-        p_sys = p_sys[0]
+        # the program's parameters wait on the host while the reference
+        # runs, and its optimizer state is dropped: the span then holds the
+        # reference's parameters, momentum and gradients (12 B a parameter)
+        # and not a fourth copy beside them, so the check, which is outside
+        # the window and after both peaks are read, bounds no configuration
+        p_shardings = jax.tree_util.tree_map(lambda t: t.sharding, p_sys[0])
+        p_sys = jax.device_get(p_sys[0])
         p_ref, ref_losses = reference.run_reference(
             job, tx, cell.traffic, axis, *make_weights(k_weights), batches,
             CHECK_STEPS, first_round=first_round,
         )
+        p_sys = jax.device_put(p_sys, p_shardings)
         agrees, report = reference.compare(
             sys_losses, ref_losses, p_sys, p_ref, make_weights(k_weights)[0],
             cells.tolerance(cell),
@@ -451,12 +458,27 @@ def run_cell(cell, seed, seconds, trace, spans, info, devices=None):
         info({
             "traced_steps": run.traced_steps,
             "device_ms_per_step_by_kind": run.device_ms_by_kind(),
+            # each entry against the kernels it names, one that names
+            # none against all Mosaic time (scopes.kernel_roofline)
             "kernels": {
-                name: flops.roofline_share(
-                    cost, (run.device_ms_by_kind()[hlo_text.MOSAIC] or 0) / 1e3, peaks
-                ) for name, cost in job.kernel_costs().items()
+                name: scopes.kernel_roofline(run, cost)
+                for name, cost in job.kernel_costs().items()
             } if peaks else None,
         })
     result["device"] = device_line
+    # each number `correct` was decided by, beside its limit: [read, limit]
+    # (a non-finite reading as text: the line has to stay JSON)
+    result["compared"] = {
+        name: [read if np.isfinite(read) else repr(read), limit]
+        for name, read, limit in (
+            ("loss_abs_err", report["loss_abs_err"], report["loss_abs_tol"]),
+            ("update_l2_err", np.max(report["update_l2_err"]).item(),
+             report["update_l2_tol"]),
+            ("failed_steps", failed, 0),
+            ("compiled_in_window", compiled_in_window, 0),
+            ("window_steps_at_least", steps, 1),
+            ("parameters_finite", int(finite_end), 1),
+        )
+    }
     bf.shutdown()
     return result

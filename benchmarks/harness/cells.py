@@ -9,11 +9,19 @@ metric by name, so a later PR adds entries and files and edits none.
 
 Every file is checked on load and a key this harness does not take is an
 error: a misspelt ``batch_per_worker`` must not run the default.
+
+A configuration drawn from a public ``config.json`` holds that source's keys
+at the top level of its file, under their own names and with their own
+values, beside the harness's keys, and declares them in ``source_keys``
+(``check_config``, ``source_entry``). ``check_against_source`` is the
+driver's check of such a file against its source's entry, in the repo: what
+it refuses there, before any run, fails a test here.
 """
 
 import dataclasses
 import json
 import os
+import re
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -22,7 +30,8 @@ CONFIG_REQUIRED = {
     "source", "job", "unit", "model", "n_params", "optimizer", "flops",
     "tolerance", "reduced", "assumed",
 }
-CONFIG_OPTIONAL = {"paper", "deployment"}
+CONFIG_OPTIONAL = {"paper", "deployment", "source_keys"}
+HARNESS_KEYS = CONFIG_REQUIRED | CONFIG_OPTIONAL
 TOLERANCE_KEYS = {"loss_abs", "loss_reason", "update_l2", "update_reason"}
 
 TRAFFIC_REQUIRED = {
@@ -69,14 +78,111 @@ def _check_keys(what, got, required, optional=frozenset()):
 
 
 def check_config(name, config):
-    _check_keys(f"config {name!r}", config, CONFIG_REQUIRED, CONFIG_OPTIONAL)
-    _check_keys(
-        f"config {name!r} tolerance", config["tolerance"], TOLERANCE_KEYS
-    )
+    """The harness's keys, and beside them at the top level exactly the
+    source's keys the file declares in ``source_keys``: one that is not
+    declared is unknown (a misspelt ``n_layers`` stays an error), one that
+    is declared and absent is missing. ``model`` stays the job's own group:
+    what is not the source's (``compute_dtype``, a recomputation flag)."""
+    what = f"config {name!r}"
+    declared = config.get("source_keys", [])
+    if not (
+        isinstance(declared, list)
+        and all(isinstance(k, str) for k in declared)
+        and len(set(declared)) == len(declared)
+    ):
+        raise CellError(f"{what}: source_keys must be a list of distinct names")
+    colliding = sorted(set(declared) & HARNESS_KEYS)
+    if colliding:
+        raise CellError(
+            f"{what}: source_keys names the harness's own keys {colliding}"
+        )
+    _check_keys(what, config, CONFIG_REQUIRED | set(declared), CONFIG_OPTIONAL)
+    undeclared = sorted(set(config["reduced"]) - set(declared))
+    if "source_keys" in config and undeclared:
+        raise CellError(
+            f"{what}: reduced names {undeclared}, which source_keys does not "
+            "declare: reduced lists the source's keys whose values differ "
+            "from the source's"
+        )
+    _check_keys(f"{what} tolerance", config["tolerance"], TOLERANCE_KEYS)
     if not os.path.isfile(job_path(config["job"])):
         raise CellError(
             f"config {name!r}: no job builder {job_path(config['job'])}"
         )
+
+
+def source_entry(config):
+    """The source's keys a configuration's file declares, as one dict, in
+    the file's own values: where a job builder reads the model's sizes."""
+    return {key: config[key] for key in config.get("source_keys", [])}
+
+
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# the names a width goes by in a config.json (the contract's list: a hidden,
+# intermediate, latent, state or projection size, a key that ends in _dim or
+# _rank, a head size, an expansion factor, the experts per token); a row does
+# not say what a width is, so this is a net for the usual names, no proof
+_WIDTH_RE = re.compile(
+    r"(_dim|_rank)$|hidden_size|intermediate_size|state_size|d_model|d_ff"
+    r"|head_dim|head_size|expand|experts_per_tok"
+)
+
+
+def catalog_entry(source, catalog=CATALOG):
+    """The ``config`` of the catalog row whose ``source_url`` is ``source``;
+    ``None`` for a source that is no row's, or where this machine has no
+    catalog."""
+    if not os.path.isfile(catalog):
+        return None
+    with open(catalog) as f:
+        for line in f:
+            row = json.loads(line)
+            if row.get("source_url") == source:
+                return row["config"]
+    return None
+
+
+def check_against_source(name, config, entry):
+    """The driver's check of a configuration's file against its source's
+    entry (the ``config`` of the catalog row whose ``source_url`` is the
+    ``source`` ``BENCHMARK.json`` gives the configuration), which it makes
+    before any run: for every key of ``entry`` whose value is a number (a
+    boolean is none), a list or a nested group, the file's **top level**
+    holds that key — never ``null`` or absent where the source has a value
+    — and holds the source's value unless ``reduced`` lists the key. A
+    nested group is copied whole and a changed one named by its top-level
+    key.
+
+    ``reduced`` may never name a width, and a width may not change inside a
+    listed group either: it may name only a count of layers, of experts held
+    or of vocabulary rows, and the per-layer lists that follow from a cut in
+    depth (``layer_types``, ``mlp_only_layers``). What a width is a row does
+    not say, so only the usual names are caught here (``_WIDTH_RE``); the
+    rule is the builder's to keep."""
+    what = f"config {name!r}"
+    reduced = config["reduced"]
+    for key, want in entry.items():
+        if isinstance(want, bool) or not isinstance(want, (int, float, list, dict)):
+            continue
+        got = config.get(key)
+        if got is None or (got != want and key not in reduced):
+            raise CellError(
+                f"{what} gives {key} as {json.dumps(got)} and its source gives "
+                f"{json.dumps(want)}: the file holds every number, list and "
+                "nested group of its source's entry at its top level under "
+                "the same key, and reduced lists each key it changes"
+            )
+    for key in reduced:
+        if key not in entry:
+            raise CellError(f"{what}: reduced names {key}, no key of its source")
+        if config.get(key) == entry[key]:
+            raise CellError(
+                f"{what}: reduced names {key}, which holds the source's value"
+            )
+        if _WIDTH_RE.search(key):
+            raise CellError(
+                f"{what}: reduced names {key}, a width: a width may not change"
+            )
 
 
 def check_traffic(name, traffic):
@@ -148,6 +254,10 @@ def load_cell(name, root=ROOT):
         raise CellError(f"cell {name!r}: no config {entry['config']!r}")
     config = _load_json(os.path.join(root, configs[entry["config"]]["file"]))
     check_config(entry["config"], config)
+    # the source the driver looks up is the one BENCHMARK.json states
+    source = catalog_entry(configs[entry["config"]]["source"])
+    if source is not None:
+        check_against_source(entry["config"], config, source)
     traffic = _load_json(traffic_path(entry["traffic"]))
     check_traffic(entry["traffic"], traffic)
     per_layer = _metrics_of(bench["per_layer"], name)

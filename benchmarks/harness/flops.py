@@ -5,6 +5,14 @@ These are the yardstick's counts: what the forward and backward passes
 happens to execute. A job builder turns them into its configuration's
 FLOPs per unit; a per-layer reader divides them by a kernel's device time.
 A multiply-add is two operations.
+
+A job's ``kernel_costs()`` is ``{entry: cost}``, a cost ``{"flops", "bytes"}``
+of one step and, where the step holds more than one kind of kernel,
+``"kernels"``: the ``pallas_call`` names (their ``name=``) whose device time
+the cost is measured against (``scopes.kernel_roofline``). An entry that
+names none is measured against all Mosaic time of the step, which is right
+only while every Mosaic call is that kernel's. How many Mosaic calls one
+compiled step of the job holds is its ``mosaic_calls``.
 """
 
 

@@ -34,7 +34,7 @@ import re
 import statistics
 import time
 
-from benchmarks.harness import hlo_text, trace_reduce
+from benchmarks.harness import flops, hlo_text, trace_reduce
 # the harness has one parser of an HLO line: these are its expressions
 from benchmarks.harness.hlo_text import _CALLS_RE, _COMPUTATION_RE, _INSTR_RE
 
@@ -162,31 +162,54 @@ def fusion_bodies(hlo):
     return {name: members.get(c, ()) for name, c in called.items()}
 
 
-def instruction_parts(op_names, bodies):
-    """{instruction: part} for every instruction that has an ``op_name`` or
-    is a fusion (any other is unscoped). Its own ``op_name`` decides
-    (``part_of``). XLA keeps one instruction's metadata for a whole fusion,
-    and it may be bare glue's or none: ``_tree_restack``'s
-    ``broadcast_in_dim`` is the root of fusions that hold the inner
-    update's arithmetic. So a fusion whose own ``op_name`` names no scope
-    takes the part most of the scoped instructions in its body
-    (``fusion_bodies``) belong to, the first of ``PARTS`` on a tie; one
-    whose ``op_name`` names a scope keeps it, whatever else was fused in."""
-    parts = {}
+def instruction_labels(op_names, bodies, label_of, order):
+    """{instruction: label} for every instruction that has an ``op_name`` or
+    is a fusion. ``label_of(op_name)`` is a label or ``None``; an
+    instruction's own ``op_name`` decides. XLA keeps one instruction's
+    metadata for a whole fusion, and it may be bare glue's or none:
+    ``_tree_restack``'s ``broadcast_in_dim`` is the root of fusions that
+    hold the inner update's arithmetic. So a fusion whose own ``op_name``
+    gives no label takes the one most of the labelled instructions in its
+    body (``fusion_bodies``) carry, the first of ``order`` on a tie; one
+    whose ``op_name`` gives a label keeps it, whatever else was fused in."""
+    labels = {}
 
-    def part(name):
-        if name not in parts:
-            parts[name] = part_of(op_names.get(name))
-            if parts[name] == UNSCOPED and name in bodies:
-                votes = collections.Counter(map(part, bodies[name]))
-                del votes[UNSCOPED]
+    def label(name):
+        if name not in labels:
+            labels[name] = label_of(op_names.get(name))
+            if labels[name] is None and name in bodies:
+                votes = collections.Counter(map(label, bodies[name]))
+                del votes[None]
                 if votes:
-                    parts[name] = max(PARTS, key=votes.__getitem__)
-        return parts[name]
+                    labels[name] = max(order, key=votes.__getitem__)
+        return labels[name]
 
     for name in (*op_names, *bodies):
-        part(name)
-    return parts
+        label(name)
+    return labels
+
+
+def instruction_parts(op_names, bodies):
+    """``instruction_labels`` over ``PARTS`` (``part_of``): an instruction
+    that takes no part of the five scopes' is unscoped."""
+    def scoped_part(op_name):
+        part = part_of(op_name)
+        return None if part == UNSCOPED else part
+
+    labels = instruction_labels(op_names, bodies, scoped_part, PARTS)
+    return {name: part or UNSCOPED for name, part in labels.items()}
+
+
+def scope_of(names):
+    """-> ``find(op_name)``: the innermost of the scope names ``names`` in an
+    ``op_name`` (the last to appear in it, as a whole word: ``bf.pack`` is
+    not in ``bf.packed``), or ``None``."""
+    found = re.compile(r"\b(%s)\b" % "|".join(map(re.escape, names))).findall
+
+    def find(op_name):
+        return (found(op_name or "") or (None,))[-1]
+
+    return find
 
 
 def _sum_by(own_times, key):
@@ -252,6 +275,68 @@ def device_ms_by_scope(run):
         return _per_step_ms(run, lambda own: ns_by_part(parts, own))
 
     return _cached(run, "device", split)
+
+
+def device_ms_by_scopes(run, names, halves=False):
+    """{name: ms per step and chip} of the step's own device time under each
+    of the scope names ``names`` (any ``jax.named_scope``s: the program's
+    ``bf.*``, a model's own round its router or its expert products), and
+    under ``None`` the rest: together the whole step, as
+    ``device_ms_by_kind`` sums it. The innermost of ``names`` in an
+    instruction's ``op_name`` decides and a fusion without one takes the
+    vote of its body (``instruction_labels``, the one implementation
+    ``device_ms_by_scope`` splits ``PARTS`` by). With ``halves`` a scope's
+    key is ``(name, "forward")`` or ``(name, "backward")``, by
+    ``transpose(jvp(`` as in ``part_of``. ``None`` without a trace or the
+    step's HLO, or when no instruction names any of ``names``."""
+    if run.trace is None or run.hlo is None:
+        return None
+    names = tuple(names)
+    find = scope_of(names)
+    label_of, order = find, names
+    if halves:
+        order = tuple((n, h) for n in names for h in (FORWARD, BACKWARD))
+
+        def label_of(op_name):
+            name = find(op_name)
+            return name and (
+                name, BACKWARD if "transpose(jvp(" in op_name else FORWARD
+            )
+
+    def split():
+        op_names = run.hlo.op_names
+        if not any(map(find, op_names.values())):
+            return None
+        labels = instruction_labels(
+            op_names, fusion_bodies(run.hlo.text), label_of, order
+        )
+        rest = object()  # `_sum_by` leaves out a key of None
+        totals = _per_step_ms(run, lambda own: _sum_by(
+            own, lambda op: labels.get(op.name) or rest
+        ))
+        totals[None] = totals.pop(rest, 0.0)
+        return {**dict.fromkeys(order, 0.0), **totals}
+
+    return _cached(run, ("scopes", names, halves), split)
+
+
+def kernel_roofline(run, cost):
+    """``flops.roofline_share`` of one entry of a job's ``kernel_costs()``
+    over the device time per step and chip of the kernels it is measured
+    against: the Mosaic calls whose ``pallas_call`` name is in
+    ``cost["kernels"]`` or, for an entry that names none, all Mosaic time of
+    the step (right only while every Mosaic call of the step is that
+    kernel's). ``None`` off the chip, without a trace or where none of them
+    ran."""
+    if not run.peaks:
+        return None
+    if "kernels" in cost:
+        by_kernel = mosaic_ms_by_kernel(run)
+        ms = by_kernel and sum(by_kernel.get(k, 0.0) for k in cost["kernels"])
+    else:
+        by_kind = run.device_ms_by_kind()
+        ms = by_kind and by_kind[hlo_text.MOSAIC]
+    return flops.roofline_share(cost, (ms or 0) / 1e3, run.peaks)
 
 
 def mosaic_ms_by_kernel(run):

@@ -65,6 +65,7 @@ class Job:
             attend=dense_attention, **kwargs
         )
         self.units_per_worker_step = self.batch * self.seq
+        self.mosaic_calls = 3 * m["n_layer"]  # flash forward, dkv, dq
         self.flops_per_unit = flops.lm_flops_per_token(m, self.seq)
 
     def init(self, key):

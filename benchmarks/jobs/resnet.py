@@ -11,6 +11,7 @@ from bluefog_tpu import models
 
 class Job:
     has_aux = True  # batch statistics ride as a batch operand, return as aux
+    mosaic_calls = 0  # no Pallas kernel runs in this job's step
 
     def __init__(self, config, traffic):
         m = self.model_cfg = config["model"]

@@ -7,7 +7,9 @@ One process, no children, no server. The cell is looked up by name in
 ``BENCHMARK.json`` (benchmarks/harness/cells.py). The last line of the
 standard output is the result: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``), ``device`` and, traced, ``breakdown``. Earlier
+metrics with ``--trace 1``), ``device``, traced ``breakdown``, and last
+``compared``: each number ``correct`` was decided by beside its limit, which
+are also the last lines of the standard error. Earlier
 lines are JSON too: spans, block times, losses, the reference's errors,
 cache hits.
 
@@ -80,6 +82,8 @@ def main(argv=None):
         cell, args.seed, seconds, bool(args.trace), bench.Spans(_STARTED), info
     )
     print(json.dumps(result), flush=True)
+    for name, (read, limit) in result["compared"].items():
+        print(f"compared {name}: {read!r} limit {limit!r}", file=sys.stderr)
     return 0
 
 

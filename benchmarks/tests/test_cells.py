@@ -123,6 +123,166 @@ def test_config_refusals():
         cells.check_config("c", cfg)
 
 
+def laid_out(**changes):
+    cfg = copy.deepcopy(toy.LAID_OUT_LM)
+    cfg.update(changes)
+    return cfg
+
+
+def test_a_config_holds_its_sources_keys_at_the_top_level(monkeypatch):
+    toy.jobs_here(monkeypatch)
+    cfg = laid_out()
+    cells.check_config("c", cfg)
+    entry = cells.source_entry(cfg)
+    assert list(entry) == list(toy.SOURCE_LM)  # the source's own order
+    assert entry["num_hidden_layers"] == 2 and entry["rope_scaling"] is None
+    assert entry["layer_types"] == ["full", "full"]
+    # the two files the benchmark has declare nothing
+    assert cells.source_entry(toy.LM) == {}
+    cells.check_against_source("c", cfg, toy.SOURCE_LM)
+
+
+def without(key):
+    cfg = laid_out()
+    del cfg[key]
+    return cfg
+
+
+@pytest.mark.parametrize("cfg, message", [
+    (laid_out(n_layers=3), r"unknown keys \['n_layers'\]"),
+    (without("hidden_size"), r"missing keys \['hidden_size'\]"),
+    (laid_out(source_keys=[*toy.SOURCE_LM, "job"]), "harness's own keys"),
+    (laid_out(source_keys=[*toy.SOURCE_LM, "vocab_size"]), "distinct"),
+    (laid_out(reduced=["num_hidden_layers", "layer_types", "n_layer"]),
+     "source_keys does not declare"),
+    (laid_out(source_keys=[]), "unknown keys"),
+], ids=["undeclared", "declared-absent", "collides", "twice", "reduced-undeclared",
+        "none-declared"])
+def test_config_layout_refusals(cfg, message, monkeypatch):
+    toy.jobs_here(monkeypatch)
+    with pytest.raises(cells.CellError, match=message):
+        cells.check_config("c", cfg)
+
+
+# what the ledger records of PR 32 (and of PR 26 / 27): the source's keys
+# verbatim, but under ``model``
+KANANA_HEAD = {
+    "attention_bias": False, "first_k_dense_replace": 1, "head_dim": 64,
+    "hidden_act": "silu", "hidden_size": 2048, "num_hidden_layers": 48,
+    "q_lora_rank": None, "rope_scaling": None, "vocab_size": 128256,
+}
+
+
+def under_model(entry, **changes):
+    return {**toy.LM, "model": {**entry, **changes}}
+
+
+def at_top_level(entry, reduced=(), **changes):
+    """A source's entry laid out the way the check wants it: its keys at the
+    top level beside the harness's, declared in ``source_keys``."""
+    return {
+        **toy.LM, **entry, **changes, "model": {"compute_dtype": "bfloat16"},
+        "source_keys": list(entry), "reduced": list(reduced),
+    }
+
+
+def test_the_check_against_the_source_reads_the_top_level():
+    check = cells.check_against_source
+    with pytest.raises(cells.CellError, match=(
+        "gives first_k_dense_replace as null and its source gives 1"
+    )):
+        check("kanana", under_model(KANANA_HEAD), KANANA_HEAD)
+    check("kanana", at_top_level(KANANA_HEAD), KANANA_HEAD)
+    cut = at_top_level(KANANA_HEAD, ["num_hidden_layers"], num_hidden_layers=6)
+    cells.check_config("kanana", cut)
+    check("kanana", cut, KANANA_HEAD)
+    with pytest.raises(cells.CellError, match=(
+        "gives num_hidden_layers as 6 and its source gives 48"
+    )):
+        check("kanana", at_top_level(KANANA_HEAD, num_hidden_layers=6), KANANA_HEAD)
+    # a null of the source's stays a null, a boolean and a string are not
+    # compared; a null where the source has a number is refused though listed
+    check("kanana", at_top_level(KANANA_HEAD, hidden_act="gelu"), KANANA_HEAD)
+    with pytest.raises(cells.CellError, match="vocab_size as null"):
+        check("kanana", at_top_level(KANANA_HEAD, ["vocab_size"], vocab_size=None), KANANA_HEAD)
+
+
+@pytest.mark.parametrize("reduced, changes, message", [
+    (["hidden_size"], {"hidden_size": 1024}, "a width"),
+    (["head_dim"], {"head_dim": 32}, "a width"),
+    (["num_layers"], {}, "no key of its source"),
+    (["vocab_size"], {}, "holds the source's value"),
+])
+def test_what_reduced_may_not_name(reduced, changes, message):
+    with pytest.raises(cells.CellError, match=message):
+        cells.check_against_source(
+            "kanana", at_top_level(KANANA_HEAD, reduced, **changes), KANANA_HEAD
+        )
+
+
+def test_nested_groups_and_lists_are_compared_whole():
+    entry = {
+        "rope_parameters": {"rope_theta": 1e6, "rope_type": "default"},
+        "layer_types": ["full", "linear"] * 2, "num_hidden_layers": 4,
+    }
+    cells.check_against_source("c", at_top_level(entry), entry)
+    bent = {"rope_parameters": {"rope_theta": 1e4, "rope_type": "default"}}
+    with pytest.raises(cells.CellError, match="gives rope_parameters as"):
+        cells.check_against_source("c", at_top_level(entry, **bent), entry)
+    cut = at_top_level(
+        entry, ["layer_types", "num_hidden_layers"],
+        layer_types=["full", "linear"], num_hidden_layers=2,
+    )
+    cells.check_against_source("c", cut, entry)
+
+
+def catalog_rows():
+    if not os.path.isfile(cells.CATALOG):
+        return []
+    with open(cells.CATALOG) as f:
+        return [json.loads(line) for line in f]
+
+
+def test_every_catalog_row_passes_laid_out_and_fails_nested():
+    """Where the catalog is on the machine: each row's ``config`` at the top
+    level passes, and nested under ``model`` fails on its first number, which
+    is the key the ledger's three refusals name."""
+    rows = catalog_rows()
+    if not rows:
+        pytest.skip("no catalog on this machine")
+    first = {}
+    for row in rows:
+        entry = row["config"]
+        assert not set(entry) & cells.HARNESS_KEYS, row["name"]
+        cfg = at_top_level(entry)
+        cells.check_config(row["name"], cfg)
+        cells.check_against_source(row["name"], cfg, entry)
+        assert cells.catalog_entry(row["source_url"]) is not None
+        number = next(
+            k for k, v in entry.items()
+            if isinstance(v, (int, float, list, dict)) and not isinstance(v, bool)
+        )
+        first[row["name"]] = number
+        with pytest.raises(cells.CellError, match=f"gives {number} as null"):
+            cells.check_against_source(row["name"], under_model(entry), entry)
+    assert first["granite-4.0-h-micro"] == "attention_multiplier"
+    assert first["LFM2-8B-A1B"] == "conv_L_cache"
+    assert first["kanana-2-30b-a3b-instruct-2601"] == "first_k_dense_replace"
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=names("configs"))
+def test_every_config_file_passes_the_checks(config):
+    """What the driver checks before any run, of every file the benchmark
+    has: a file whose ``source`` is a catalog row holds the row's entry."""
+    with open(os.path.join(cells.ROOT, config["file"])) as f:
+        cfg = json.load(f)
+    cells.check_config(config["name"], cfg)
+    entry = cells.catalog_entry(config["source"])
+    if entry is not None:
+        cells.check_against_source(config["name"], cfg, entry)
+        assert set(cells.source_entry(cfg)) >= set(entry)
+
+
 def test_unknown_names_and_devices():
     with pytest.raises(cells.CellError, match="no workload"):
         cells.load_cell("resnet50_8chip")

@@ -85,3 +85,43 @@ def test_compare_separates_a_wrong_update():
     assert not ok
     ok, _ = reference.compare([[1.0, float("nan")]], [[1.0, 1.0]], ref, ref, p0, tol)
     assert not ok
+
+
+@pytest.mark.parametrize("config, mix, workers", [
+    (toy.RESNET, toy.traffic(schedule="one_peer_exp2", nodes_per_machine=2), 4),
+    (toy.LM, toy.traffic(seq=64), 1),
+], ids=["resnet-onepeer", "lm-local"])
+def test_parameters_held_on_the_host_change_no_bit_of_the_report(
+    config, mix, workers, monkeypatch
+):
+    """While the reference runs the program's parameters wait on the host
+    (``bench.run_cell``, the ``reference`` span). Kept on the device, as
+    before PR 33 — ``device_get`` made the identity, which makes the
+    ``device_put`` that follows a no-op — the check reports the same
+    numbers to the last bit."""
+    import jax
+
+    gets = []
+    host_get = jax.device_get
+
+    def counted(tree):
+        gets.append(tree)
+        return host_get(tree)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    result, info = toy.rehearse(config, mix, workers)
+    assert len(gets) == 1  # the one copy to the host is the parameters'
+    assert all(
+        isinstance(leaf, jax.Array) for leaf in jax.tree_util.tree_leaves(gets[0])
+    )
+    monkeypatch.setattr(jax, "device_get", lambda tree: tree)
+    on_device, info_on_device = toy.rehearse(config, mix, workers)
+    assert info["reference"] == info_on_device["reference"]
+    for name in ("loss_abs_err", "update_l2_err"):
+        assert result["compared"][name] == on_device["compared"][name]
+    assert result["correct"] and on_device["correct"]
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["update_l2_err"] == [
+        max(info["reference"]["update_l2_err"]), toy.TOLERANCE["update_l2"]
+    ]
+

@@ -14,20 +14,7 @@ from benchmarks.harness import bench, reference
 
 import toy
 
-UNITS = {"throughput_per_chip": "unit/s", "peak_hbm_gib": "GiB", "setup_s": "s"}
-
-
-def rehearse(config, mix, workers):
-    cell = toy.cell(config, mix, workers)
-    cell.units.update(UNITS)
-    lines = []
-    result = bench.run_cell(
-        cell, seed=3, seconds=0.3, trace=False,
-        spans=bench.Spans(time.perf_counter()), info=lines.append,
-        devices=jax.devices()[:workers],
-    )
-    json.dumps([lines, result])  # every line is JSON
-    return result, lines[-1]
+UNITS, rehearse = toy.UNITS, toy.rehearse
 
 
 @pytest.mark.parametrize("config, mix, workers", [
@@ -38,11 +25,13 @@ def rehearse(config, mix, workers):
     (toy.LM, toy.traffic(seq=64), 1),
     (toy.LM, toy.traffic(seq=64, topology="exp2"), 4),
     (toy.LM, toy.traffic(seq=48, topology="ring"), 4),
+    (toy.LAID_OUT_LM, toy.traffic(seq=64, topology="exp2"), 4),
 ], ids=[
     "resnet-local", "resnet-onepeer", "resnet-allreduce", "resnet-hier",
-    "lm-local", "lm-exp2", "lm-ring",
+    "lm-local", "lm-exp2", "lm-ring", "lm-laid-out-as-its-source",
 ])
-def test_cell_runs_and_agrees_with_the_reference(config, mix, workers):
+def test_cell_runs_and_agrees_with_the_reference(config, mix, workers, monkeypatch):
+    toy.jobs_here(monkeypatch)
     result, info = rehearse(config, mix, workers)
     assert result["correct"], info["reference"]
     assert result["failed"] == 0
@@ -68,6 +57,55 @@ def test_a_wrong_mixing_weight_turns_correct_false(monkeypatch):
     result, info = rehearse(toy.RESNET, mix, 4)
     assert not result["correct"]
     assert min(info["reference"]["update_l2_err"]) > 0.02
+
+
+def _state_unchanged(monkeypatch):
+    real = bench.bf.make_train_step
+
+    def make(opt, loss_fn, has_aux=False):
+        fused = real(opt, loss_fn, has_aux=has_aux)
+
+        def step(params, state, *operands):
+            # the real step consumes what it is given: it gets copies
+            copies = jax.tree_util.tree_map(jax.numpy.copy, (params, state))
+            return (params, state, fused(*copies, *operands)[2])
+
+        return step
+
+    monkeypatch.setattr(bench.bf, "make_train_step", make)
+
+
+def _half_of_the_batch(monkeypatch):
+    real = bench.load_job
+
+    def load(cell):
+        job = real(cell)
+        whole = job.loss_fn
+        job.loss_fn = lambda params, tokens: whole(params, tokens[: len(tokens) // 2])
+        return job
+
+    monkeypatch.setattr(bench, "load_job", load)
+
+
+def _no_exchange(monkeypatch):
+    # planted in the reference, put in the program's place
+    monkeypatch.setattr(reference, "_mix", lambda w, tree: tree)
+
+
+@pytest.mark.parametrize("fault, least", [
+    (_state_unchanged, 0.999), (_half_of_the_batch, 0.1), (_no_exchange, 0.01),
+], ids=["state-unchanged", "half-of-the-batch", "no-exchange"])
+def test_a_broken_step_turns_correct_false(fault, least, monkeypatch):
+    """The faults a training cell can have, each under the rest of a whole
+    run: a step that returns its state as it got it reads an update error of
+    1 by this measure; the mean over half of the rows and a step without the
+    exchange between the workers read far over the toy's 1e-3."""
+    fault(monkeypatch)
+    result, info = rehearse(toy.LM, toy.traffic(seq=64, topology="exp2"), 4)
+    assert not result["correct"] and result["failed"] == 0
+    read, limit = result["compared"]["update_l2_err"]
+    assert read > least >= 10 * limit
+    assert read == max(info["reference"]["update_l2_err"])
 
 
 def test_a_quantized_wire_needs_its_stated_tolerance():

@@ -40,7 +40,7 @@ LM_CELLS = ["gpt2m_1chip_full", "gpt2m_1chip_b1"]
     ("backward_ms", "device_trace", "models", None),
     ("pack_unpack_ms", "device_trace", "optimizer_path", None),
     ("inner_update_ms", "device_trace", "optimizer_path", None),
-    ("combine_ms", "device_trace", "collectives", None),
+    ("combine_ms", "device_trace", "collectives", ["resnet50_4chip_onepeer"]),
     ("unscoped_device_ms", "device_trace", "device", None),
     ("flash_fwd_ms", "device_trace", "attention_kernels", LM_CELLS),
     ("flash_bwd_ms", "device_trace", "attention_kernels", LM_CELLS),
@@ -387,3 +387,121 @@ def test_device_readers_on_a_scoped_trace_recorded_on_the_v5e():
     assert set(kernels) == {"bf_flash_fwd", "bf_flash_dkv", "bf_flash_dq"}
     assert sum(kernels.values()) == pytest.approx(kinds["mosaic"])
     assert bench.load_reader("flash_fwd_ms")(run) == pytest.approx(kernels["bf_flash_fwd"])
+
+
+# -- any scope names, any kernel names (PR 33) --------------------------------------
+
+BF_SCOPES = ("bf.loss_grad", "bf.pack", "bf.unpack", "bf.gossip", "bf.inner_update")
+
+
+def recorded_run(stem, steps):
+    with open(os.path.join(DATA, f"{stem}.hlo.txt")) as f:
+        hlo = hlo_text.HloIndex(f.read())
+    run = bench.Run(None, 1, None, ON_THE_CHIP, bench.Spans(0.0))
+    run.hlo = hlo
+    run.trace = trace_reduce.load(os.path.join(DATA, f"{stem}.xplane.pb"))
+    run.traced_steps = steps
+    return run
+
+
+def test_the_six_parts_of_the_recorded_trace_to_the_last_digit():
+    """What ``device_ms_by_scope`` read of ``data/tiny_scoped.*`` before its
+    vote became ``instruction_labels``: the same floats, not nearly."""
+    run = recorded_run("tiny_scoped", 6)
+    assert scopes.device_ms_by_scope(run) == {
+        "forward": 0.022736333333333334, "backward": 0.029945166666666665,
+        "pack_unpack": 0.0011586666666666666, "inner_update": 0.005178,
+        "combine": 0.0004761666666666667, "unscoped": 0.0021923333333333335,
+    }
+    assert {n: bench.load_reader(n)(run) for n in DEVICE} == {
+        "forward_ms": 0.022736333333333334, "backward_ms": 0.029945166666666665,
+        "pack_unpack_ms": 0.0011586666666666666, "inner_update_ms": 0.005178,
+        "combine_ms": 0.0004761666666666667,
+        "unscoped_device_ms": 0.0021923333333333335,
+    }
+
+
+@pytest.mark.parametrize("stem", ["tiny_1chip", "tiny_4chip"])
+def test_a_trace_recorded_before_the_scopes_names_none_of_them(stem):
+    run = recorded_run(stem, 20)
+    assert scopes.device_ms_by_scope(run) is None
+    assert scopes.device_ms_by_scopes(run, BF_SCOPES) is None
+    assert scopes.device_ms_by_scopes(run, BF_SCOPES, halves=True) is None
+    # any word of an op_name is a scope name to this reader: with the
+    # operation's own name it splits the step as ``device_ms_by_kind`` does
+    split = scopes.device_ms_by_scopes(run, ("dot_general",))
+    kinds = run.device_ms_by_kind()
+    assert split["dot_general"] == pytest.approx(kinds["matmul_conv"])
+    assert split["dot_general"] + split[None] == pytest.approx(sum(kinds.values()))
+
+
+def test_the_step_by_the_scope_names_the_caller_gives():
+    run = recorded_run("tiny_scoped", 6)
+    parts = scopes.device_ms_by_scope(run)
+    by_name = scopes.device_ms_by_scopes(run, BF_SCOPES)
+    assert set(by_name) == {*BF_SCOPES, None}
+    assert by_name["bf.loss_grad"] == pytest.approx(parts["forward"] + parts["backward"])
+    assert by_name["bf.pack"] + by_name["bf.unpack"] == pytest.approx(parts["pack_unpack"])
+    assert by_name["bf.gossip"] == parts["combine"]
+    assert by_name["bf.inner_update"] == parts["inner_update"]
+    assert by_name[None] == parts["unscoped"]
+    halves = scopes.device_ms_by_scopes(run, BF_SCOPES, halves=True)
+    assert halves["bf.loss_grad", "forward"] == parts["forward"]
+    assert halves["bf.loss_grad", "backward"] == parts["backward"]
+    assert sum(halves.values()) == pytest.approx(sum(parts.values()))
+    # a made-up inner scope of a model's own: flax names the one block of
+    # this toy LM ``Block_0``, and the innermost name decides, so its time
+    # leaves ``bf.loss_grad``'s and the step's sum stays
+    inner = scopes.device_ms_by_scopes(run, (*BF_SCOPES, "Block_0"), halves=True)
+    block = inner["Block_0", "forward"], inner["Block_0", "backward"]
+    assert 0 < block[0] < parts["forward"] and 0 < block[1] < parts["backward"]
+    assert inner["bf.loss_grad", "forward"] == pytest.approx(parts["forward"] - block[0])
+    assert inner["bf.loss_grad", "backward"] == pytest.approx(parts["backward"] - block[1])
+    assert inner[None] == parts["unscoped"]
+    # the flash kernels run inside the block
+    kernels = scopes.mosaic_ms_by_kernel(run)
+    assert block[0] > kernels["bf_flash_fwd"]
+    # a name that is in no op_name reads 0.0 beside the others; none at all
+    # is None, and `bf.pack` is not in `bf.packed`
+    assert scopes.device_ms_by_scopes(run, ("bf.gossip", "router"))["router"] == 0.0
+    assert scopes.device_ms_by_scopes(run, ("router", "experts")) is None
+    assert scopes.scope_of(("bf.pack",))("jit(f)/bf.packed/add") is None
+    assert scopes.scope_of(("a", "b"))("jit(f)/a/b/a/mul") == "a"
+    assert scopes.scope_of(("a",))(None) is None
+
+
+def test_the_vote_of_a_fusions_body_over_any_labels():
+    hlo = hlo_text.HloIndex(SCOPED_HLO)
+    labels = scopes.instruction_labels(
+        hlo.op_names, scopes.fusion_bodies(SCOPED_HLO),
+        scopes.scope_of(("bf.unpack", "bf.inner_update")),
+        ("bf.unpack", "bf.inner_update"),
+    )
+    assert labels["fusion.11"] == "bf.inner_update"  # two votes to one
+    assert labels["fusion.13"] is None
+    # its own op_name names a scope, but none of these two: its body votes
+    assert labels["fusion.2"] == "bf.inner_update"
+    assert labels["slice.7"] == "bf.unpack"
+
+
+def test_a_kernels_roof_is_read_against_the_kernels_it_names():
+    run = recorded_run("tiny_scoped", 6)
+    run.peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    kernels, mosaic = scopes.mosaic_ms_by_kernel(run), run.device_ms_by_kind()["mosaic"]
+    cost = {"flops": 197e12 * 1e-6, "bytes": 819e9 * 5e-7}  # 1 us, compute binds
+    every = scopes.kernel_roofline(run, cost)
+    assert every == {"share": pytest.approx(1e-6 / (mosaic / 1e3)), "binds": "compute"}
+    # what `flash_roofline` reads, which this PR leaves alone
+    run.job = type("J", (), {"kernel_costs": lambda self: {"flash": cost}})()
+    assert bench.load_reader("flash_roofline")(run) == 100.0 * every["share"]
+    forward = scopes.kernel_roofline(run, {**cost, "kernels": ["bf_flash_fwd"]})
+    assert forward["share"] == pytest.approx(1e-6 / (kernels["bf_flash_fwd"] / 1e3))
+    backward = scopes.kernel_roofline(
+        run, {**cost, "kernels": ["bf_flash_dkv", "bf_flash_dq"]}
+    )
+    assert 1 / forward["share"] + 1 / backward["share"] == pytest.approx(1 / every["share"])
+    # a kernel that did not run has no roof to stand under, and off the
+    # chip nothing has
+    assert scopes.kernel_roofline(run, {**cost, "kernels": ["bf_expert_gmm"]}) is None
+    run.peaks = None
+    assert scopes.kernel_roofline(run, cost) is None
