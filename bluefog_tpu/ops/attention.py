@@ -62,16 +62,21 @@ def _expand_kv(q, kv):
 
 
 def reference_attention(q, k, v, causal: bool = False,
-                        scale: Optional[float] = None):
+                        scale: Optional[float] = None, mask=None):
     """Dense softmax attention on full (unsharded) tensors
     ``[batch, seq, heads, dim]`` — the numpy-oracle-grade reference the
     sequence-parallel paths are tested against. K/V with fewer heads than
     Q run grouped-query attention (each KV head serves ``h/h_kv``
-    query heads)."""
+    query heads). ``mask`` is a static mask kind of
+    :mod:`bluefog_tpu.ops.flash` (a ``BlockDiffusionMask``), laid out
+    densely from its elementwise definition."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     k, v = _expand_kv(q, k), _expand_kv(q, v)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if mask is not None:
+        pos = jnp.arange(s.shape[-1])
+        s = jnp.where(mask.allowed(pos[:, None], pos[None, :]), s, -jnp.inf)
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)

@@ -21,12 +21,30 @@ emits the per-row logsumexp) with FlashAttention-2-style backward kernels
 probabilities from Q/K and the saved logsumexp instead of materializing
 the T×T matrix).
 
+Mask kinds, all static (positions come from iotas in the kernel, no mask
+tensor ever exists in HBM): none, ``causal=True``, and
+``mask=BlockDiffusionMask(seq, block)`` — the training mask of block
+diffusion over a doubled sequence (``seq`` clean positions, then their
+``seq`` noised copies; causal over blocks of ``block``, bidirectional
+inside one). Under either mask a tile that holds no allowed pair is
+skipped, not masked (``_when_live``): the causal kernel visits half of the
+square, the block-diffusion kernel about a quarter.
+
+Grouped-query heads (K/V with ``h_kv`` heads, ``h % h_kv == 0``) never
+exist expanded: a KV head serves its query group from the index maps, and
+the dK/dV kernel sums the group in its VMEM accumulator. ``head_dim`` 128
+fills the lane width and is the kernels' best case; any other size runs
+unpadded (see above).
+
 ``flash_attention`` lowers to the dense XLA path on non-TPU platforms
 and for cross-attention (mismatched Q/KV shapes), so callers can use it
-unconditionally. ``interpret=True`` runs the kernels in the Pallas
-interpreter (CPU CI).
+unconditionally — except under a ``mask``: a model that asks for the
+block-diffusion kernel is refused (``ValueError``) where the shapes would
+send it down the dense path, never taken there silently.
+``interpret=True`` runs the kernels in the Pallas interpreter (CPU CI).
 """
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -38,6 +56,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "BlockDiffusionMask",
+    "block_diffusion_live_tiles",
+    "tile_counts",
     "flash_attention",
     "flash_attention_with_lse",
     "flash_attention_supported",
@@ -49,6 +70,107 @@ _LANES = 128
 # width-8 trailing dim keeps the residual 16x smaller than lane-width.
 _SUB = 8
 _NEG_INF = -jnp.inf
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The block-diffusion training mask over ``2 * seq`` positions:
+    positions ``0 .. seq-1`` are the clean tokens, ``seq .. 2 seq - 1`` their
+    noised copies. With ``blk(i) = i // block`` on a half's own index, a
+    clean query ``i`` sees clean key ``j`` iff ``blk(j) <= blk(i)`` and no
+    noised key; a noised query ``seq + i`` sees clean key ``j`` iff
+    ``blk(j) < blk(i)`` and noised key ``seq + j`` iff ``blk(j) == blk(i)``.
+    ``seq**2 + seq * block`` of the ``4 seq**2`` pairs are allowed (for
+    ``block`` dividing ``seq``). Static: it is part of the kernel's
+    configuration."""
+
+    seq: int
+    block: int
+
+    def __post_init__(self):
+        if self.seq < 1 or self.block < 1:
+            raise ValueError(f"seq and block must be positive: {self}")
+
+    def allowed(self, qpos, kpos, xp=jnp):
+        """Elementwise: may the query at ``qpos`` see the key at ``kpos``
+        (positions in the doubled sequence; broadcastable integer arrays
+        of ``xp``, ``numpy`` or ``jax.numpy``)."""
+        q_noised, k_noised = qpos >= self.seq, kpos >= self.seq
+        qb = _block_of(xp.where(q_noised, qpos - self.seq, qpos), self.block)
+        kb = _block_of(xp.where(k_noised, kpos - self.seq, kpos), self.block)
+        # booleans meet only in & | ~: Mosaic has no select between masks
+        return (~k_noised & (kb + q_noised.astype(qb.dtype) <= qb)) | (
+            k_noised & q_noised & (kb == qb)
+        )
+
+    def tile_live(self, iq, ik, block_q, block_k, xp=jnp):
+        """Does tile ``(iq, ik)`` hold an allowed pair: scalars traced in a
+        kernel (``xp=jnp``) or integer arrays on the host (``xp=numpy``).
+        A tile may straddle the two halves (``seq`` need be no multiple of
+        the tile), so each of the three quadrants that allow anything is
+        asked by the first and last block its part of the tile touches."""
+        seq, b, total = self.seq, self.block, 2 * self.seq
+        q0, k0 = iq * block_q, ik * block_k
+        q1 = xp.minimum(q0 + block_q, total)  # exclusive
+        k1 = xp.minimum(k0 + block_k, total)
+        q_clean_hi = _block_of(xp.minimum(q1, seq) - 1, b)
+        q_noised_lo = _block_of(xp.maximum(q0, seq) - seq, b)
+        q_noised_hi = _block_of(q1 - seq - 1, b)
+        k_clean_lo = _block_of(k0, b)
+        k_noised_lo = _block_of(xp.maximum(k0, seq) - seq, b)
+        k_noised_hi = _block_of(k1 - seq - 1, b)
+        q_clean, q_noised = q0 < seq, q1 > seq
+        k_clean, k_noised = k0 < seq, k1 > seq
+        live = (
+            (q_clean & k_clean & (k_clean_lo <= q_clean_hi))
+            | (q_noised & k_clean & (k_clean_lo < q_noised_hi))
+            | (q_noised & k_noised & (k_noised_lo <= q_noised_hi)
+               & (q_noised_lo <= k_noised_hi))
+        )
+        return live & (q0 < total) & (k0 < total)
+
+
+def _block_of(pos, block):
+    # a shift where it can be one: the vector units have no integer divide
+    if block & (block - 1) == 0:
+        return pos >> (block.bit_length() - 1)
+    return pos // block
+
+
+def _tile_grid(t, block_q, block_k):
+    """``(iq, ik)`` index arrays over the tiles of a sequence of ``t``
+    padded to the tiles' common multiple, as ``_flash`` pads it."""
+    tile = int(np.lcm(block_q, block_k))
+    t_pad = -(-t // tile) * tile
+    return np.meshgrid(
+        np.arange(t_pad // block_q), np.arange(t_pad // block_k),
+        indexing="ij",
+    )
+
+
+def block_diffusion_live_tiles(mask, block_q, block_k):
+    """The ``(iq, ik)`` of every tile the kernels visit under ``mask`` (an
+    ``[n, 2]`` numpy array, row-major), at tiles of ``block_q x block_k``:
+    what ``BlockDiffusionMask.tile_live`` says on the host."""
+    iq, ik = _tile_grid(2 * mask.seq, block_q, block_k)
+    return np.argwhere(mask.tile_live(iq, ik, block_q, block_k, xp=np))
+
+
+def tile_counts(t, kind=False, block_q=None, block_k=None):
+    """``(live, total)``: the tiles one forward pass visits for one (batch,
+    head) at sequence length ``t`` under the mask kind ``kind`` (``False``,
+    ``True`` for causal, or a ``BlockDiffusionMask``), and the tiles of the
+    padded square, at the tile sizes ``flash_attention`` would choose."""
+    block_q = _auto_block(t) if block_q is None else block_q
+    block_k = block_q if block_k is None else block_k
+    iq, ik = _tile_grid(t, block_q, block_k)
+    if kind is True:
+        live = ik * block_k < (iq + 1) * block_q  # as `_when_live` skips
+    elif kind:
+        live = kind.tile_live(iq, ik, block_q, block_k, xp=np)
+    else:
+        live = np.ones_like(iq, bool)
+    return int(live.sum()), live.size
 
 
 def _positions(iq, ik, block_q, block_k):
@@ -64,6 +186,8 @@ def _positions(iq, ik, block_q, block_k):
 def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
     """Static-shape validity mask for one score tile, or None when every
     entry is valid (divisible, non-causal shapes compile mask-free).
+    ``causal`` is the mask kind: ``False``, ``True`` or a
+    ``BlockDiffusionMask``.
 
     Raggedness is judged against the PADDED length, not ``block_k``
     alone: with block_q != block_k the lcm rounding can append
@@ -74,12 +198,26 @@ def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
         return None
     qpos, kpos = _positions(iq, ik, block_q, block_k)
     keep = None
-    if causal:
+    if causal is True:
         keep = qpos >= kpos
+    elif causal:
+        keep = causal.allowed(qpos, kpos)
     if ragged:
         valid = kpos < kv_len
         keep = valid if keep is None else keep & valid
     return keep
+
+
+def _when_live(iq, ik, block_q, block_k, causal, tile):
+    """Run ``tile`` for tile ``(iq, ik)`` unless the mask kind ``causal``
+    allows no pair in it: a dead tile is skipped, not masked."""
+    if causal is True:
+        # skip K tiles that lie entirely in the future of this Q tile
+        pl.when(ik * block_k < (iq + 1) * block_q)(tile)
+    elif causal:
+        pl.when(causal.tile_live(iq, ik, block_q, block_k))(tile)
+    else:
+        tile()
 
 
 # -- forward -----------------------------------------------------------------
@@ -123,11 +261,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         acc_ref[:] = acc_ref[:] * corr[:, None] + pv
         m_ref[:, 0] = m_new
 
-    if causal:
-        # skip K tiles that lie entirely in the future of this Q tile
-        pl.when(ik * block_k < (iq + 1) * block_q)(_tile)
-    else:
-        _tile()
+    _when_live(iq, ik, block_q, block_k, causal, _tile)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _finalize():
@@ -255,10 +389,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(ik * block_k < (iq + 1) * block_q)(_tile)
-    else:
-        _tile()
+    _when_live(iq, ik, block_q, block_k, causal, _tile)
 
     @pl.when(iq2 == pl.num_programs(2) - 1)
     def _finalize():
@@ -295,10 +426,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(ik * block_k < (iq + 1) * block_q)(_tile)
-    else:
-        _tile()
+    _when_live(iq, ik, block_q, block_k, causal, _tile)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _finalize():
@@ -535,9 +663,12 @@ def flash_attention_supported(q, k=None, v=None, *, block_q: int = 128,
                               block_k: int = 128) -> bool:
     """Kernel applicability: self-attention shapes only (one shared
     sequence length). Arbitrary sequence length and head_dim are handled
-    by padded-with-masking tiles, and grouped-query K/V (fewer heads,
-    ``h % h_kv == 0``) is served natively from the index maps — so only
-    cross-attention (mismatched batch/seq/dim) falls back."""
+    by padded-with-masking tiles (``head_dim`` 128, a full lane width, is
+    the kernels' best case; 64 runs unpadded at half the MXU's depth), and
+    grouped-query K/V (fewer heads, ``h % h_kv == 0``, K and V alike) is
+    served natively from the index maps, never expanded in HBM — so only
+    cross-attention (mismatched batch/seq/dim) and K/V with differing
+    head counts fall back."""
     del block_q, block_k  # any T tiles via padding; kept for API compat
     if q.ndim != 4 or q.shape[1] < 1:
         return False
@@ -614,7 +745,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    mask: Optional[BlockDiffusionMask] = None):
     """Flash attention on ``[batch, seq, heads, head_dim]`` tensors.
 
     Uses the Pallas TPU kernels (forward AND backward — safe inside
@@ -622,14 +754,30 @@ def flash_attention(q, k, v, causal: bool = False,
     mismatched shapes and non-TPU platforms fall back to the dense XLA
     attention (same math). Tile sizes default to the largest that fits
     the sequence without excessive padding (see :func:`_auto_block`);
-    pass ``block_q``/``block_k`` to override."""
+    pass ``block_q``/``block_k`` to override.
+
+    Mask kinds: none, ``causal=True``, or ``mask=`` a
+    :class:`BlockDiffusionMask` over a sequence of ``2 * mask.seq``
+    positions (not with ``causal``). A ``mask`` is never sent down the
+    dense path for its shapes: what the kernels cannot take raises."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     from bluefog_tpu.ops.attention import reference_attention
 
-    if not flash_attention_supported(
+    supported = flash_attention_supported(
         q, k, v, block_q=block_q, block_k=block_k
-    ):
+    )
+    if mask is not None:
+        if causal:
+            raise ValueError("give causal=True or a mask, not both")
+        if not supported or q.shape[1] != 2 * mask.seq:
+            raise ValueError(
+                f"{mask} needs self-attention over 2 * seq = "
+                f"{2 * mask.seq} positions, got q {q.shape}, k {k.shape}, "
+                f"v {v.shape}: refused rather than run densely"
+            )
+        causal = mask  # the kernels' one static mask kind
+    if not supported:
         return reference_attention(q, k, v, causal=causal, scale=scale)
     if interpret:
         return _flash(q, k, v, causal, float(scale), block_q, block_k,
@@ -643,6 +791,6 @@ def flash_attention(q, k, v, causal: bool = False,
             q, k, v, causal, float(scale), block_q, block_k, False
         ),
         default=lambda q, k, v: reference_attention(
-            q, k, v, causal=causal, scale=scale
+            q, k, v, causal=causal is True, scale=scale, mask=mask
         ).astype(q.dtype),  # branch outputs must agree: dense promotes
     )

@@ -329,6 +329,13 @@ def test_closed_loop_doctor_detects_controller_migrates():
     import optax
 
     ctx = bf.get_context()
+    # this test times real probes, so its cost model is the one this
+    # mesh gives now (the doctor's first sample runs compiler.calibrate()),
+    # not the file's pin: the injected delay is the MODELED round x 19,
+    # and against a pinned 10 us round it clears 3x a healthy probe only
+    # while that probe takes under 0.17 ms, which a worker that has run
+    # other files no longer does
+    compiler.clear_calibration()
     session = bf.elastic.start(policy="average")
     session.inject("degrade", rank=2, step=0, factor=0.05, peer=3)
     # doctor at interval 1: an occasional blame-free probe sample (host
