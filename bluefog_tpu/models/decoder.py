@@ -40,8 +40,12 @@ Scopes (``jax.named_scope``, read back from the compiled step's
 ``bf.moe.route``), ``bf.head`` (final norm, head, loss). Host gauges, set
 when the model is traced (nothing is synced in the step):
 ``bluefog.moe.rows_offered`` (positions x k x layers a call),
-``bluefog.moe.rows_capacity`` (rows the expert layers' buffers hold a
-call), ``bluefog.attn.tiles_live`` / ``bluefog.attn.tiles_total`` (tiles a
+``bluefog.moe.rows_capacity`` (pairs the expert layers' buffers have a
+row for a call), ``bluefog.moe.row_tile`` (rows a tile of those buffers
+takes) and ``bluefog.moe.buffer_rows`` (the buffers' true rows a call,
+slack tiles included: with :func:`bluefog_tpu.ops.moe.tiles_in_use` of the
+returned ``rows_per_expert``, rows touched against rows held),
+``bluefog.attn.tiles_live`` / ``bluefog.attn.tiles_total`` (tiles a
 forward pass of the attention kernels visits / would visit unmasked, over
 batch, heads and layers). The device's own counts come back beside the
 hidden states (``counts``), for the caller to return beside its loss.
@@ -325,9 +329,18 @@ class DecoderLM(nn.Module):
 def _record_static_counts(cfg, batch, positions, mask):
     """What one call offers its expert layers and its attention kernels,
     known from the shapes: host gauges, written while tracing."""
-    rows = batch * positions * cfg.num_experts_per_tok * cfg.num_hidden_layers
+    pairs = batch * positions * cfg.num_experts_per_tok  # a layer
+    rows = pairs * cfg.num_hidden_layers
     metrics_mod.gauge("bluefog.moe.rows_offered").set(rows)
     metrics_mod.gauge("bluefog.moe.rows_capacity").set(rows)
+    tm = moe.row_tile(
+        pairs, cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size,
+        cfg.compute_dtype,
+    )
+    metrics_mod.gauge("bluefog.moe.row_tile").set(tm)
+    metrics_mod.gauge("bluefog.moe.buffer_rows").set(
+        moe.buffer_tiles(pairs, cfg.num_experts, tm) * tm * cfg.num_hidden_layers
+    )
     live, total = flash.tile_counts(positions, _kernel_kind(mask))
     scale = batch * cfg.num_attention_heads * cfg.num_hidden_layers
     metrics_mod.gauge("bluefog.attn.tiles_live").set(live * scale)

@@ -24,9 +24,16 @@ routed to them. That is the contract of :func:`expert_layer`:
 - **no pair that lands here is dropped, whatever the router does**: the
   row buffer holds ``tokens x k`` rows, all that can arrive (and a tile
   of slack a held expert), so there is no capacity factor to tune and
-  nothing to overflow. One path at any imbalance: the products do work
-  only for the rows that did arrive; the gathers that fill and read the
-  buffer, and the activation between the products, go over all of it.
+  nothing to overflow. One path at any imbalance, and the work on the
+  buffer is as long as the tiles in use, not as the buffer: the products'
+  grids, and the passes XLA runs round them — the gathers that fill the
+  buffer (``_dispatch``, and the combine's backward pass), the activation
+  between the products and its derivative, the sum of the buffer's two
+  cotangents — which are loops over chunks of whole tiles whose trip
+  count is read from what landed (``_over_tiles_in_use``). One write as
+  long as the buffer is left: a buffer that a loop fills (the rows, the
+  activation) starts as one value written all over (``_smeared``), and
+  nothing reads the tiles past the last chunk.
 
 The shares add up: over a partition of the experts into held ranges the
 layers' outputs sum to the uncut layer's (``tests/test_moe.py``). On one
@@ -36,19 +43,24 @@ absent chips or their traffic.
 Everything that moves rows is a gather in both directions: grouping is a
 permutation of the ``tokens x k`` pairs, so the transpose of "take the rows
 in sorted order" is "take them back in the inverse order", never a
-scatter-add (``_dispatch``, ``_combine``). And what the grouped product
-leaves in the tiles past the last one in use is never read: it is whatever
-was there, so both directions select (``where``), never multiply by zero.
+scatter-add (``_dispatch``, ``_combine``). And what the tiles past the last
+one in use hold is never read: in a product's output it is whatever was
+there (a kernel's grid ends with the tiles in use), so both directions
+select (``where``), never multiply by zero.
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["route", "expert_layer", "grouped_product", "row_tile"]
+__all__ = [
+    "route", "expert_layer", "grouped_product", "row_tile", "buffer_tiles",
+    "tiles_in_use",
+]
 
 
 def route(u, w_router, k, norm_topk_prob=True, dtype=jnp.float32):
@@ -69,13 +81,6 @@ def route(u, w_router, k, norm_topk_prob=True, dtype=jnp.float32):
     return weights, experts.astype(jnp.int32)
 
 
-def _rows_of_tokens(x, pair_of_row, k, valid):
-    """The row buffer from token space: row ``r`` holds ``x[token of the
-    pair laid out at r]``, zeros where the layout holds no pair. ``x [tokens, d]``
-    -> ``[rows, d]``."""
-    return jnp.where(valid[:, None], x[pair_of_row // k], 0)
-
-
 def _tokens_of_rows(buf, row_of_pair, landed, scale=None):
     """Token space from the row buffer: token ``t`` gets the float32 sum
     over its choices ``s`` that landed of ``buf[row of pair (t, s)]``
@@ -89,62 +94,235 @@ def _tokens_of_rows(buf, row_of_pair, landed, scale=None):
         if scale is not None:
             term = term * scale[:, s, None]
         # a select, not a product with 0: the tiles past the ones in use
-        # are whatever the grouped product left there
+        # are whatever was there
         total = total + jnp.where(landed[:, s, None], term, 0.0)
     return total
+
+
+# -- passes over the row buffer ------------------------------------------------
+#
+# The buffer is laid out in tiles of `tm` rows (below), and the tiles in use
+# are its first `tiles_used`, a device scalar. Whatever XLA does to
+# buffer-shaped values it does a chunk of whole tiles at a time, in a loop
+# whose trip count is ceil(tiles_used / chunk): a pass costs what landed,
+# not what could have. The loops live inside `custom_vjp` forward and
+# backward functions, so nothing differentiates through one.
+
+# Tiles a turn takes: a few, so that the last turn overshoots by little. Not
+# fitted: 4 and 16 read the same step on the chip to 0.01 % (PERF.md, PR 35).
+_CHUNK_TILES = 8
+
+
+def _smeared(shape, x):
+    """A buffer for a pass over the tiles in use to fill, holding one
+    element of ``x`` all over: the one write as long as the buffer. (A loop
+    whose carry starts as an instruction without operands — ``jax.lax.empty``,
+    zeros — makes XLA's scheduler defer kernels all over the step, which
+    then holds 1.5 GB more: PERF.md, PR 35.)"""
+    return jnp.broadcast_to(x.reshape(-1)[0], shape)
+
+
+def _over_tiles_in_use(tm, tiles_used, turn, bufs, reads=(), others=()):
+    """The row buffers ``bufs`` after ``turn(tm, first_tile, *blocks of
+    bufs, *blocks of reads, *others) -> new blocks of bufs`` over every
+    chunk of ``_CHUNK_TILES`` whole tiles that holds a tile in use (a buffer
+    is whole chunks long: ``buffer_tiles``); ``reads`` are row buffers too,
+    ``others`` whatever else a turn reads. A buffer whose old rows the turn
+    reads is rewritten in place: a cotangent takes the place of the value
+    it is the last to read. ``turn`` is a function of the module, not a
+    closure (see ``_body``)."""
+    turns = -(-tiles_used // _CHUNK_TILES)
+    carry = (tuple(bufs), tuple(reads), tuple(others))
+    return jax.lax.fori_loop(0, turns, _body(turn, tm), carry)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _body(turn, tm):
+    """One loop body a kind of pass and tile: jax keeps a body's trace by
+    the function it is, so the six layers of a stack (and the passes over
+    each) trace it once; what a turn reads goes round in the carry."""
+    rows = _CHUNK_TILES * tm
+
+    def body(i, carry):
+        bufs, reads, others = carry
+        take = lambda buf: jax.lax.dynamic_slice_in_dim(buf, i * rows, rows)
+        blocks = turn(
+            tm, i * _CHUNK_TILES, *map(take, bufs), *map(take, reads), *others
+        )
+        bufs = tuple(
+            jax.lax.dynamic_update_slice_in_dim(buf, block, i * rows, 0)
+            for buf, block in zip(bufs, blocks)
+        )
+        return bufs, reads, others
+
+    return body
 
 
 # Grouping is a permutation of the tokens x k pairs, so "take the rows in
 # sorted order" transposes to "take them back along the inverse", a gather
 # again; autodiff would emit a scatter-add, which the TPU runs an update at
-# a time. `moves` = (pair_of_row [rows], row_of_pair [tokens, k], landed
-# [tokens, k], valid [rows]): integers and booleans, no cotangent.
+# a time.
 
 
-@jax.custom_vjp
-def _dispatch(u, moves):
-    pair_of_row, _, landed, valid = moves
-    return _rows_of_tokens(u, pair_of_row, landed.shape[1], valid)
+class _Moves(NamedTuple):
+    """Where the pairs go: integers and booleans, no cotangent."""
+
+    sorted_pairs: jax.Array  # [pairs + tm] the pairs by held expert, absent last
+    tile_rank: jax.Array  # [tiles] sorted rank of a tile's first pair
+    tile_rows: jax.Array  # [tiles] pairs a tile holds (0 past the ones in use)
+    tiles_used: jax.Array  # [] the buffer's first tiles, the ones in use
+    row_of_pair: jax.Array  # [tokens, k] where a pair landed
+    landed: jax.Array  # [tokens, k] whether it did
 
 
-def _dispatch_fwd(u, moves):
-    return _dispatch(u, moves), moves
+def _pairs_of_rows(tm, first, moves):
+    """The rows of the chunk of tiles from ``first``: ``(pair, valid)``, the
+    pair each row holds and whether it holds one. A tile holds ``tile_rows``
+    pairs, consecutive in sorted order from ``tile_rank`` — a slice of
+    ``sorted_pairs`` a tile, not a lookup a row (it is padded by a tile, so
+    that no slice is moved to fit) — then rows of zeros."""
+    take = lambda per_tile: jax.lax.dynamic_slice_in_dim(per_tile, first, _CHUNK_TILES)
+    run = lambda rank: jax.lax.dynamic_slice_in_dim(moves.sorted_pairs, rank, tm)
+    valid = jnp.arange(tm, dtype=jnp.int32) < take(moves.tile_rows)[:, None]
+    return jax.vmap(run)(take(moves.tile_rank)).reshape(-1), valid.reshape(-1)
 
 
-def _dispatch_bwd(moves, dxs):
-    _, row_of_pair, landed, _ = moves
-    return _tokens_of_rows(dxs, row_of_pair, landed).astype(dxs.dtype), None
+def _rows_of_tokens(x, pair, valid, k):
+    """Rows from token space: row ``r`` holds ``x[token of pair[r]]``,
+    zeros where it holds no pair. ``x [tokens, d]`` -> ``[len(pair), d]``."""
+    return jnp.where(valid[:, None], x[pair // k], 0)
+
+
+def _fill_turn(tm, first, _, u, *moves):
+    moves = _Moves(*moves)
+    pair, valid = _pairs_of_rows(tm, first, moves)
+    return (_rows_of_tokens(u, pair, valid, moves.landed.shape[1]),)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(tm, u, moves):
+    xs = _smeared((moves.tile_rows.shape[0] * tm, u.shape[1]), u)
+    return _over_tiles_in_use(
+        tm, moves.tiles_used, _fill_turn, (xs,), others=(u, *moves)
+    )[0]
+
+
+def _dispatch_fwd(tm, u, moves):
+    return _dispatch(tm, u, moves), moves
+
+
+def _dispatch_bwd(tm, moves, dxs):
+    return _tokens_of_rows(dxs, moves.row_of_pair, moves.landed).astype(dxs.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(out, weights, moves):
-    _, row_of_pair, landed, _ = moves
-    return _tokens_of_rows(out, row_of_pair, landed, weights)
+def _sums_turn(tm, first, _, out, dy, *moves):
+    moves = _Moves(*moves)
+    pair, valid = _pairs_of_rows(tm, first, moves)
+    dy_rows = _rows_of_tokens(dy, pair, valid, moves.landed.shape[1])  # float32
+    products = jnp.where(valid[:, None], dy_rows * out.astype(jnp.float32), 0.0)
+    return (jnp.sum(products, axis=-1),)
 
 
-def _combine_fwd(out, weights, moves):
-    return _combine(out, weights, moves), (out, weights, moves)
+def _weigh_turn(tm, first, out, d_w_rows, dy, weights, *moves):
+    moves = _Moves(*moves)
+    pair, valid = _pairs_of_rows(tm, first, moves)
+    dy_rows = _rows_of_tokens(dy, pair, valid, moves.landed.shape[1])
+    w_rows = weights.reshape(-1)[pair]
+    return (dy_rows * w_rows[:, None]).astype(out.dtype), d_w_rows
 
 
-def _combine_bwd(res, dy):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(tm, out, weights, moves):
+    del tm
+    return _tokens_of_rows(out, moves.row_of_pair, moves.landed, weights)
+
+
+def _combine_fwd(tm, out, weights, moves):
+    return _combine(tm, out, weights, moves), (out, weights, moves)
+
+
+def _combine_bwd(tm, res, dy):
     out, weights, moves = res
-    pair_of_row, row_of_pair, landed, valid = moves
-    k = landed.shape[1]
-    dy_rows = _rows_of_tokens(dy, pair_of_row, k, valid)  # float32
-    w_rows = weights.reshape(-1)[pair_of_row]
-    d_out = (dy_rows * w_rows[:, None]).astype(out.dtype)
-    d_w_rows = jnp.sum(
-        jnp.where(valid[:, None], dy_rows * out.astype(jnp.float32), 0.0), axis=-1
+    # Two passes, so that the cotangent can take `out`'s place: the row sums
+    # are the last to read `out`, and the second loop rewrites them as they
+    # are, which orders it behind them. (Handed to it as one of `others`
+    # they are dropped from its carry as unused, and XLA copies `out`: the
+    # compile test holds that. One pass would read and write the buffer in
+    # two kernels of a turn, and XLA then copies all of it every turn.)
+    (d_w_rows,) = _over_tiles_in_use(
+        tm, moves.tiles_used, _sums_turn,
+        (_smeared(out.shape[:1], dy),), (out,), (dy, *moves),
     )
-    index = jnp.minimum(row_of_pair, out.shape[0] - 1)
-    d_w = jnp.where(landed, d_w_rows[index], 0.0)
+    d_out, d_w_rows = _over_tiles_in_use(
+        tm, moves.tiles_used, _weigh_turn,
+        (out, d_w_rows), others=(dy, weights, *moves),
+    )
+    index = jnp.minimum(moves.row_of_pair, out.shape[0] - 1)
+    d_w = jnp.where(moves.landed, d_w_rows[index], 0.0)
     return d_out, d_w.astype(weights.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _add_turn(tm, first, a, b):
+    return (a + b,)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _twice(tm, xs, tiles_used):
+    """``xs`` for each of two readers; their cotangents are added over the
+    tiles in use (autodiff's own sum would go over the whole buffer)."""
+    del tm, tiles_used
+    return xs, xs
+
+
+def _twice_fwd(tm, xs, tiles_used):
+    return _twice(tm, xs, tiles_used), tiles_used
+
+
+def _twice_bwd(tm, tiles_used, ds):
+    return *_over_tiles_in_use(tm, tiles_used, _add_turn, ds[:1], ds[1:]), None
+
+
+_twice.defvjp(_twice_fwd, _twice_bwd)
+
+
+def _silu_times(gate, up):
+    return (
+        jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    ).astype(gate.dtype)
+
+
+def _act_turn(tm, first, _, gate, up):
+    return (_silu_times(gate, up),)
+
+
+def _act_bwd_turn(tm, first, gate, up, d_act):
+    return jax.vjp(_silu_times, gate, up)[1](d_act)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _activation(tm, gate, up, tiles_used):
+    """``silu(gate) * up`` in float32, back in the products' dtype, over
+    the tiles in use; its derivative is autodiff's own, a chunk at a time."""
+    act = _smeared(gate.shape, gate)
+    return _over_tiles_in_use(tm, tiles_used, _act_turn, (act,), (gate, up))[0]
+
+
+def _activation_fwd(tm, gate, up, tiles_used):
+    return _activation(tm, gate, up, tiles_used), (gate, up, tiles_used)
+
+
+def _activation_bwd(tm, res, d_act):
+    *gate_up, tiles_used = res
+    return *_over_tiles_in_use(tm, tiles_used, _act_bwd_turn, gate_up, (d_act,)), None
+
+
+_activation.defvjp(_activation_fwd, _activation_bwd)
 
 
 # -- the grouped product -------------------------------------------------------
@@ -175,6 +353,28 @@ def row_tile(rows, held, k, n, dtype):
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)):
         return 512 if rows >= 512 * held else 128
     return 8
+
+
+def buffer_tiles(rows, held, tm):
+    """Tiles the buffer holds (static): every pair that can arrive, and a
+    tile of slack a held expert — a group ends inside a tile at most once —
+    filled up to whole chunks of the passes over it."""
+    return -(-(-(-rows // tm) + held) // _CHUNK_TILES) * _CHUNK_TILES
+
+
+def _tiles_per_group(rows_per_expert, tm):
+    # the layout: a group starts on a tile and keeps at least one
+    return jnp.maximum(1, -(-rows_per_expert // tm))
+
+
+def tiles_in_use(rows_per_expert, tm):
+    """Tiles of ``tm`` rows the layer's passes went over, from the
+    ``rows_per_expert [..., experts_held]`` it returned and the tile it
+    laid them out in (``row_tile``; the gauge ``bluefog.moe.row_tile``):
+    the sum over held experts of ``max(1, ceil(rows / tm))``. Times ``tm``,
+    against ``buffer_tiles x tm`` (``bluefog.moe.buffer_rows``), it is the
+    share of the buffer touched."""
+    return _tiles_per_group(rows_per_expert, tm).sum(axis=-1)
 
 
 def _vma(*xs):
@@ -370,8 +570,7 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
     held = w_gate.shape[0]
     pairs = n * k  # what can arrive: every choice of every token
     tm = row_tile(pairs, held, w_gate.shape[1], w_gate.shape[2], dtype)
-    tiles = -(-pairs // tm) + held  # a group ends inside a tile at most once
-    rows = tiles * tm
+    tiles = buffer_tiles(pairs, held, tm)
 
     with jax.named_scope("bf.moe.route"):
         local = experts.reshape(pairs) - held_start
@@ -390,43 +589,43 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
         # pair: the difference is 0 unless this arithmetic is broken
         rows_dropped = landed.sum().astype(jnp.int32) - group_sizes.sum()
 
-        # the layout: a group starts on a tile and keeps at least one
-        tiles_per_group = jnp.maximum(1, -(-group_sizes // tm))
+        tiles_per_group = _tiles_per_group(group_sizes, tm)
+        tiles_used = tiles_per_group.sum()
         tile_end = jnp.cumsum(tiles_per_group)
-        first_row = (tile_end - tiles_per_group) * tm  # of a group, in the buffer
+        first_tile = tile_end - tiles_per_group  # of a group, in the buffer
         first_rank = jnp.cumsum(group_sizes) - group_sizes  # in sorted order
         tile_group = jnp.minimum(
             jnp.sum(jnp.arange(tiles)[:, None] >= tile_end, axis=1, dtype=jnp.int32),
             held - 1,
         )
-        # buffer row -> the pair it holds (tile by tile: small lookups)
-        in_group = (
-            jnp.arange(rows, dtype=jnp.int32).reshape(tiles, tm)
-            - first_row[tile_group][:, None]
-        )
-        valid = (in_group < group_sizes[tile_group][:, None]).reshape(rows)
-        rank_of_row = (first_rank[tile_group][:, None] + in_group).reshape(rows)
-        pair_of_row = sorted_pairs[jnp.clip(rank_of_row, 0, pairs - 1)]
+        # tile -> the pairs it holds: how many, and the first one's rank (a
+        # tile past the ones in use holds none); the rows themselves are
+        # worked out a chunk at a time, where they are moved
+        before = (jnp.arange(tiles, dtype=jnp.int32) - first_tile[tile_group]) * tm
+        tile_rows = jnp.clip(group_sizes[tile_group] - before, 0, tm)
+        tile_rank = first_rank[tile_group] + before
         # pair -> its buffer row (where it landed)
-        shift = jnp.append(first_row - first_rank, 0)
+        shift = jnp.append(first_tile * tm - first_rank, 0)
         row_of_pair = rank_of_pair + jnp.sum(is_group * shift, axis=1, dtype=jnp.int32)
-        landed = landed.reshape(n, k)
 
-        moves = (pair_of_row, row_of_pair.reshape(n, k), landed, valid)
-        xs = _dispatch(u.astype(dtype), moves)
+        moves = _Moves(
+            jnp.pad(sorted_pairs, (0, tm)), tile_rank, tile_rows, tiles_used,
+            row_of_pair.reshape(n, k), landed.reshape(n, k),
+        )
+        xs = _dispatch(tm, u.astype(dtype), moves)
     with jax.named_scope("bf.moe.experts"):
         def product(lhs, rhs):
             return grouped_product(
                 (tm, interpret), lhs, rhs.astype(dtype), (tile_group, tiles_per_group)
             )
 
-        gate, up = product(xs, w_gate), product(xs, w_up)
-        act = (
-            jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        ).astype(dtype)
+        xs_gate, xs_up = _twice(tm, xs, tiles_used)
+        act = _activation(
+            tm, product(xs_gate, w_gate), product(xs_up, w_up), tiles_used
+        )
         out = product(act, w_down)
     with jax.named_scope("bf.moe.combine"):
-        y = _combine(out, weights, moves).astype(dtype)
+        y = _combine(tm, out, weights, moves).astype(dtype)
 
     counts = {
         "rows_per_expert": group_sizes,

@@ -44,6 +44,39 @@ def share(w, top, chosen, start, held):
     )
 
 
+# Rows a held expert gets (the pattern repeats over the held experts), for
+# what a pass bounded by the tiles in use (tiles of 8 rows off the TPU,
+# chunks of 8 tiles) can get wrong. The rest of the pairs go elsewhere.
+IMBALANCES = {
+    "one-tile-each": [3, 1, 8, 5],   # exactly one tile in use a group
+    "tile-boundary": [16, 8, 24, 0],  # groups that end on a tile's last row
+    "ragged-chunks": [30, 17, 9, 12],  # 11 tiles in use of 4 groups: 1 3/8 chunks
+}
+
+
+def forced_choices(case, rng, start, held):
+    """``chosen [N, K]`` with ``IMBALANCES[case]`` rows on each held expert
+    and every other pair on an absent one; ``"all-held"``: every pair lands."""
+    if case == "all-held":
+        return np.stack([rng.permutation(held)[:K] for _ in range(N)]) + start
+    want = np.resize(IMBALANCES[case], held)
+    flat = np.full(N * K, (start + held) % E)
+    flat[rng.permutation(N * K)[:want.sum()]] = np.repeat(np.arange(held), want) + start
+    return flat.reshape(N, K)
+
+
+def equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters, each
+    with the names of the primitives it sits inside."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub, inside + (eqn.primitive.name,))
+
+
 @pytest.mark.parametrize("shares", [1, 2, 4, 8, 16])
 def test_the_shares_add_up_to_the_uncut_layer(shares):
     w = weights()
@@ -76,16 +109,20 @@ def test_the_router_keeps_its_width_and_normalises_the_chosen():
     )
 
 
-@pytest.mark.parametrize("case", ["all-held", "one-expert", "two-experts", "none-held"])
+@pytest.mark.parametrize(
+    "case", ["all-held", "one-expert", "two-experts", "none-held", *IMBALANCES]
+)
 def test_no_row_is_lost_whatever_the_router_does(case):
     """Every choice of every token forced onto the held range (the row
-    buffer's worst case), onto one expert, onto two, and onto none."""
+    buffer's worst case, nearly every tile in use), onto one expert, onto
+    two, and onto none; and what stresses a pass bounded by the tiles in
+    use (``IMBALANCES``)."""
     w = weights(2)
     held, start = 4, 8
     rng = np.random.RandomState(3)
     top = jnp.asarray(rng.dirichlet(np.ones(K), N), jnp.float32)
-    if case == "all-held":
-        chosen = np.stack([rng.permutation(held)[:K] for _ in range(N)]) + start
+    if case == "all-held" or case in IMBALANCES:
+        chosen = forced_choices(case, rng, start, held)
     elif case == "one-expert":
         chosen = np.full((N, K), start + 2)
     elif case == "two-experts":
@@ -94,24 +131,31 @@ def test_no_row_is_lost_whatever_the_router_does(case):
         chosen = np.stack([rng.permutation(start)[:K] for _ in range(N)])
     chosen = jnp.asarray(chosen, jnp.int32)
     y, counts = share(w, top, chosen, start, held)
-    want_rows = np.bincount(
-        np.asarray(chosen).ravel() - start, minlength=held
-    )[:held] if case != "none-held" else np.zeros(held, int)
+    local = np.asarray(chosen).ravel() - start
+    want_rows = np.bincount(local[(local >= 0) & (local < held)], minlength=held)
+    if case in IMBALANCES:
+        assert want_rows.tolist() == IMBALANCES[case]
     assert np.asarray(counts["rows_per_expert"]).tolist() == want_rows.tolist()
     assert int(counts["rows_dropped"]) == 0
-    assert int(counts["rows_absent"]) == (N * K if case == "none-held" else 0)
+    assert int(counts["rows_absent"]) == N * K - want_rows.sum()
     sl = slice(start, start + held)  # the plain layer over the held experts
     want = plain_layer(w["u"], top, chosen - start, w["gate"][sl], w["up"][sl], w["down"][sl])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5, atol=2e-6)
+    assert not np.asarray(y)[~((local >= 0) & (local < held)).reshape(N, K).any(1)].any()
     if case == "none-held":
         assert not np.asarray(y).any()  # a token with no held choice gets zeros
 
 
+@pytest.mark.parametrize("imbalance", ["router", "all-held", *IMBALANCES])
 @pytest.mark.parametrize("wrt", ["u", "gate", "up", "down", "top"])
-def test_gradients_match_the_plain_layer(wrt):
+def test_gradients_match_the_plain_layer(wrt, imbalance):
     w = weights(4)
     top, chosen = moe.route(w["u"], w["router"], K)
     start, held = 4, 8
+    if imbalance != "router":
+        chosen = jnp.asarray(
+            forced_choices(imbalance, np.random.RandomState(5), start, held), jnp.int32
+        )
     probe = jnp.cos(jnp.arange(N * D, dtype=jnp.float32)).reshape(N, D)
 
     def through(fn, x):
@@ -146,8 +190,96 @@ def test_moving_rows_is_a_gather_in_both_directions():
     grad = jax.grad(lambda u: moe.expert_layer(
         u, top, chosen, w["gate"][:2], w["up"][:2], w["down"][:2],
     )[0].sum())
-    text = str(jax.make_jaxpr(grad)(w["u"]))
-    assert "scatter" not in text and "ragged_dot" in text and "cond" not in text
+    jaxpr = jax.make_jaxpr(grad)(w["u"])
+    text = str(jaxpr)
+    assert "scatter" not in text and "ragged_dot" in text
+    # the primitive, not the word: a loop's jaxpr prints its `cond_jaxpr`
+    primitives = {eqn.primitive.name for eqn, _ in equations(jaxpr.jaxpr)}
+    assert "cond" not in primitives and "while" in primitives
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
+def test_no_pass_outside_a_loop_is_as_long_as_the_buffer(path):
+    """In the gradient of the whole layer (all five) nothing that moves or
+    touches rows — a gather, a select, a cast, a product, a sum — makes a
+    value as long as the row buffer outside a loop over the tiles in use or
+    a kernel. The one exception is named: the write that starts a buffer a
+    loop fills (``_smeared``: a scalar broadcast), one each for the rows,
+    the activation and the row sums of the combine's backward pass."""
+    if path == "kernels":
+        n, d, f, held, interpret = 256, 128, 128, 4, True
+    else:
+        n, d, f, held, interpret = N, D, F, 4, False
+    rng = np.random.RandomState(8)
+    mk = lambda *shape: jnp.asarray(0.1 * rng.randn(*shape), jnp.float32)
+    top = jnp.asarray(rng.dirichlet(np.ones(K), n), jnp.float32)
+    chosen = jnp.asarray(rng.randint(0, E, (n, K)), jnp.int32)
+    tm = moe.row_tile(n * K, held, d, f, jnp.float32)
+    assert tm == (128 if interpret else 8)
+    rows = moe.buffer_tiles(n * K, held, tm) * tm
+    assert rows >= 2 * 8 * tm  # more than a chunk of 8 tiles
+
+    def loss(u, top, gate, up, down):
+        return moe.expert_layer(u, top, chosen, gate, up, down, interpret=interpret)[0].sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        mk(n, d), top, mk(held, d, f), mk(held, d, f), mk(held, f, d)
+    )
+    passes = (
+        "gather", "select_n", "convert_element_type", "mul", "add", "add_any",
+        "broadcast_in_dim",
+    )
+    long, started, in_loops = [], [], 0
+    for eqn, inside in equations(jaxpr.jaxpr):
+        shapes = [getattr(v.aval, "shape", ()) for v in eqn.outvars]
+        if not any(shape and shape[0] == rows for shape in shapes):
+            continue
+        if "while" in inside or "pallas_call" in inside:
+            in_loops += 1
+        elif eqn.primitive.name == "broadcast_in_dim" and eqn.invars[0].aval.shape == ():
+            started.append(shapes[0][1:])
+        elif eqn.primitive.name in passes:
+            long.append(str(eqn))
+    assert not long, long
+    assert sorted(started) == sorted([(), (d,), (f,)]), started
+    assert in_loops  # the buffers are written where the test looks away
+
+
+@pytest.mark.parametrize("case", ["router", "all-held", "none-held", *IMBALANCES])
+def test_tiles_in_use_is_the_layout_the_products_are_given(case, monkeypatch):
+    """``tiles_in_use`` of the ``rows_per_expert`` a call returns is the
+    number of tiles its grouped products and its loops went over: the
+    layout's first that many tiles belong to the held experts in order,
+    each as many as its rows take and at least one."""
+    w = weights(9)
+    held, start, tm = 4, 8, 8
+    rng = np.random.RandomState(10)
+    top, chosen = moe.route(w["u"], w["router"], K)
+    if case == "none-held":
+        chosen = chosen % start
+    elif case != "router":
+        chosen = jnp.asarray(forced_choices(case, rng, start, held), jnp.int32)
+    seen = []
+    product = moe.grouped_product
+
+    def spy(static, lhs, rhs, tiles):
+        seen.append((static[0], lhs.shape[0], *map(np.asarray, tiles)))
+        return product(static, lhs, rhs, tiles)
+
+    monkeypatch.setattr(moe, "grouped_product", spy)
+    _, counts = share(w, top, chosen, start, held)
+    rows = np.asarray(counts["rows_per_expert"])
+    used = int(moe.tiles_in_use(rows, tm))
+    assert used == sum(max(1, -(-int(r) // tm)) for r in rows)
+    assert len(seen) == 3
+    for tile, buffer_rows, tile_group, per_group in seen:
+        assert tile == tm == moe.row_tile(N * K, held, D, F, jnp.float32)
+        assert buffer_rows == moe.buffer_tiles(N * K, held, tm) * tm >= used * tm
+        assert per_group.sum() == used
+        assert tile_group[:used].tolist() == np.repeat(np.arange(held), per_group).tolist()
+    # stacked over layers (or workers), as a caller's `aux` holds them
+    stacked = moe.tiles_in_use(np.stack([rows, rows[::-1]]), tm)
+    assert np.asarray(stacked).tolist() == [used, used]
 
 
 PATHS = {  # tm, k, n, interpret: the kernels in the interpreter, and ragged_dot

@@ -196,6 +196,11 @@ def test_scopes_and_gauges_of_one_traced_loss():
     peek = lambda name: metrics.peek(name).value
     assert peek("bluefog.moe.rows_offered") == positions * k * layers
     assert peek("bluefog.moe.rows_capacity") == positions * k * layers
+    # the buffers' true size: tiles of 8 rows off the kernels' shapes, a row
+    # for every pair and a tile of slack a held expert, in whole chunks of 8
+    tiles = positions * k // 8 + job.model.cfg.num_experts
+    assert peek("bluefog.moe.row_tile") == 8
+    assert peek("bluefog.moe.buffer_rows") == -(-tiles // 8) * 8 * 8 * layers
     live, total = peek("bluefog.attn.tiles_live"), peek("bluefog.attn.tiles_total")
     assert 0 < live <= total and total == BATCH * 4 * layers  # one tile a head
 
