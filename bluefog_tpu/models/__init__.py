@@ -13,11 +13,11 @@ from bluefog_tpu.models.resnet import (
 from bluefog_tpu.models.mlp import MLP, MnistCNN
 from bluefog_tpu.models.transformer import TransformerLM
 from bluefog_tpu.models.decoder import (
-    DecoderConfig, DecoderLM, block_diffusion_loss,
+    DecoderConfig, DecoderLM, block_diffusion_loss, next_token_loss,
 )
 
 __all__ = [
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "MLP", "MnistCNN", "TransformerLM",
-    "DecoderConfig", "DecoderLM", "block_diffusion_loss",
+    "DecoderConfig", "DecoderLM", "block_diffusion_loss", "next_token_loss",
 ]

@@ -8,6 +8,9 @@ is built from the keys a sparse-expert decoder's ``config.json`` carries —
 ``rope_theta``, ``num_experts``, ``num_experts_per_tok``,
 ``moe_intermediate_size``, ``norm_topk_prob``, ``num_hidden_layers``,
 ``vocab_size`` — plus what no such file says (:class:`DecoderConfig`).
+Two of the source's keys choose a layer's kind: ``kv_lora_rank`` present
+makes its attention **latent** (below), ``n_shared_experts`` > 0 puts a
+shared expert beside the routed ones.
 
 One layer, input ``x [positions, hidden]``::
 
@@ -25,8 +28,25 @@ expert-parallel deployment (:mod:`bluefog_tpu.ops.moe`): the router keeps
 ``num_experts`` of them from ``experts_start``. After the last layer an
 RMSNorm and an untied float32 head.
 
+**Latent attention** (DeepSeek-V2's MLA, :class:`LatentAttention`) takes the
+place of the first three lines: queries go through a low-rank pair with a
+norm between, keys and values are expanded per head from one compressed
+stream, and the rotary part of the key is ONE vector shared by all heads::
+
+    c_q = RMSNorm(u W_qa);  q = c_q W_qb -> heads x [q_nope | q_rope]
+    [c_kv | k_r] = u W_kva;  c_kv = RMSNorm(c_kv)
+    c_kv W_kvb -> heads x [k_nope | v]
+    q_rope, k_r <- rotary over interleaved pairs (2i, 2i+1), the
+                   frequencies of ``rope_parameters`` (:class:`RopeParameters`)
+    k = [k_nope | k_r broadcast over heads]
+    h = x + concat(softmax(s q k^T, causal) v) W_o
+
+**A shared expert** adds ``W_d(silu(u' W_g) * (u' W_u))`` of width
+``n_shared_experts x moe_intermediate_size`` to ``y``, whole on every chip;
+the routed sum is scaled by ``routed_scaling_factor``.
+
 The model is a mask-kind away from either objective: causal next-token
-prediction (``mask="causal"``) or **block diffusion**
+prediction (``mask="causal"``, :func:`next_token_loss`) or **block diffusion**
 (:func:`block_diffusion_loss`; BD3-LMs, Arriola et al. 2025, the objective
 the SDAR family trains with): the clean sequence and a noised copy go
 through the stack together under
@@ -34,10 +54,14 @@ through the stack together under
 noised half only.
 
 Scopes (``jax.named_scope``, read back from the compiled step's
-``op_name``s): ``bf.attn`` (projections, norms, rotary, the kernel),
-``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.combine``
+``op_name``s): ``bf.attn`` (projections, norms, rotary, the kernel) and
+inside it ``bf.attn.latent`` (latent attention's down- and up-projections,
+its two norms, rotary and the assembly of the keys: what a latent-aware
+kernel would not need; the kernels and ``o_proj`` stay directly under
+``bf.attn``), ``bf.moe.route`` / ``bf.moe.experts`` / ``bf.moe.combine``
 (:func:`bluefog_tpu.ops.moe.expert_layer`; the router is under
-``bf.moe.route``), ``bf.head`` (final norm, head, loss). Host gauges, set
+``bf.moe.route``), ``bf.moe.shared`` (the shared expert), ``bf.head``
+(final norm, head, loss). Host gauges, set
 when the model is traced (nothing is synced in the step):
 ``bluefog.moe.rows_offered`` (positions x k x layers a call),
 ``bluefog.moe.rows_capacity`` (pairs the expert layers' buffers have a
@@ -47,11 +71,17 @@ slack tiles included: with :func:`bluefog_tpu.ops.moe.tiles_in_use` of the
 returned ``rows_per_expert``, rows touched against rows held),
 ``bluefog.attn.tiles_live`` / ``bluefog.attn.tiles_total`` (tiles a
 forward pass of the attention kernels visits / would visit unmasked, over
-batch, heads and layers). The device's own counts come back beside the
+batch, heads and layers); with latent attention
+``bluefog.attn.kv_latent_bytes`` (the compressed stream a call makes:
+positions x (kv_lora_rank + qk_rope_head_dim) x layers x itemsize) and
+``bluefog.attn.kv_expanded_bytes`` (the per-head keys and values the
+kernels read instead); with a shared expert ``bluefog.moe.shared_rows``
+(positions x layers a call). The device's own counts come back beside the
 hidden states (``counts``), for the caller to return beside its loss.
 """
 
 import dataclasses
+import math
 from typing import Any, Optional, Union
 
 import flax.linen as nn
@@ -61,9 +91,96 @@ import jax.numpy as jnp
 from bluefog_tpu import metrics as metrics_mod
 from bluefog_tpu.ops import flash, moe
 
-__all__ = ["DecoderConfig", "DecoderLM", "block_diffusion_loss"]
+__all__ = [
+    "DecoderConfig", "DecoderLM", "RopeParameters", "block_diffusion_loss",
+    "next_token_loss",
+]
 
 MaskKind = Union[None, str, flash.BlockDiffusionMask]
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParameters:
+    """A source's ``rope_parameters`` group under its own keys. ``yarn``
+    (Peng et al. 2023, in the convention of the family the keys come from,
+    DeepSeek-V2 / V3's) blends each rotary frequency between the plain one
+    and one ``factor`` times slower, by where its wavelength falls against
+    ``original_max_position_embeddings``, and rescales the softmax."""
+
+    rope_theta: float = 10000.0
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max_position_embeddings: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    llama_4_scaling_beta: Optional[float] = None
+
+    @classmethod
+    def from_source(cls, group):
+        group = dict(group)
+        legacy = group.pop("type", None)  # the key's older name, kept beside it
+        kind = group.setdefault("rope_type", legacy or "default")
+        if legacy not in (None, kind) or kind not in ("default", "yarn"):
+            raise ValueError(
+                f"rope_type = {kind!r}: this stack builds only 'default' and 'yarn'"
+            )
+        names = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(set(group) - names)
+        if unknown:
+            raise ValueError(f"rope_parameters holds keys {unknown} nothing reads")
+        if kind == "yarn" and not group.get("original_max_position_embeddings"):
+            raise ValueError("yarn needs original_max_position_embeddings")
+        return cls(**group)
+
+    def _mscale(self, scale):
+        # m(f, a) = 0.1 a ln f + 1
+        if self.rope_type != "yarn" or self.factor <= 1:
+            return 1.0
+        return 0.1 * scale * math.log(self.factor) + 1.0
+
+    def inv_freq(self, d):
+        """The ``d / 2`` rotary frequencies, float32: ``theta^(-2i/d)``, and
+        under yarn ``(f / factor) ramp_i + f (1 - ramp_i)`` with ``ramp``
+        rising from 0 to 1 between the dimensions whose wavelengths make
+        ``beta_fast`` and ``beta_slow`` turns over the original length."""
+        theta = float(self.rope_theta)
+        freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        if self.rope_type != "yarn":
+            return freq
+
+        def corr(turns):
+            return (
+                d * math.log(self.original_max_position_embeddings
+                             / (turns * 2 * math.pi)) / (2 * math.log(theta))
+            )
+
+        low = max(math.floor(corr(self.beta_fast)), 0)
+        high = min(math.ceil(corr(self.beta_slow)), d - 1)
+        span = (high - low) or 0.001  # the family's guard against 0 / 0
+        ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / span, 0, 1)
+        return freq / self.factor * ramp + freq * (1 - ramp)
+
+    @property
+    def cos_sin_scale(self):
+        """What yarn multiplies cos and sin by."""
+        return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
+
+    def softmax_scale(self, d):
+        """``m(factor, mscale_all_dim)^2 / sqrt(d)``."""
+        return self._mscale(self.mscale_all_dim) ** 2 / math.sqrt(d)
+
+    def query_scale(self, positions):
+        """``1 + beta ln(1 + floor(p / original_max_position_embeddings))``
+        per position, float32 (llama-4's length-dependent temperature: 1
+        inside the original length); ``None`` without a beta."""
+        if not self.llama_4_scaling_beta:
+            return None
+        chunks = jnp.floor(
+            positions.astype(jnp.float32) / self.original_max_position_embeddings
+        )
+        return 1.0 + self.llama_4_scaling_beta * jnp.log1p(chunks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,8 +188,11 @@ class DecoderConfig:
     """The source's keys under their own names, then what it does not say.
 
     ``num_experts`` is the number of experts **held here** (the leading
-    axis of the expert leaves); ``experts_total`` the router's width, the
-    published count; the held range starts at ``experts_start``."""
+    axis of the expert leaves; a source that calls it ``n_routed_experts``
+    is read under that name); ``experts_total`` the router's width, the
+    published count; the held range starts at ``experts_start``.
+    ``kv_lora_rank`` chooses latent attention, ``n_shared_experts`` the
+    shared expert."""
 
     hidden_size: int
     num_hidden_layers: int
@@ -86,6 +206,16 @@ class DecoderConfig:
     norm_topk_prob: bool = True
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    # latent attention, a shared expert (the source's keys still)
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: Optional[int] = None
+    rope_interleave: bool = False
+    rope_parameters: Optional[RopeParameters] = None
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
     # not the source's
     experts_total: Optional[int] = None
     experts_start: int = 0
@@ -122,6 +252,27 @@ class DecoderConfig:
                 f"held experts {self.experts_start}.."
                 f"{self.experts_start + self.num_experts} of {self.experts_total}"
             )
+        if self.kv_lora_rank is None:
+            if self.rope_parameters and self.rope_parameters.rope_type != "default":
+                raise ValueError(
+                    "rope_parameters of kind yarn are built for latent attention only"
+                )
+            return
+        if not self.q_lora_rank:
+            raise ValueError("latent attention here has the low-rank query path only")
+        if not self.rope_interleave or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention rotates interleaved pairs: rope_interleave "
+                "and an even qk_rope_head_dim"
+            )
+        if not (self.qk_nope_head_dim + self.qk_rope_head_dim == self.head_dim
+                == (self.v_head_dim or self.head_dim)):
+            raise ValueError(
+                "the attention kernels take one head size: qk_nope_head_dim + "
+                "qk_rope_head_dim, v_head_dim and head_dim must be equal"
+            )
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError("latent attention expands a key and a value per query head")
 
     @classmethod
     def from_source(cls, entry, **own):
@@ -132,13 +283,21 @@ class DecoderConfig:
             "attention_bias": (False,), "tie_word_embeddings": (False,),
             "hidden_act": ("silu",), "decoder_sparse_step": (1,),
             "mlp_only_layers": ([],), "rope_scaling": (None,),
-            "use_sliding_window": (False,),
+            "use_sliding_window": (False,), "sliding_window": (None,),
+            "mlp_bias": (False,), "first_k_dense_replace": (0,),
+            "n_group": (1,), "topk_group": (1,),
         }
         for key, allowed in refusals.items():
             if key in entry and entry[key] not in allowed:
                 raise ValueError(
                     f"{key} = {entry[key]!r}: this stack builds only {allowed}"
                 )
+        entry = dict(entry)
+        if "n_routed_experts" in entry:
+            entry.setdefault("num_experts", entry["n_routed_experts"])
+        if entry.get("rope_parameters") is not None:
+            rope = RopeParameters.from_source(entry["rope_parameters"])
+            entry.update(rope_parameters=rope, rope_theta=rope.rope_theta)
         names = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in entry.items() if k in names}, **own)
 
@@ -147,6 +306,13 @@ def _kernel_kind(mask: MaskKind):
     """A model's mask kind as ``ops/flash.py`` names it: ``False`` (none),
     ``True`` (``"causal"``) or the ``BlockDiffusionMask`` itself."""
     return mask if isinstance(mask, flash.BlockDiffusionMask) else mask == "causal"
+
+
+def _attend(q, k, v, mask: MaskKind, scale=None):
+    kind = _kernel_kind(mask)
+    if isinstance(kind, bool):
+        return flash.flash_attention(q, k, v, causal=kind, scale=scale)
+    return flash.flash_attention(q, k, v, mask=kind, scale=scale)
 
 
 def _init(cfg):
@@ -193,6 +359,69 @@ def rotary(x, positions, theta):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
+def rotary_interleaved(x, positions, inv_freq, scale=1.0):
+    """Rotary positions over the pairs ``(2i, 2i+1)`` of the last axis:
+    ``x [b, t, heads, d]``, ``positions [t]``, ``inv_freq [d / 2]``; cos and
+    sin times ``scale``; angles in float32."""
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = (jnp.cos(angles) * scale)[None, :, None, :]
+    sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return rotated.reshape(x.shape).astype(x.dtype)
+
+
+def _dense(cfg, width, name):
+    return nn.Dense(
+        width, use_bias=False, dtype=cfg.compute_dtype,
+        param_dtype=cfg.param_dtype, kernel_init=_init(cfg), name=name,
+    )
+
+
+class LatentAttention(nn.Module):
+    """Causal or unmasked latent attention (the module's header has the
+    equations); the per-head keys and values are expanded for the same
+    flash kernels every other attention here runs."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        b, t, _ = x.shape
+        heads, nope, rope = (
+            cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        )
+        pos = cfg.rope_parameters or RopeParameters(rope_theta=cfg.rope_theta)
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dt, name=name)
+        with jax.named_scope("bf.attn.latent"):
+            c_q = norm("q_a_norm")(_dense(cfg, cfg.q_lora_rank, "q_a_proj")(x))
+            q = _dense(cfg, heads * cfg.head_dim, "q_b_proj")(c_q)
+            q_nope, q_rope = jnp.split(q.reshape(b, t, heads, cfg.head_dim), [nope], axis=-1)
+            c_kv, k_rope = jnp.split(
+                _dense(cfg, cfg.kv_lora_rank + rope, "kv_a_proj")(x),
+                [cfg.kv_lora_rank], axis=-1,
+            )
+            kv = _dense(cfg, heads * (nope + cfg.head_dim), "kv_b_proj")(
+                norm("kv_a_norm")(c_kv)
+            )
+            k_nope, v = jnp.split(kv.reshape(b, t, heads, -1), [nope], axis=-1)
+            freq = pos.inv_freq(rope)
+            turn = lambda y: rotary_interleaved(y, positions, freq, pos.cos_sin_scale)
+            # ONE rotary key a position, shared by all heads
+            k_rope = jnp.broadcast_to(turn(k_rope[:, :, None]), (b, t, heads, rope))
+            q = jnp.concatenate([q_nope, turn(q_rope)], axis=-1)
+            k = jnp.concatenate([k_nope, k_rope], axis=-1)
+            q_scale = pos.query_scale(positions)
+            if q_scale is not None:
+                q = (q * q_scale[None, :, None, None]).astype(dt)
+        att = _attend(q, k, v, mask, pos.softmax_scale(cfg.head_dim))
+        return _dense(cfg, cfg.hidden_size, "o_proj")(
+            att.reshape(b, t, heads * cfg.head_dim)
+        )
+
+
 class Attention(nn.Module):
     cfg: DecoderConfig
 
@@ -202,10 +431,7 @@ class Attention(nn.Module):
         b, t, _ = x.shape
 
         def proj(name, heads):
-            y = nn.Dense(
-                heads * cfg.head_dim, use_bias=False, dtype=dt,
-                param_dtype=cfg.param_dtype, kernel_init=_init(cfg), name=name,
-            )(x)
+            y = _dense(cfg, heads * cfg.head_dim, name)(x)
             return y.reshape(b, t, heads, cfg.head_dim)
 
         q = proj("q_proj", cfg.num_attention_heads)
@@ -216,17 +442,9 @@ class Attention(nn.Module):
             k = RMSNorm(cfg.rms_norm_eps, dt, name="k_norm")(k)
         q = rotary(q, positions, cfg.rope_theta)
         k = rotary(k, positions, cfg.rope_theta)
-        kind = _kernel_kind(mask)
-        if isinstance(kind, bool):
-            att = flash.flash_attention(q, k, v, causal=kind)
-        else:
-            att = flash.flash_attention(q, k, v, mask=kind)
+        att = _attend(q, k, v, mask)
         att = att.reshape(b, t, cfg.num_attention_heads * cfg.head_dim)
-        return nn.Dense(
-            cfg.hidden_size, use_bias=False, dtype=dt,
-            param_dtype=cfg.param_dtype, kernel_init=_init(cfg),
-            name="o_proj",
-        )(att)
+        return _dense(cfg, cfg.hidden_size, "o_proj")(att)
 
 
 class SparseExperts(nn.Module):
@@ -252,6 +470,8 @@ class SparseExperts(nn.Module):
                 rows, w_router, cfg.num_experts_per_tok, cfg.norm_topk_prob,
                 dtype=cfg.router_dtype,
             )
+        if cfg.routed_scaling_factor != 1:
+            weights = weights * cfg.routed_scaling_factor
         # for `apply(..., mutable=["intermediates"])`: who chose what
         self.sow("intermediates", "experts_chosen", experts.reshape(b, t, -1))
         y, counts = moe.expert_layer(
@@ -261,6 +481,23 @@ class SparseExperts(nn.Module):
         return y.reshape(b, t, d), counts
 
 
+class SharedExpert(nn.Module):
+    """The gated feed-forward every position goes through beside its routed
+    choices, ``n_shared_experts`` experts wide as one; whole on every chip
+    (over the shares of a layer it is counted once)."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        width = cfg.n_shared_experts * cfg.moe_intermediate_size
+        gate = _dense(cfg, width, "gate_proj")(u).astype(jnp.float32)
+        up = _dense(cfg, width, "up_proj")(u).astype(jnp.float32)
+        # the activation in float32, as the routed experts' (``ops/moe.py``)
+        return _dense(cfg, cfg.hidden_size, "down_proj")(jax.nn.silu(gate) * up)
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
 
@@ -268,11 +505,16 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, mask):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype, name=name)
+        attention = Attention if cfg.kv_lora_rank is None else LatentAttention
         with jax.named_scope("bf.attn"):
-            x = x + Attention(cfg, name="attn")(
+            x = x + attention(cfg, name="attn")(
                 norm("input_norm")(x), positions, mask
             )
-        y, counts = SparseExperts(cfg, name="experts")(norm("post_attn_norm")(x))
+        u = norm("post_attn_norm")(x)
+        y, counts = SparseExperts(cfg, name="experts")(u)
+        if cfg.n_shared_experts:
+            with jax.named_scope("bf.moe.shared"):
+                y = y + SharedExpert(cfg, name="shared_expert")(u)
         return x + y, counts
 
 
@@ -345,6 +587,30 @@ def _record_static_counts(cfg, batch, positions, mask):
     scale = batch * cfg.num_attention_heads * cfg.num_hidden_layers
     metrics_mod.gauge("bluefog.attn.tiles_live").set(live * scale)
     metrics_mod.gauge("bluefog.attn.tiles_total").set(total * scale)
+    per_layer = batch * positions * cfg.num_hidden_layers
+    if cfg.kv_lora_rank is not None:
+        itemsize = jnp.dtype(cfg.compute_dtype).itemsize
+        metrics_mod.gauge("bluefog.attn.kv_latent_bytes").set(
+            per_layer * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize
+        )
+        metrics_mod.gauge("bluefog.attn.kv_expanded_bytes").set(
+            per_layer * cfg.num_attention_heads * 2 * cfg.head_dim * itemsize
+        )
+    if cfg.n_shared_experts:
+        metrics_mod.gauge("bluefog.moe.shared_rows").set(per_layer)
+
+
+def next_token_loss(model, params, tokens):
+    """The causal training loss of one batch, ``-> (loss, counts)``: the
+    mean over positions ``0 .. seq - 2`` of the cross-entropy of position
+    ``i``'s float32 logits against token ``i + 1``."""
+    h, counts = model.apply({"params": params}, tokens, method="hidden")
+    with jax.named_scope("bf.head"):
+        logits = model.apply({"params": params}, h[:, :-1], method="head")
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=-1)[..., 0]
+        loss = jnp.mean(lse - picked)
+    return loss, counts
 
 
 def block_diffusion_loss(model, params, tokens, draws, levels, *, block,
