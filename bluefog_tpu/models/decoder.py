@@ -69,9 +69,11 @@ row for a call), ``bluefog.moe.row_tile`` (rows a tile of those buffers
 takes) and ``bluefog.moe.buffer_rows`` (the buffers' true rows a call,
 slack tiles included: with :func:`bluefog_tpu.ops.moe.tiles_in_use` of the
 returned ``rows_per_expert``, rows touched against rows held),
-``bluefog.attn.tiles_live`` / ``bluefog.attn.tiles_total`` (tiles a
-forward pass of the attention kernels visits / would visit unmasked, over
-batch, heads and layers); with latent attention
+``bluefog.attn.tiles_live`` / ``bluefog.attn.tiles_total`` (tiles of a
+forward pass of the attention kernels that hold an allowed pair / of the
+whole square, over batch, heads and layers) and ``bluefog.attn.grid_steps``
+(the grid steps that pass takes: ``tiles_live`` where the kernels walk the
+live tiles' list, ``tiles_total`` on the rectangle); with latent attention
 ``bluefog.attn.kv_latent_bytes`` (the compressed stream a call makes:
 positions x (kv_lora_rank + qk_rope_head_dim) x layers x itemsize) and
 ``bluefog.attn.kv_expanded_bytes`` (the per-head keys and values the
@@ -583,10 +585,14 @@ def _record_static_counts(cfg, batch, positions, mask):
     metrics_mod.gauge("bluefog.moe.buffer_rows").set(
         moe.buffer_tiles(pairs, cfg.num_experts, tm) * tm * cfg.num_hidden_layers
     )
-    live, total = flash.tile_counts(positions, _kernel_kind(mask))
+    kind = _kernel_kind(mask)
+    live, total = flash.tile_counts(positions, kind)
     scale = batch * cfg.num_attention_heads * cfg.num_hidden_layers
     metrics_mod.gauge("bluefog.attn.tiles_live").set(live * scale)
     metrics_mod.gauge("bluefog.attn.tiles_total").set(total * scale)
+    metrics_mod.gauge("bluefog.attn.grid_steps").set(
+        flash.grid_steps(positions, kind) * scale
+    )
     per_layer = batch * positions * cfg.num_hidden_layers
     if cfg.kv_lora_rank is not None:
         itemsize = jnp.dtype(cfg.compute_dtype).itemsize

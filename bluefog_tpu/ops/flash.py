@@ -26,9 +26,13 @@ tensor ever exists in HBM): none, ``causal=True``, and
 ``mask=BlockDiffusionMask(seq, block)`` — the training mask of block
 diffusion over a doubled sequence (``seq`` clean positions, then their
 ``seq`` noised copies; causal over blocks of ``block``, bidirectional
-inside one). Under either mask a tile that holds no allowed pair is
-skipped, not masked (``_when_live``): the causal kernel visits half of the
-square, the block-diffusion kernel about a quarter.
+inside one). Under either mask a tile that holds no allowed pair is never
+visited: which tiles are live is known from the shapes, so where some are
+dead the three kernels' grids walk a scalar-prefetched list of the live
+tiles and are as long as that list (``_grid``) — the causal kernel visits
+half of the square, the block-diffusion kernel about a quarter, and a dead
+tile costs neither a grid step nor a K/V fetch. A configuration with no
+dead tile (no mask, or a single tile) keeps the rectangular grid.
 
 Grouped-query heads (K/V with ``h_kv`` heads, ``h % h_kv == 0``) never
 exist expanded: a KV head serves its query group from the index maps, and
@@ -59,6 +63,7 @@ __all__ = [
     "BlockDiffusionMask",
     "block_diffusion_live_tiles",
     "tile_counts",
+    "grid_steps",
     "flash_attention",
     "flash_attention_with_lse",
     "flash_attention_supported",
@@ -103,21 +108,22 @@ class BlockDiffusionMask:
             k_noised & q_noised & (kb == qb)
         )
 
-    def tile_live(self, iq, ik, block_q, block_k, xp=jnp):
-        """Does tile ``(iq, ik)`` hold an allowed pair: scalars traced in a
-        kernel (``xp=jnp``) or integer arrays on the host (``xp=numpy``).
-        A tile may straddle the two halves (``seq`` need be no multiple of
-        the tile), so each of the three quadrants that allow anything is
-        asked by the first and last block its part of the tile touches."""
+    def tile_live(self, iq, ik, block_q, block_k):
+        """Does tile ``(iq, ik)`` hold an allowed pair: integer ``numpy``
+        arrays, on the host (the kernels visit the tiles this names, they
+        never ask). A tile may straddle the two halves (``seq`` need be no
+        multiple of the tile), so each of the three quadrants that allow
+        anything is asked by the first and last block its part of the tile
+        touches."""
         seq, b, total = self.seq, self.block, 2 * self.seq
         q0, k0 = iq * block_q, ik * block_k
-        q1 = xp.minimum(q0 + block_q, total)  # exclusive
-        k1 = xp.minimum(k0 + block_k, total)
-        q_clean_hi = _block_of(xp.minimum(q1, seq) - 1, b)
-        q_noised_lo = _block_of(xp.maximum(q0, seq) - seq, b)
+        q1 = np.minimum(q0 + block_q, total)  # exclusive
+        k1 = np.minimum(k0 + block_k, total)
+        q_clean_hi = _block_of(np.minimum(q1, seq) - 1, b)
+        q_noised_lo = _block_of(np.maximum(q0, seq) - seq, b)
         q_noised_hi = _block_of(q1 - seq - 1, b)
         k_clean_lo = _block_of(k0, b)
-        k_noised_lo = _block_of(xp.maximum(k0, seq) - seq, b)
+        k_noised_lo = _block_of(np.maximum(k0, seq) - seq, b)
         k_noised_hi = _block_of(k1 - seq - 1, b)
         q_clean, q_noised = q0 < seq, q1 > seq
         k_clean, k_noised = k0 < seq, k1 > seq
@@ -137,23 +143,83 @@ def _block_of(pos, block):
     return pos // block
 
 
-def _tile_grid(t, block_q, block_k):
-    """``(iq, ik)`` index arrays over the tiles of a sequence of ``t``
-    padded to the tiles' common multiple, as ``_flash`` pads it."""
+@functools.lru_cache(maxsize=None)
+def _live(kind, t, block_q, block_k):
+    """Which tiles hold an allowed pair under the mask kind ``kind``
+    (``False``, ``True`` for causal, or a ``BlockDiffusionMask``): ``bool
+    [n_q, n_k]`` over the tiles of a sequence of ``t`` padded to the tiles'
+    common multiple, as ``_flash`` pads it."""
     tile = int(np.lcm(block_q, block_k))
     t_pad = -(-t // tile) * tile
-    return np.meshgrid(
+    iq, ik = np.meshgrid(
         np.arange(t_pad // block_q), np.arange(t_pad // block_k),
         indexing="ij",
     )
+    if kind is True:
+        live = ik * block_k < (iq + 1) * block_q  # a key no query is before
+    elif kind:
+        live = kind.tile_live(iq, ik, block_q, block_k)
+    else:
+        live = np.ones_like(iq, bool)
+    live.setflags(write=False)  # one array for every caller
+    return live
+
+
+# what an entry of a grid's tile list is: the first / the last of its major
+# (the accumulator starts / is written out), and a tile to compute at all
+_FIRST, _LAST, _LIVE = 1, 2, 4
+
+
+@functools.lru_cache(maxsize=None)
+def _grid(kind, t, block_q, block_k, by_key=False, group=1):
+    """``(dims, tables)``: a kernel's grid after its leading (batch, head)
+    dimension, under the mask kind ``kind``. Majors are query tiles and
+    minors key tiles, or the other way round ``by_key``; inside one major
+    the minors run once for each of ``group`` members, as ``g * n_minor +
+    minor`` (the dK/dV kernel's walk over the query heads of its group).
+
+    With no dead tile the grid is the rectangle, ``dims = (n_major, group *
+    n_minor)``, and ``tables`` is None. With dead tiles it is as long as the
+    list of the live ones, ``dims = (n,)``, and ``tables`` are that list's
+    ``int32 [n]`` arrays ``(major, minor, flags)``, which the kernel takes
+    as scalar-prefetch operands: the rectangle's order with the dead tiles
+    left out, so every sum is the rectangle's bit for bit. A major with no
+    live tile (a row of padding) gets one entry that is not ``_LIVE``: its
+    output block is still written, as zeros."""
+    live = _live(kind, t, block_q, block_k)
+    if by_key:
+        live = live.T
+    n_major, n_minor = live.shape
+    if live.all():
+        return (n_major, group * n_minor), None
+    majors, minors, flags = [], [], []
+    for major, row in enumerate(live):
+        run = (np.arange(group)[:, None] * n_minor + np.flatnonzero(row)).ravel()
+        run_flags = np.full(max(run.size, 1), _LIVE if run.size else 0)
+        run_flags[0] |= _FIRST
+        run_flags[-1] |= _LAST
+        majors.append(np.full(run_flags.size, major))
+        minors.append(run if run.size else [0])
+        flags.append(run_flags)
+    tables = tuple(
+        np.concatenate(x).astype(np.int32) for x in (majors, minors, flags)
+    )
+    for table in tables:
+        table.setflags(write=False)
+    return (tables[0].size,), tables
 
 
 def block_diffusion_live_tiles(mask, block_q, block_k):
     """The ``(iq, ik)`` of every tile the kernels visit under ``mask`` (an
     ``[n, 2]`` numpy array, row-major), at tiles of ``block_q x block_k``:
-    what ``BlockDiffusionMask.tile_live`` says on the host."""
-    iq, ik = _tile_grid(2 * mask.seq, block_q, block_k)
-    return np.argwhere(mask.tile_live(iq, ik, block_q, block_k, xp=np))
+    what ``BlockDiffusionMask.tile_live`` says."""
+    return np.argwhere(_live(mask, 2 * mask.seq, block_q, block_k))
+
+
+def _blocks(t, block_q, block_k):
+    # the tile sizes of a call that leaves either to `flash_attention`
+    block_q = _auto_block(t) if block_q is None else block_q
+    return block_q, block_q if block_k is None else block_k
 
 
 def tile_counts(t, kind=False, block_q=None, block_k=None):
@@ -161,16 +227,17 @@ def tile_counts(t, kind=False, block_q=None, block_k=None):
     head) at sequence length ``t`` under the mask kind ``kind`` (``False``,
     ``True`` for causal, or a ``BlockDiffusionMask``), and the tiles of the
     padded square, at the tile sizes ``flash_attention`` would choose."""
-    block_q = _auto_block(t) if block_q is None else block_q
-    block_k = block_q if block_k is None else block_k
-    iq, ik = _tile_grid(t, block_q, block_k)
-    if kind is True:
-        live = ik * block_k < (iq + 1) * block_q  # as `_when_live` skips
-    elif kind:
-        live = kind.tile_live(iq, ik, block_q, block_k, xp=np)
-    else:
-        live = np.ones_like(iq, bool)
+    live = _live(kind, t, *_blocks(t, block_q, block_k))
     return int(live.sum()), live.size
+
+
+def grid_steps(t, kind=False, block_q=None, block_k=None):
+    """The grid steps one forward pass takes for one (batch, head), read
+    from the grid the forward kernel is given: ``tile_counts``' ``live``
+    where the grid walks the list of live tiles, its ``total`` where it is
+    the rectangle."""
+    dims, _ = _grid(kind, t, *_blocks(t, block_q, block_k))
+    return int(np.prod(dims))
 
 
 def _positions(iq, ik, block_q, block_k):
@@ -208,27 +275,90 @@ def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
     return keep
 
 
-def _when_live(iq, ik, block_q, block_k, causal, tile):
-    """Run ``tile`` for tile ``(iq, ik)`` unless the mask kind ``causal``
-    allows no pair in it: a dead tile is skipped, not masked."""
-    if causal is True:
-        # skip K tiles that lie entirely in the future of this Q tile
-        pl.when(ik * block_k < (iq + 1) * block_q)(tile)
-    elif causal:
-        pl.when(causal.tile_live(iq, ik, block_q, block_k))(tile)
-    else:
-        tile()
+class _GridStep:
+    """Where one grid step of a kernel stands: its tile ``(major, minor)``
+    and whether that starts or ends its major's run. Read from the program
+    ids on the rectangular grid, from the scalar-prefetched list of the live
+    tiles (``tables``, `_grid`) on a grid as long as that list."""
+
+    def __init__(self, tables=None):
+        if tables is None:
+            self.major, self.minor = pl.program_id(1), pl.program_id(2)
+            self._flags = None
+        else:
+            entry = pl.program_id(1)
+            self.major, self.minor = tables[0][entry], tables[1][entry]
+            self._flags = tables[2][entry]
+
+    def _flag(self, flag):
+        return (self._flags & flag) != 0
+
+    def when_first(self, init):
+        listed = self._flags is not None
+        pl.when(self._flag(_FIRST) if listed else self.minor == 0)(init)
+
+    def when_last(self, finalize):
+        listed = self._flags is not None
+        pl.when(
+            self._flag(_LAST) if listed
+            else self.minor == pl.num_programs(2) - 1
+        )(finalize)
+
+    def when_live(self, tile, causal, iq, ik, block_q, block_k):
+        if self._flags is not None:
+            pl.when(self._flag(_LIVE))(tile)  # all but a dead row's entry
+        elif causal is True:
+            # true of every tile: a rectangle is only walked where none is
+            # dead. Asked still, as ever, because the single-tile causal call
+            # (gpt2-medium) is held to the program it always lowered to, op
+            # for op (tests/test_flash_block_diffusion.py pins its jaxpr)
+            pl.when(ik * block_k < (iq + 1) * block_q)(tile)
+        else:
+            tile()
+
+
+def _tiled_call(kernel, bh, grid, *, name, in_specs, out_specs,
+                scratch_shapes, **kw):
+    """The ``pl.pallas_call`` of ``kernel(step, *refs)`` over ``bh`` (batch,
+    head) slots times ``grid = (dims, tables)`` (`_grid`). Each spec is
+    ``(block_shape, index)`` with ``index`` written over ``(b, major,
+    minor)``, whichever of the two grids carries them."""
+    dims, tables = grid
+    tables = tables or ()  # the rectangle prefetches nothing
+
+    def spec(shape, index):
+        if not tables:
+            return pl.BlockSpec(shape, index)
+        return pl.BlockSpec(
+            shape,
+            lambda b, entry, major, minor, flags: index(
+                b, major[entry], minor[entry]
+            ),
+        )
+
+    call = pl.pallas_call(
+        lambda *refs: kernel(
+            _GridStep(refs[:len(tables)] or None), *refs[len(tables):]
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=(bh, *dims),
+            in_specs=[spec(*s) for s in in_specs],
+            out_specs=tuple(spec(*s) for s in out_specs),
+            scratch_shapes=scratch_shapes,
+        ),
+        name=name, **kw,
+    )
+    return functools.partial(call, *tables)
 
 
 # -- forward -----------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, block_k, kv_len, t_pad):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _fwd_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                l_ref, *, scale, causal, block_q, block_k, kv_len, t_pad):
+    iq, ik = step.major, step.minor
 
-    @pl.when(ik == 0)
+    @step.when_first
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -261,9 +391,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         acc_ref[:] = acc_ref[:] * corr[:, None] + pv
         m_ref[:, 0] = m_new
 
-    _when_live(iq, ik, block_q, block_k, causal, _tile)
+    step.when_live(_tile, causal, iq, ik, block_q, block_k)
 
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @step.when_last
     def _finalize():
         l = l_ref[:, 0]
         m = m_ref[:, 0]
@@ -290,28 +420,22 @@ def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
     group = bh // kf.shape[0]
     out_dtype = qf.dtype if out_dtype is None else out_dtype
     vma = _vma(qf)
-    grid = (bh, t_pad // block_q, t_pad // block_k)
-    kv_spec = pl.BlockSpec(
-        (1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0)
-    )
-    return pl.pallas_call(
+    q_spec = ((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0))
+    kv_spec = ((1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0))
+    return _tiled_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
+        bh, _grid(causal, kv_len, block_q, block_k),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t_pad, d_pad), out_dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, t_pad, _SUB), jnp.float32, vma=vma),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0)),
-            kv_spec,
-            kv_spec,
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=(
-            pl.BlockSpec((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, _SUB), lambda b, iq, ik: (b, iq, 0)),
+            q_spec,
+            ((1, block_q, _SUB), lambda b, iq, ik: (b, iq, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, d_pad), jnp.float32),
@@ -345,18 +469,17 @@ def _recompute_p(q_ref, k_ref, lse_ref, iq, ik, scale, causal, block_q,
     return p
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, block_q, block_k, kv_len, t_pad):
-    ik = pl.program_id(1)
-    # the inner grid dim enumerates (query head of the group, q tile):
-    # with grouped-query attention one KV head accumulates dK/dV over
-    # every query head it serves; iq is the tile index within one head
-    iq2 = pl.program_id(2)
+    # the minor enumerates (query head of the group, q tile): with
+    # grouped-query attention one KV head accumulates dK/dV over every
+    # query head it serves; iq is the tile index within one head
+    ik, iq2 = step.major, step.minor
     n_q = t_pad // block_q
     iq = iq2 % n_q
 
-    @pl.when(iq2 == 0)
+    @step.when_first
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -389,21 +512,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    _when_live(iq, ik, block_q, block_k, causal, _tile)
+    step.when_live(_tile, causal, iq, ik, block_q, block_k)
 
-    @pl.when(iq2 == pl.num_programs(2) - 1)
+    @step.when_last
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dq_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dlse_ref, dq_ref, dq_acc,
                    *, scale, causal, block_q, block_k, kv_len, t_pad):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    iq, ik = step.major, step.minor
 
-    @pl.when(ik == 0)
+    @step.when_first
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -426,9 +548,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    _when_live(iq, ik, block_q, block_k, causal, _tile)
+    step.when_live(_tile, causal, iq, ik, block_q, block_k)
 
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @step.when_last
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -451,33 +573,26 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
     bh_kv = kf.shape[0]
     group = bh // bh_kv
     n_q = t_pad // block_q
-    # dK/dV grid: (kv head, k tile, group member x q tile) — the inner
-    # dim walks every query head served by this KV head, so the group
+    # dK/dV grid: (kv head, then per k tile every group member x q tile) —
+    # the minor walks every query head served by this KV head, so the group
     # reduction happens in the VMEM accumulator with no expanded copy
-    q_gqa = pl.BlockSpec(
-        (1, block_q, d_pad),
-        lambda b, ik, iq2: (b * group + iq2 // n_q, iq2 % n_q, 0),
-    )
-    r_gqa = pl.BlockSpec(
-        (1, block_q, _SUB),
-        lambda b, ik, iq2: (b * group + iq2 // n_q, iq2 % n_q, 0),
-    )
-    k_spec = pl.BlockSpec((1, block_k, d_pad), lambda b, ik, iq2: (b, ik, 0))
-    dk, dv = pl.pallas_call(
+    by_head = lambda b, ik, iq2: (b * group + iq2 // n_q, iq2 % n_q, 0)
+    q_gqa = ((1, block_q, d_pad), by_head)
+    r_gqa = ((1, block_q, _SUB), by_head)
+    k_spec = ((1, block_k, d_pad), lambda b, ik, iq2: (b, ik, 0))
+    dk, dv = _tiled_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
+        bh_kv,
+        _grid(causal, kv_len, block_q, block_k, by_key=True, group=group),
         out_shape=(
             jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), kf.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), vf.dtype, vma=vma),
         ),
-        grid=(bh_kv, t_pad // block_k, group * n_q),
         in_specs=[q_gqa, k_spec, k_spec, q_gqa, r_gqa, r_gqa, r_gqa],
-        out_specs=(
-            pl.BlockSpec((1, block_k, d_pad), lambda b, ik, iq2: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, ik, iq2: (b, ik, 0)),
-        ),
+        out_specs=(k_spec, k_spec),
         scratch_shapes=[
             pltpu.VMEM((block_k, d_pad), jnp.float32),
             pltpu.VMEM((block_k, d_pad), jnp.float32),
@@ -485,23 +600,21 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
         interpret=interpret,
         name="bf_flash_dkv",
     )(qf, kf, vf, do, lse, delta, dlse_w)
-    q_spec2 = pl.BlockSpec((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0))
-    k_spec2 = pl.BlockSpec(
-        (1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0)
-    )
-    r_spec2 = pl.BlockSpec((1, block_q, _SUB), lambda b, iq, ik: (b, iq, 0))
-    dq = pl.pallas_call(
+    q_spec2 = ((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0))
+    k_spec2 = ((1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0))
+    r_spec2 = ((1, block_q, _SUB), lambda b, iq, ik: (b, iq, 0))
+    (dq,) = _tiled_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), qf.dtype, vma=vma),
-        grid=(bh, t_pad // block_q, t_pad // block_k),
+        bh, _grid(causal, kv_len, block_q, block_k),
+        out_shape=(
+            jax.ShapeDtypeStruct((bh, t_pad, d_pad), qf.dtype, vma=vma),
+        ),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2,
                   r_spec2],
-        out_specs=pl.BlockSpec(
-            (1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0)
-        ),
+        out_specs=(q_spec2,),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
         name="bf_flash_dq",
@@ -586,10 +699,7 @@ def _flash_with_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     """Padded/folded kernel invocation returning ``(out, lse)`` in the
     caller's layout: out ``[b, t, h, d]``, lse ``[b, h, t]`` (f32)."""
     b, t, h, d = q.shape
-    if block_q is None:
-        block_q = _auto_block(t)
-    if block_k is None:
-        block_k = block_q
+    block_q, block_k = _blocks(t, block_q, block_k)
     tile = int(np.lcm(block_q, block_k))
     t_pad = -(-t // tile) * tile
     qp, kp, vp = (_pad_to(x, t_pad, d) for x in (q, k, v))
@@ -716,10 +826,7 @@ def _auto_block(t: int) -> int:
 )
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     b, t, h, d = q.shape
-    if block_q is None:
-        block_q = _auto_block(t)
-    if block_k is None:
-        block_k = block_q
+    block_q, block_k = _blocks(t, block_q, block_k)
     # ragged tails tile via zero padding: padded K positions are masked to
     # -inf in-kernel (zero softmax weight), padded Q rows are discarded.
     # Cost: one O(T*d) copy, not O(T^2). head_dim needs no padding — the

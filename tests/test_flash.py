@@ -230,6 +230,41 @@ def test_with_lse_matches_dense_including_lse_gradient(causal):
         )
 
 
+@pytest.mark.parametrize("t, h, h_kv", [(512, 2, 2), (512, 4, 1), (450, 2, 1)])
+def test_with_lse_over_the_live_tiles_of_a_causal_square(t, h, h_kv):
+    """Four tiles a side under ``causal=True``: the three kernels walk the
+    list of the ten live ones (``flash._grid``), and ``dlse`` rides the index
+    maps ``lse`` and ``delta`` ride. Both outputs and the joint gradient of
+    a loss that uses both against dense autodiff."""
+    from bluefog_tpu.ops import flash
+
+    assert flash.tile_counts(t, True, 128, 128) == (10, 16)
+    assert flash.grid_steps(t, True, 128, 128) == 10
+    rng = np.random.RandomState(12)
+    d = 64
+    q = jnp.asarray(rng.randn(1, t, h, d), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(1, t, h_kv, d), jnp.float32) for _ in range(2))
+    kernel = lambda q, k, v: flash.flash_attention_with_lse(
+        q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
+    dense = lambda q, k, v: flash._dense_with_lse(q, k, v, True, 1.0 / np.sqrt(d))
+    for got, want in zip(kernel(q, k, v), dense(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    weight = jnp.asarray(rng.randn(1, h, t), jnp.float32)  # a dlse that is not zero
+
+    def loss_of(fn):
+        def loss(q, k, v):
+            o, l = fn(q, k, v)
+            return (o ** 2).sum() + (l * weight).sum()
+        return loss
+
+    gf = jax.grad(loss_of(kernel), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_of(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=1e-4, err_msg=f"d{name}")
+
+
 def test_merge_blocks_reassembles_full_attention():
     """The online-softmax merge rule: attending two key blocks separately
     and merging (out, lse) pairs equals attending the concatenation."""

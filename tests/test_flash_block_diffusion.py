@@ -2,9 +2,12 @@
 ``interpret=True`` against a dense mask laid out from the definition
 (forward and the three gradients, grouped heads, an L that is no multiple
 of the tile), the live-tile list against the tiles that hold an allowed
-pair, the refusals, and the causal call of ``gpt2-medium`` unchanged."""
+pair, the grids as long as that list (in the tables, in the jaxpr, and
+every output block written), the refusals, and the causal call of
+``gpt2-medium`` unchanged."""
 
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -39,10 +42,10 @@ def plain_attention(q, k, v, allowed):
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def qkv(seq, seed=0):
+def qkv(seq, seed=0, h=H, hkv=HKV):
     rng = np.random.RandomState(seed)
     mk = lambda h: jnp.asarray(rng.randn(B, 2 * seq, h, D), jnp.float32)
-    return mk(H), mk(HKV), mk(HKV)
+    return mk(h), mk(hkv), mk(hkv)
 
 
 @pytest.mark.parametrize("seq, block", [(8, 4), (100, 4), (96, 8), (30, 3)])
@@ -59,12 +62,19 @@ def test_the_mask_allows_what_the_definition_allows(seq, block):
 # L = 100 is no multiple of any tile, so tiles straddle the two halves and
 # the tail is padded; 4 query heads on 2 key-value heads
 CASES = [(100, 4, 64, 64), (100, 4, 32, 64), (100, 4, 64, 32), (96, 8, 128, 128)]
+# 2 L = 400 padded to the tiles' common multiple, 768: the last two rows of
+# query tiles, or the last two columns of key tiles, hold padding only
+DEAD_ROWS, DEAD_COLUMNS = (200, 8, 128, 384), (200, 8, 384, 128)
+# 8 query heads on one key-value head, as the cell's 32 on 4
+HEADS = [c + (H, HKV) for c in CASES + [DEAD_ROWS, DEAD_COLUMNS]] + [
+    (100, 4, 32, 64, 8, 1), (128, 4, 64, 64, 8, 1),
+]
 
 
-@pytest.mark.parametrize("seq, block, block_q, block_k", CASES)
+@pytest.mark.parametrize("seq, block, block_q, block_k, h, hkv", HEADS)
 @pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
-def test_kernel_matches_the_dense_mask(seq, block, block_q, block_k, what):
-    q, k, v = qkv(seq)
+def test_kernel_matches_the_dense_mask(seq, block, block_q, block_k, h, hkv, what):
+    q, k, v = qkv(seq, h=h, hkv=hkv)
     mask, allowed = BlockDiffusionMask(seq, block), dense_mask(seq, block)
     kernel = lambda q, k, v: flash_attention(
         q, k, v, mask=mask, block_q=block_q, block_k=block_k, interpret=True
@@ -110,6 +120,130 @@ def test_about_a_quarter_of_the_cells_tiles_are_live():
     assert flash.tile_counts(1024, True) == (1, 1)
     assert flash.tile_counts(4096, True, 1024, 1024) == (10, 16)
     assert flash.tile_counts(4096, False, 1024, 1024) == (16, 16)
+
+
+def folded(t, t_pad, heads, seed):
+    """``[2 * heads, t_pad, D]``, zero past ``t``: what ``_flash`` hands the kernels."""
+    x = np.random.RandomState(seed).randn(2 * heads, t, D)
+    return jnp.asarray(np.pad(x, ((0, 0), (0, t_pad - t), (0, 0))), jnp.float32)
+
+
+@pytest.mark.parametrize("kind, t, block_q, block_k, h, hkv", [
+    (BlockDiffusionMask(*DEAD_ROWS[:2]), 400, *DEAD_ROWS[2:], 4, 2),
+    (BlockDiffusionMask(*DEAD_COLUMNS[:2]), 400, *DEAD_COLUMNS[2:], 4, 2),
+    (BlockDiffusionMask(100, 4), 200, 32, 64, 8, 1),
+    (True, 450, 128, 64, 4, 2),
+])
+def test_every_output_block_is_written(kind, t, block_q, block_k, h, hkv):
+    """Before the slice: the interpreter starts an output as NaN (the chip as
+    whatever was there), so a block no grid step wrote shows. A row of query
+    tiles with no live tile comes out as zeros and ``-inf``, a column of key
+    tiles with none as zero gradients."""
+    tile = int(np.lcm(block_q, block_k))
+    t_pad = -(-t // tile) * tile
+    assert flash._grid(kind, t, block_q, block_k)[1] is not None
+    q, k, v = folded(t, t_pad, h, 0), folded(t, t_pad, hkv, 1), folded(t, t_pad, hkv, 2)
+    do = folded(t, t_pad, h, 3)
+    dlse = jnp.broadcast_to(folded(t, t_pad, h, 4)[:, :, :1], (2 * h, t_pad, 8))
+    (out, lse), vjp = jax.vjp(
+        flash._flash_lse_fn(kind, 0.2, block_q, block_k, t, True), q, k, v
+    )
+    dq, dk, dv = vjp((do, dlse))
+    for name, x in (("out", out), ("dq", dq), ("dk", dk), ("dv", dv)):
+        assert np.isfinite(np.asarray(x)).all(), name
+    assert not np.isnan(np.asarray(lse)).any()
+    live = flash._live(kind, t, block_q, block_k)
+    for iq in np.flatnonzero(~live.any(axis=1)):
+        rows = slice(iq * block_q, (iq + 1) * block_q)
+        assert not np.asarray(out[:, rows]).any() and not np.asarray(dq[:, rows]).any()
+        assert (np.asarray(lse[:, rows]) == -np.inf).all()
+    for ik in np.flatnonzero(~live.any(axis=0)):
+        rows = slice(ik * block_k, (ik + 1) * block_k)
+        assert not np.asarray(dk[:, rows]).any() and not np.asarray(dv[:, rows]).any()
+
+
+@pytest.mark.parametrize("by_key, group", [(False, 1), (True, 1), (True, 8)])
+@pytest.mark.parametrize("kind, t, block_q, block_k", [
+    (BlockDiffusionMask(4096, 4), 8192, 1024, 1024),
+    (True, 4096, 1024, 1024),
+    (BlockDiffusionMask(*DEAD_ROWS[:2]), 400, *DEAD_ROWS[2:]),
+    (BlockDiffusionMask(*DEAD_COLUMNS[:2]), 400, *DEAD_COLUMNS[2:]),
+    (BlockDiffusionMask(100, 4), 200, 32, 64),
+    (True, 450, 128, 64),
+])
+def test_the_tile_list_is_the_rectangle_with_the_dead_tiles_left_out(
+    kind, t, block_q, block_k, by_key, group
+):
+    live = flash._live(kind, t, block_q, block_k)
+    live = live.T if by_key else live
+    n_major, n_minor = live.shape
+    (n,), (major, minor, flags) = flash._grid(kind, t, block_q, block_k, by_key, group)
+    assert major.dtype == minor.dtype == flags.dtype == np.int32
+    assert n == len(major) == len(minor) == len(flags)
+    is_live = flags & flash._LIVE != 0
+    # the rectangle's walk, major by major, a group member after another
+    walk = [
+        (i, g * n_minor + j)
+        for i in range(n_major) for g in range(group) for j in range(n_minor)
+        if live[i, j]
+    ]
+    assert list(zip(major[is_live], minor[is_live])) == walk
+    assert is_live.sum() == group * live.sum()
+    # a major with no live tile has one entry all the same; every major's
+    # entries stand together, the first and the last flagged and no other
+    assert sorted(major[~is_live]) == list(np.flatnonzero(~live.any(axis=1)))
+    assert list(major) == sorted(major) and set(major) == set(range(n_major))
+    starts = np.r_[True, major[1:] != major[:-1]]
+    ends = np.r_[major[1:] != major[:-1], True]
+    assert ((flags & flash._FIRST != 0) == starts).all()
+    assert ((flags & flash._LAST != 0) == ends).all()
+
+
+@pytest.mark.parametrize("kind, t, n", [
+    (False, 4096, 4), (True, 1024, 1), (BlockDiffusionMask(512, 4), 1024, 1),
+])
+def test_a_configuration_with_no_dead_tile_keeps_the_rectangle(kind, t, n):
+    assert flash._grid(kind, t, 1024, 1024) == ((n, n), None)
+    assert flash._grid(kind, t, 1024, 1024, by_key=True, group=8) == ((n, 8 * n), None)
+    assert flash.grid_steps(t, kind) == flash.tile_counts(t, kind)[1] == n * n
+
+
+# the benchmark's two decoder cells: 2 x 8 192 positions under the block
+# diffusion mask with 32 query heads on 4 (sdar30b_1chip_b2), 1 x 4 096
+# causal with 32 on 32 (mistral4_1chip_b1); heads of 128, tiles of 1 024
+CELLS = {
+    "sdar30b_1chip_b2": (BlockDiffusionMask(4096, 4), 2, 8192, 32, 4, 24, 64),
+    "mistral4_1chip_b1": (True, 1, 4096, 32, 32, 10, 16),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_three_grids_are_as_long_as_the_live_tiles(cell):
+    kind, b, t, h, hkv, live, total = CELLS[cell]
+    assert flash.tile_counts(t, kind) == (live, total)
+    assert flash.grid_steps(t, kind) == live
+    q = jax.ShapeDtypeStruct((b, t, h, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, t, hkv, 128), jnp.bfloat16)
+    kw = {"causal": True} if kind is True else {"mask": kind}
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, **kw).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
+    calls = text.split("pallas_call[")[1:]
+    grids = {
+        re.search(r"name=(bf_flash_\w+)", call).group(1):
+        tuple(int(n) for n in re.search(r"grid=\(([\d, ]+)\)", call).group(1).split(","))
+        for call in calls
+    }
+    assert grids == {
+        "bf_flash_fwd": (b * h, live),
+        "bf_flash_dkv": (b * hkv, h // hkv * live),
+        "bf_flash_dq": (b * h, live),
+    }
+    # and the lists ride in beside the tensors, int32 and as long as the grid
+    for call in calls:
+        assert f"i32[{live}]" in call or f"i32[{h // hkv * live}]" in call
 
 
 def test_the_dense_path_lays_the_same_mask_out():

@@ -368,6 +368,7 @@ def test_scopes_and_gauges_of_one_traced_loss():
     assert peek("bluefog.moe.rows_offered") == positions * 2 * layers
     live, total = peek("bluefog.attn.tiles_live"), peek("bluefog.attn.tiles_total")
     assert 0 < live <= total and total == BATCH * 4 * layers  # one tile a head
+    assert peek("bluefog.attn.grid_steps") == total  # so the grid is the rectangle
 
 
 def test_the_readers_parts_tell_the_latent_path_from_the_rest_of_attention():

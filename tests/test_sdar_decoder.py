@@ -162,6 +162,28 @@ def test_the_cells_counts_are_the_issues():
     assert abs(per_token - 3.16e9) < 1e7
 
 
+@pytest.mark.parametrize("name, masked, steps, live, total", [
+    ("sdar30b_1chip_b2", True, 9216, 9216, 24576),   # 24 of 64 tiles x 64 x 6
+    ("mistral4_1chip_b1", True, 1280, 1280, 2048),   # 10 of 16 tiles x 32 x 4
+    ("sdar30b_1chip_b2", False, 24576, 24576, 24576),
+])
+def test_the_attention_gauges_of_the_benchmarks_decoder_cells(name, masked, steps, live, total):
+    """``bluefog.attn.grid_steps`` is read from the grid the forward kernel
+    is given: the live tiles under either cell's mask kind, the whole
+    rectangle for an unmasked call."""
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.ops.flash import BlockDiffusionMask
+
+    job = bench.load_job(cells.load_cell(name))
+    if name.startswith("sdar"):
+        positions, mask = 2 * job.seq, BlockDiffusionMask(job.seq, job.block)
+    else:
+        positions, mask = job.seq, "causal"
+    decoder._record_static_counts(job.cfg, job.batch, positions, mask if masked else None)
+    peek = lambda gauge: metrics.peek(f"bluefog.attn.{gauge}").value
+    assert (peek("grid_steps"), peek("tiles_live"), peek("tiles_total")) == (steps, live, total)
+
+
 @pytest.mark.parametrize("change", [
     {"attention_bias": True}, {"tie_word_embeddings": True}, {"hidden_act": "gelu"},
     {"decoder_sparse_step": 2}, {"mlp_only_layers": [0]}, {"use_sliding_window": True},
@@ -203,6 +225,7 @@ def test_scopes_and_gauges_of_one_traced_loss():
     assert peek("bluefog.moe.buffer_rows") == -(-tiles // 8) * 8 * 8 * layers
     live, total = peek("bluefog.attn.tiles_live"), peek("bluefog.attn.tiles_total")
     assert 0 < live <= total and total == BATCH * 4 * layers  # one tile a head
+    assert peek("bluefog.attn.grid_steps") == total  # so the grid is the rectangle
 
 
 def test_the_causal_mask_kind_sees_no_later_token():
