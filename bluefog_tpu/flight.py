@@ -36,10 +36,17 @@ What gets recorded (event ``kind`` -> payload):
 - ``session_start`` / ``session_end`` — clock handshake (unix ns,
   monotonic us, timeline us) + mesh shape + process index; the
   cross-rank alignment anchor ``tools/trace_merge.py`` uses.
+  ``session_start`` also says how the process got here: ``import_s``
+  (the package's own import), ``jax_preloaded`` and ``process_age_s``.
 - ``plan_compile`` — every CommPlan the compiler lowers (topology
   version, round count, live token); full round/edge structure is
   retained in a bounded side table for the postmortem.
 - ``compile`` — XLA program (re)builds, by cache-key family.
+- ``build`` — what jax itself reports while a program is being built:
+  one event at the end of each trace, lowering and compile-or-load
+  (``phase``, jax's ``fun``, ``dur_us``; a ``backend`` event also says
+  what the persistent cache answered). :func:`build_phases` reads them
+  back; the outer ones are kept in a bounded side table as well.
 - ``step_begin`` / ``step_dispatched`` — optimizer step boundaries with
   the communicating flag; the merge tool turns these into per-rank step
   spans and computes per-step critical paths over the plan's rounds.
@@ -93,6 +100,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from jax import profiler as _profiler
 
+from bluefog_tpu import metrics as metrics_mod
 from bluefog_tpu import timeline as tl
 from bluefog_tpu import watchdog
 from bluefog_tpu.logging_util import logger
@@ -104,6 +112,8 @@ __all__ = [
     "events",
     "StepPhases",
     "step_phases",
+    "build_phases",
+    "note_import",
     "note_plan",
     "note_fault",
     "note_advisory",
@@ -188,6 +198,12 @@ _slo: List[dict] = []  # bounded side table of SLO budget snapshots
 # error-budget state that preceded it — "we died while paging on a
 # burned budget" vs "we died green" is the first postmortem question
 # — so the sampled snapshots survive ring eviction like the rest
+_builds: List[dict] = []  # bounded side table of the OUTER ``build``
+# events, as the ring holds them: the first and the newest _BUILDS_KEPT
+# of the session. The ring forgets a job's start after ~1 400 steps, and
+# the postmortem of a recompile storm wants both ends: what the first
+# programs cost, and what is being built now
+_builds_dropped = 0  # outer builds that fell out of the middle
 _plans_lock = threading.Lock()
 _hooks_installed = False
 _prev_excepthook = None
@@ -235,7 +251,7 @@ def reconfigure() -> None:
     """Re-read the env knobs and start a fresh ring (one flight per
     session: ``bf.init()`` calls this so a dump never mixes events from
     a torn-down mesh with the new one)."""
-    global _enabled_cache, _recorder
+    global _enabled_cache, _recorder, _builds_dropped
     _enabled_cache = None
     _recorder = None
     with _plans_lock:
@@ -244,6 +260,8 @@ def reconfigure() -> None:
         _advisories.clear()
         _decisions.clear()
         _slo.clear()
+        del _builds[:]
+        _builds_dropped = 0
     del _dump_history[:]
 
 
@@ -362,6 +380,188 @@ def step_phases(t0_us: Optional[int] = None, t1_us: Optional[int] = None,
         else:
             stamps = []
     return out
+
+
+# -- what jax says while a program is built -----------------------------------
+
+# jax's own duration events (jax 0.9: ``dispatch.log_elapsed_time``), each
+# with the built function's ``fun_name``: the trace to a jaxpr (one for
+# every inner ``jit`` the outer trace meets, inside the outer's, and one for
+# every index map and helper a Pallas kernel's lowering traces, inside the
+# lowering's), the lowering to an MLIR module, and the backend's compile or
+# its load from the persistent cache.
+BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_BUILD_COUNTERS = {
+    phase: f"bluefog.build.{phase}_s" for phase in BUILD_PHASES.values()
+}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BUILDS_KEPT = 32  # of each end of a session, in the side table
+# A child shorter than this is counted on its outer event (``inner``,
+# ``inner_us``) and not written: a model's step meets an inner ``jit`` for
+# every jax.numpy call of every distinct shape (12 463 phases a run of
+# ``gpt2m_1chip_b1``, 3 159 of them inside the step's trace), most of some
+# tens of microseconds, and the ring is there for the last 1 400 steps, not
+# for them. The ones that say where a trace went are long.
+_CHILD_MIN_US = 1000
+_listening = False
+
+
+class _BuildThread(threading.local):
+    """One thread's open build phases and what the persistent cache has
+    said since its last ``backend`` event. jax says when a phase begins
+    (a scalar event of the same name) as well as when it ends, so whether
+    an ending phase is an outer one is known on arrival. ``inner`` counts
+    the children of the open outer event, at any depth, and ``inner_us``
+    adds up its direct children's durations."""
+
+    def __init__(self):
+        self.depth = self.inner = self.inner_us = 0
+        self.asked = self.hit = False
+        self.retrieval_us = None
+
+
+_build_thread = _BuildThread()
+
+# The three callbacks run inside jax's tracing and compiling machinery: they
+# return at once while the recorder is off, call nothing of jax's, and make
+# no more than the event they write.
+
+
+def _on_jax_scalar(event, value=None, **kwargs):
+    if not enabled():
+        return
+    if event in BUILD_PHASES:  # the phase begins
+        _build_thread.depth += 1
+
+
+def _on_jax_event(event, **kwargs):
+    if not enabled():
+        return
+    if event == _CACHE_ASKED:
+        _build_thread.asked = True
+    elif event == _CACHE_HIT:
+        _build_thread.hit = True
+
+
+def _on_jax_duration(event, duration_secs=0.0, **kwargs):
+    if not enabled():
+        return
+    try:
+        _record_build(event, duration_secs, kwargs.get("fun_name"))
+    except Exception:  # whatever jax passes, its build goes on
+        logger.debug("flight: a build report was dropped", exc_info=True)
+
+
+def _record_build(event, duration_secs, fun):
+    global _builds_dropped
+    mine = _build_thread
+    phase = BUILD_PHASES.get(event)
+    if phase is None:
+        if event == _CACHE_RETRIEVAL:
+            mine.retrieval_us = int(duration_secs * 1e6)
+        return
+    dur_us = int(duration_secs * 1e6)
+    data = {"phase": phase, "fun": fun, "dur_us": dur_us}
+    if phase == "backend":
+        # asked and not found is a miss, whether or not the compile was
+        # long enough to be written (jax's own ``cache_misses`` counts the
+        # entries written)
+        data["cache"] = "hit" if mine.hit else "miss" if mine.asked else None
+        if mine.hit:
+            data["retrieval_us"] = mine.retrieval_us
+            metrics_mod.counter("bluefog.build.cache_hits").inc()
+        elif mine.asked:
+            metrics_mod.counter("bluefog.build.cache_misses").inc()
+        mine.asked = mine.hit = False
+        mine.retrieval_us = None
+    depth = mine.depth = max(0, mine.depth - 1)
+    if depth:  # a child: its time is inside its outer event's
+        mine.inner += 1
+        if depth == 1:
+            mine.inner_us += dur_us
+        if dur_us >= _CHILD_MIN_US:
+            _rec().record("build", data)
+        return
+    if mine.inner:
+        data["inner"], data["inner_us"] = mine.inner, mine.inner_us
+        mine.inner = mine.inner_us = 0
+    rec = _rec()
+    seq = rec.record("build", data)
+    metrics_mod.counter(_BUILD_COUNTERS[phase]).inc(duration_secs)
+    _builds.append({
+        "seq": seq, "t_us": rec._buf[seq % rec.capacity][1],
+        "kind": "build", "data": data,
+    })
+    if len(_builds) > 2 * _BUILDS_KEPT:
+        del _builds[_BUILDS_KEPT]  # the oldest of the newest
+        _builds_dropped += 1
+
+
+def _listen_to_jax() -> None:
+    """Register the three callbacks, once a process: jax keeps a listener
+    for the life of the process and ``bf.init()`` may run again."""
+    global _listening
+    if _listening:
+        return
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_jax_scalar)
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _listening = True
+
+
+def build_phases(t0_us: Optional[int] = None, t1_us: Optional[int] = None,
+                 evs: Optional[List[dict]] = None) -> List[dict]:
+    """The ``build`` events in the ring (or in ``evs``: ring events, or a
+    dump's ``builds``) that lie whole inside ``[t0_us, t1_us]`` on the
+    ring's clock, oldest first: one ``{"seq", "t_us", "start_us", "phase",
+    "fun", "dur_us", "outer"}`` per event; a ``backend`` one with its
+    ``cache`` (and ``retrieval_us``) too, one that had children with their
+    number ``inner`` and its direct children's total ``inner_us`` (so
+    ``dur_us - inner_us`` was spent in none of them). An event is written
+    when its phase ends, so ``t_us`` is the end and ``start_us = t_us -
+    dur_us`` the start (jax times the phase on the wall clock: only the
+    duration is taken from it).
+
+    jax reports a trace for every inner ``jit`` the outer trace meets,
+    inside the outer's duration, and the traces a lowering makes (a Pallas
+    kernel's index maps) inside the lowering's. An event whose interval
+    lies inside another ``build`` event's, of whatever phase, is that one's
+    child and has ``outer`` false: a sum over the outer events counts no
+    second twice, and the children say where the outer event spent its
+    time (the ring holds those of a millisecond or more,
+    ``_CHILD_MIN_US``; the shorter ones are in ``inner`` / ``inner_us``
+    alone). Nesting is read before the interval is cut, so a child stays a
+    child when its outer event ends past ``t1_us``."""
+    out = []
+    for e in (events() if evs is None else evs):
+        if e["kind"] == "build":
+            d = e.get("data", {})
+            out.append({
+                "seq": e["seq"], "t_us": e["t_us"],
+                "start_us": e["t_us"] - d.get("dur_us", 0), **d, "outer": True,
+            })
+    # earliest start first and of those the one that ended last (a parent is
+    # written after its children): an event lies inside an earlier one of
+    # this order iff it ends no later than the latest end seen
+    latest_end = None
+    for r in sorted(out, key=lambda r: (r["start_us"], -r["t_us"], -r["seq"])):
+        if latest_end is not None and r["t_us"] <= latest_end:
+            r["outer"] = False
+        else:
+            latest_end = r["t_us"]
+    return [
+        r for r in out
+        if (t0_us is None or r["start_us"] >= t0_us)
+        and (t1_us is None or r["t_us"] <= t1_us)
+    ]
 
 
 def note_plan(plan, topo_version: int, live_token=None,
@@ -496,7 +696,6 @@ def _owned_ranks(ctx) -> List[int]:
 
 def _build_dump(reason: str) -> dict:
     from bluefog_tpu import context as ctx_mod
-    from bluefog_tpu import metrics as metrics_mod
 
     out: Dict[str, Any] = {
         "version": DUMP_VERSION,
@@ -544,6 +743,8 @@ def _build_dump(reason: str) -> dict:
         out["advisories"] = list(_advisories)
         out["autotune_decisions"] = list(_decisions)
         out["slo_snapshots"] = list(_slo)
+        out["builds"] = list(_builds)
+        out["builds_dropped"] = _builds_dropped
     try:
         out["metrics"] = metrics_mod.snapshot()
     except Exception:
@@ -667,6 +868,36 @@ def _uninstall_crash_hooks() -> None:
 
 # -- session lifecycle (called by bluefog_tpu.context) ------------------------
 
+# what ``bluefog_tpu/__init__.py`` measured of its own import: the seconds
+# from its first statement to its last import, with what that pulls in, and
+# whether ``jax`` was in ``sys.modules`` before (then its import is not in
+# ``import_s``, as under a caller that imports jax first)
+_import: Dict[str, Any] = {"import_s": None, "jax_preloaded": None}
+
+
+def note_import(import_s: float, jax_preloaded: bool) -> None:
+    """Called once, by the package's ``__init__``, after its last import."""
+    _import.update(import_s=import_s, jax_preloaded=jax_preloaded)
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since this process was started, or None where that cannot
+    be read (Linux only: ``starttime``, field 22 of ``/proc/self/stat``,
+    in clock ticks since boot, against ``CLOCK_BOOTTIME``). Interpreter
+    start, every import so far, the backend's start and the caller's own
+    work before ``bf.init()`` are all inside it."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command (field 2) may hold spaces: count from its ")"
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (
+            time.clock_gettime(time.CLOCK_BOOTTIME)
+            - started / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if age >= 0 else None
+
 
 def on_init(ctx) -> None:
     """Open the black box for a fresh session: new ring, clock
@@ -675,6 +906,7 @@ def on_init(ctx) -> None:
     reconfigure()
     if not enabled():
         return
+    _listen_to_jax()
     record(
         "session_start",
         **_clock_triple(),
@@ -682,6 +914,9 @@ def on_init(ctx) -> None:
         size=ctx.size,
         machine_size=ctx.machine_size,
         pid=os.getpid(),
+        # the way here rides the event: the ring is new at every init
+        **_import,
+        process_age_s=_process_age_s(),
     )
     watchdog.add_stall_handler(_on_stall)  # idempotent (same fn object)
     if dump_dir() is not None:

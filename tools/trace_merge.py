@@ -167,7 +167,7 @@ def _steps_of(dump: dict) -> List[dict]:
 
 _INSTANT_KINDS = {
     "fault", "membership", "repair", "stall", "crash", "sigterm",
-    "window_op", "compile",
+    "window_op", "compile", "build",
 }
 
 
@@ -239,6 +239,8 @@ def merge_trace(dumps: List[dict], traces: Dict[int, list]) -> dict:
                 label = f"repair epoch={data.get('epoch')}"
             elif kind == "stall":
                 label = f"stall:{data.get('name')}"
+            elif kind == "build":  # written at the phase's end
+                label = f"build:{data.get('phase')} {data.get('fun')}"
             pid = (
                 data["rank"] if kind in ("fault", "membership")
                 and "rank" in data else host_pid
