@@ -73,7 +73,10 @@ returned ``rows_per_expert``, rows touched against rows held),
 forward pass of the attention kernels that hold an allowed pair / of the
 whole square, over batch, heads and layers) and ``bluefog.attn.grid_steps``
 (the grid steps that pass takes: ``tiles_live`` where the kernels walk the
-live tiles' list, ``tiles_total`` on the rectangle); with latent attention
+live tiles' list, ``tiles_total`` on the rectangle),
+``bluefog.attn.subtiles_live`` / ``bluefog.attn.subtiles_masked`` (the
+sub-tiles that pass computes / of them those that build a mask: a whole
+tile counts all its sub-tiles live and none masked); with latent attention
 ``bluefog.attn.kv_latent_bytes`` (the compressed stream a call makes:
 positions x (kv_lora_rank + qk_rope_head_dim) x layers x itemsize) and
 ``bluefog.attn.kv_expanded_bytes`` (the per-head keys and values the
@@ -570,6 +573,20 @@ class DecoderLM(nn.Module):
         return self.head(h), counts
 
 
+def record_attention_counts(positions, kind, slots):
+    """The attention kernels' host gauges for a call at ``positions`` under
+    the mask kind ``kind`` (``ops.flash``'s), summed over ``slots`` (batch,
+    head, layer) slots: written while tracing, the step does not change."""
+    live, total = flash.tile_counts(positions, kind)
+    sub_live, sub_masked = flash.subtile_counts(positions, kind)
+    for name, n in (
+        ("tiles_live", live), ("tiles_total", total),
+        ("grid_steps", flash.grid_steps(positions, kind)),
+        ("subtiles_live", sub_live), ("subtiles_masked", sub_masked),
+    ):
+        metrics_mod.gauge(f"bluefog.attn.{name}").set(n * slots)
+
+
 def _record_static_counts(cfg, batch, positions, mask):
     """What one call offers its expert layers and its attention kernels,
     known from the shapes: host gauges, written while tracing."""
@@ -585,13 +602,9 @@ def _record_static_counts(cfg, batch, positions, mask):
     metrics_mod.gauge("bluefog.moe.buffer_rows").set(
         moe.buffer_tiles(pairs, cfg.num_experts, tm) * tm * cfg.num_hidden_layers
     )
-    kind = _kernel_kind(mask)
-    live, total = flash.tile_counts(positions, kind)
-    scale = batch * cfg.num_attention_heads * cfg.num_hidden_layers
-    metrics_mod.gauge("bluefog.attn.tiles_live").set(live * scale)
-    metrics_mod.gauge("bluefog.attn.tiles_total").set(total * scale)
-    metrics_mod.gauge("bluefog.attn.grid_steps").set(
-        flash.grid_steps(positions, kind) * scale
+    record_attention_counts(
+        positions, _kernel_kind(mask),
+        batch * cfg.num_attention_heads * cfg.num_hidden_layers,
     )
     per_layer = batch * positions * cfg.num_hidden_layers
     if cfg.kv_lora_rank is not None:

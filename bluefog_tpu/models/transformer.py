@@ -15,6 +15,7 @@ from typing import Any, Callable, Optional
 import jax.numpy as jnp
 import flax.linen as nn
 
+from bluefog_tpu.models.decoder import record_attention_counts
 from bluefog_tpu.ops.attention import reference_attention  # noqa: F401 (re-export)
 from bluefog_tpu.ops.flash import flash_attention
 
@@ -76,9 +77,12 @@ class TransformerLM(nn.Module):
         # bwd; measured 2.6-14.6x fwd / 3.2-5.2x fwd+bwd over the dense XLA path at T>=4096 — see
         # docs/performance.md), dense XLA elsewhere (flash_attention falls
         # back by itself)
-        attend = self.attend or (
-            lambda q, k, v: flash_attention(q, k, v, causal=True)
-        )
+        attend = self.attend
+        if attend is None:
+            attend = lambda q, k, v: flash_attention(q, k, v, causal=True)
+            record_attention_counts(
+                tokens.shape[1], True, tokens.shape[0] * self.heads * self.layers
+            )
         x = nn.Embed(self.vocab, self.dim, dtype=self.dtype)(tokens)
         pos_table = self.param(
             "pos", nn.initializers.normal(0.02), (self.max_len, self.dim)

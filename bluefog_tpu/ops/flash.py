@@ -4,10 +4,9 @@
 The sequence-parallel layers (:mod:`bluefog_tpu.ops.attention`) delegate
 their per-device block attention to XLA by default; this module provides
 the hand-tiled TPU kernels for the same math — flash-attention online
-softmax with one pass over K/V tiles, f32 accumulators in VMEM, causal
-tiles skipped entirely (not just masked) so the causal kernel does half
-the work. Layout follows the MXU/VPU tiling rules: Q/K/V tiles are
-``[block, head_dim]`` with sequence blocks multiples of 128 lanes / 8
+softmax with one pass over K/V tiles, f32 accumulators in VMEM, and only
+the allowed part of the square computed (below). Layout follows the
+MXU/VPU tiling rules: Q/K/V tiles are ``[block, head_dim]`` with sequence blocks multiples of 128 lanes / 8
 sublanes (``pallas_guide``: tiling constraints). Ragged sequence lengths
 tile via zero padding + in-kernel masking along the SEQUENCE axis only
 (an O(T·d) copy), never an O(T²) dense fallback; ``head_dim`` is
@@ -26,13 +25,19 @@ tensor ever exists in HBM): none, ``causal=True``, and
 ``mask=BlockDiffusionMask(seq, block)`` — the training mask of block
 diffusion over a doubled sequence (``seq`` clean positions, then their
 ``seq`` noised copies; causal over blocks of ``block``, bidirectional
-inside one). Under either mask a tile that holds no allowed pair is never
-visited: which tiles are live is known from the shapes, so where some are
-dead the three kernels' grids walk a scalar-prefetched list of the live
-tiles and are as long as that list (``_grid``) — the causal kernel visits
-half of the square, the block-diffusion kernel about a quarter, and a dead
-tile costs neither a grid step nor a K/V fetch. A configuration with no
-dead tile (no mask, or a single tile) keeps the rectangular grid.
+inside one). What each part of the square holds is known from the shapes
+(``_classes``, on the host): a tile, and a sub-tile inside it, is dead (no
+allowed pair), whole (every pair allowed and every key real) or partial.
+Where any tile is not whole the three kernels' grids walk a
+scalar-prefetched list of the live tiles and are as long as that list
+(``_grid``): a dead tile costs neither a grid step nor a K/V fetch. A whole
+tile runs a mask-free body — no iotas, no mask, no select. A partial tile
+walks its live sub-tiles (512² in a tile of 1 024, ``_sub_tile``) in a
+rolled loop over a scalar-prefetched run of them, slicing Q, K, V, dO and
+the accumulators' rows, and only its partial sub-tiles build a mask: the
+causal diagonal computes three sub-tiles of four, block diffusion's
+noised-noised diagonal two. A configuration whose every tile is whole (no
+mask at a length the tiles divide) keeps the rectangular grid.
 
 Grouped-query heads (K/V with ``h_kv`` heads, ``h % h_kv == 0``) never
 exist expanded: a KV head serves its query group from the index maps, and
@@ -64,6 +69,7 @@ __all__ = [
     "block_diffusion_live_tiles",
     "tile_counts",
     "grid_steps",
+    "subtile_counts",
     "flash_attention",
     "flash_attention_with_lse",
     "flash_attention_supported",
@@ -108,32 +114,32 @@ class BlockDiffusionMask:
             k_noised & q_noised & (kb == qb)
         )
 
-    def tile_live(self, iq, ik, block_q, block_k):
-        """Does tile ``(iq, ik)`` hold an allowed pair: integer ``numpy``
-        arrays, on the host (the kernels visit the tiles this names, they
-        never ask). A tile may straddle the two halves (``seq`` need be no
-        multiple of the tile), so each of the three quadrants that allow
-        anything is asked by the first and last block its part of the tile
-        touches."""
-        seq, b, total = self.seq, self.block, 2 * self.seq
-        q0, k0 = iq * block_q, ik * block_k
-        q1 = np.minimum(q0 + block_q, total)  # exclusive
-        k1 = np.minimum(k0 + block_k, total)
-        q_clean_hi = _block_of(np.minimum(q1, seq) - 1, b)
-        q_noised_lo = _block_of(np.maximum(q0, seq) - seq, b)
-        q_noised_hi = _block_of(q1 - seq - 1, b)
-        k_clean_lo = _block_of(k0, b)
-        k_noised_lo = _block_of(np.maximum(k0, seq) - seq, b)
-        k_noised_hi = _block_of(k1 - seq - 1, b)
+    def span(self, q0, q1, k0, k1):
+        """``(some, every)``: is some / every pair of the rectangle ``[q0,
+        q1) x [k0, k1)`` allowed (non-empty; integer ``numpy`` arrays, on
+        the host: the kernels compute what this names, they never ask). A
+        rectangle may straddle the two halves (``seq`` need be no multiple
+        of a tile), so each of the four quadrants is asked by the first and
+        last block its part of the rectangle touches; a position past ``2 *
+        seq`` counts as noised, as ``allowed`` has it."""
+        seq, blk = self.seq, lambda pos: _block_of(pos, self.block)
         q_clean, q_noised = q0 < seq, q1 > seq
         k_clean, k_noised = k0 < seq, k1 > seq
-        live = (
-            (q_clean & k_clean & (k_clean_lo <= q_clean_hi))
-            | (q_noised & k_clean & (k_clean_lo < q_noised_hi))
-            | (q_noised & k_noised & (k_noised_lo <= q_noised_hi)
-               & (q_noised_lo <= k_noised_hi))
+        qc_lo, qc_hi = blk(q0), blk(np.minimum(q1, seq) - 1)
+        qn_lo, qn_hi = blk(np.maximum(q0, seq) - seq), blk(q1 - seq - 1)
+        kc_lo, kc_hi = blk(k0), blk(np.minimum(k1, seq) - 1)
+        kn_lo, kn_hi = blk(np.maximum(k0, seq) - seq), blk(k1 - seq - 1)
+        cc, nc, nn_ = q_clean & k_clean, q_noised & k_clean, q_noised & k_noised
+        some = (
+            (cc & (kc_lo <= qc_hi)) | (nc & (kc_lo < qn_hi))
+            | (nn_ & (kn_lo <= qn_hi) & (qn_lo <= kn_hi))
         )
-        return live & (q0 < total) & (k0 < total)
+        every = (
+            (~cc | (kc_hi <= qc_lo)) & (~nc | (kc_hi < qn_lo))
+            & ~(q_clean & k_noised)
+            & (~nn_ | ((qn_lo == qn_hi) & (kn_lo == kn_hi) & (qn_lo == kn_lo)))
+        )
+        return some, every
 
 
 def _block_of(pos, block):
@@ -143,66 +149,147 @@ def _block_of(pos, block):
     return pos // block
 
 
-@functools.lru_cache(maxsize=None)
-def _live(kind, t, block_q, block_k):
-    """Which tiles hold an allowed pair under the mask kind ``kind``
-    (``False``, ``True`` for causal, or a ``BlockDiffusionMask``): ``bool
-    [n_q, n_k]`` over the tiles of a sequence of ``t`` padded to the tiles'
-    common multiple, as ``_flash`` pads it."""
+def _span(kind, q0, q1, k0, k1):
+    """``(some, every)`` pair of each rectangle ``[q0, q1) x [k0, k1)``
+    allowed under the mask kind ``kind`` (``False``, ``True`` for causal,
+    or a ``BlockDiffusionMask``)."""
+    if kind is True:
+        return q1 - 1 >= k0, q0 >= k1 - 1
+    if kind:
+        return kind.span(q0, q1, k0, k1)
+    return np.ones(q0.shape, bool), np.ones(q0.shape, bool)
+
+
+def _padded(t, block_q, block_k):
+    # the length `_flash` pads a sequence of `t` to: the tiles' common multiple
     tile = int(np.lcm(block_q, block_k))
-    t_pad = -(-t // tile) * tile
-    iq, ik = np.meshgrid(
-        np.arange(t_pad // block_q), np.arange(t_pad // block_k),
+    return -(-t // tile) * tile
+
+
+# the class of a rectangle of the square (a tile, or a sub-tile of one):
+# no allowed pair; some; every pair allowed and every key a real one
+_DEAD, _PARTIAL, _WHOLE = 0, 1, 2
+
+
+@functools.lru_cache(maxsize=None)
+def _classes(kind, t, size_q, size_k, t_pad):
+    """``int8 [t_pad // size_q, t_pad // size_k]``: the class of each
+    ``size_q x size_k`` rectangle of the square of ``t_pad`` positions, of
+    which the first ``t`` are real, under the mask kind ``kind``. A class
+    is judged over the real queries: a padding query's output is sliced
+    away and its cotangents are zero, so it may take a whole rectangle's
+    mask-free body as well as be left out of a dead one (it then meets only
+    finite scores: it sees every key of that rectangle in the forward pass
+    too). Exact: the kernels mask what is ``_PARTIAL`` and nothing else."""
+    q0, k0 = np.meshgrid(
+        np.arange(0, t_pad, size_q), np.arange(0, t_pad, size_k),
         indexing="ij",
     )
-    if kind is True:
-        live = ik * block_k < (iq + 1) * block_q  # a key no query is before
-    elif kind:
-        live = kind.tile_live(iq, ik, block_q, block_k)
-    else:
-        live = np.ones_like(iq, bool)
-    live.setflags(write=False)  # one array for every caller
-    return live
+    q1, k1 = np.minimum(q0 + size_q, t), k0 + size_k  # real queries only
+    some, _ = _span(kind, q0, q1, k0, np.minimum(k1, t))
+    _, every = _span(kind, q0, q1, k0, k1)
+    live = some & (q0 < t) & (k0 < t)
+    out = np.where(live, np.where(every & (k1 <= t), _WHOLE, _PARTIAL), _DEAD)
+    out = out.astype(np.int8)
+    out.setflags(write=False)  # one array for every caller
+    return out
+
+
+def _live(kind, t, block_q, block_k):
+    """Which tiles hold an allowed pair under the mask kind ``kind``:
+    ``bool [n_q, n_k]`` over the tiles of a sequence of ``t`` padded to the
+    tiles' common multiple, as ``_flash`` pads it."""
+    return _classes(kind, t, block_q, block_k, _padded(t, block_q, block_k)) != _DEAD
+
+
+def _sub_shape(block_q, block_k, sub):
+    # a partial tile's sub-tiles; the whole tile is its one sub-tile where
+    # the kernels walk none
+    return (block_q, block_k) if sub is None else (sub, sub)
+
+
+def _sub_classes(kind, t, block_q, block_k, sub):
+    """``int8 [n_q, n_k, n_sq, n_sk]``: each tile's sub-tiles' classes."""
+    sub_q, sub_k = _sub_shape(block_q, block_k, sub)
+    t_pad = _padded(t, block_q, block_k)
+    n_sq, n_sk = block_q // sub_q, block_k // sub_k
+    cls = _classes(kind, t, sub_q, sub_k, t_pad)
+    return cls.reshape(t_pad // block_q, n_sq, t_pad // block_k, n_sk).transpose(0, 2, 1, 3)
 
 
 # what an entry of a grid's tile list is: the first / the last of its major
-# (the accumulator starts / is written out), and a tile to compute at all
-_FIRST, _LAST, _LIVE = 1, 2, 4
+# (the accumulator starts / is written out), a tile to compute at all, and
+# a tile whose every pair is allowed (the mask-free body; a live tile
+# without it walks its live sub-tiles)
+_FIRST, _LAST, _LIVE, _FULL = 1, 2, 4, 8
 
 
 @functools.lru_cache(maxsize=None)
-def _grid(kind, t, block_q, block_k, by_key=False, group=1):
+def _grid(kind, t, block_q, block_k, sub=None, by_key=False, group=1):
     """``(dims, tables)``: a kernel's grid after its leading (batch, head)
-    dimension, under the mask kind ``kind``. Majors are query tiles and
-    minors key tiles, or the other way round ``by_key``; inside one major
-    the minors run once for each of ``group`` members, as ``g * n_minor +
+    dimension, under the mask kind ``kind``, with partial tiles walked in
+    sub-tiles of ``sub`` (None: whole). Majors are query tiles and minors
+    key tiles, or the other way round ``by_key``; inside one major the
+    minors run once for each of ``group`` members, as ``g * n_minor +
     minor`` (the dK/dV kernel's walk over the query heads of its group).
 
-    With no dead tile the grid is the rectangle, ``dims = (n_major, group *
-    n_minor)``, and ``tables`` is None. With dead tiles it is as long as the
-    list of the live ones, ``dims = (n,)``, and ``tables`` are that list's
-    ``int32 [n]`` arrays ``(major, minor, flags)``, which the kernel takes
-    as scalar-prefetch operands: the rectangle's order with the dead tiles
-    left out, so every sum is the rectangle's bit for bit. A major with no
-    live tile (a row of padding) gets one entry that is not ``_LIVE``: its
-    output block is still written, as zeros."""
-    live = _live(kind, t, block_q, block_k)
+    Where every tile is whole the grid is the rectangle, ``dims = (n_major,
+    group * n_minor)``, and ``tables`` is None. Else it is as long as the
+    list of the live tiles, ``dims = (n,)``, and ``tables`` are ``int32``
+    arrays the kernel takes as scalar-prefetch operands: ``(major, minor,
+    flags, walk)``, each ``[n]``, and ``subs``. The list is the rectangle's
+    order with the dead tiles left out, so every sum is the rectangle's. A
+    major with no live tile (a row of padding) gets one entry that is not
+    ``_LIVE``: its output block is still written, as zeros. A partial
+    entry's ``walk`` is where its run starts in ``subs``: the run's length,
+    then one code a live sub-tile, ``q_off << 16 | k_off << 1 | whole`` with
+    the sub-tile's offsets in the tile, query-major; runs are shared by the
+    tiles that have the same, and ``subs[0]`` is the empty run."""
+    tiles = _classes(kind, t, block_q, block_k, _padded(t, block_q, block_k))
     if by_key:
-        live = live.T
-    n_major, n_minor = live.shape
-    if live.all():
+        tiles = tiles.T
+    n_major, n_minor = tiles.shape
+    if (tiles == _WHOLE).all():
         return (n_major, group * n_minor), None
-    majors, minors, flags = [], [], []
-    for major, row in enumerate(live):
-        run = (np.arange(group)[:, None] * n_minor + np.flatnonzero(row)).ravel()
-        run_flags = np.full(max(run.size, 1), _LIVE if run.size else 0)
+    sub_cls = _sub_classes(kind, t, block_q, block_k, sub)
+    sub_q, sub_k = _sub_shape(block_q, block_k, sub)
+    subs, runs = [0], {}
+
+    def walk_of(iq, ik):
+        live = np.argwhere(sub_cls[iq, ik] != _DEAD)
+        whole = sub_cls[iq, ik][tuple(live.T)] == _WHOLE
+        codes = tuple(
+            (live[:, 0] * sub_q << 16 | live[:, 1] * sub_k << 1 | whole).tolist()
+        )
+        if codes not in runs:
+            runs[codes] = len(subs)
+            subs.extend((len(codes),) + codes)
+        return runs[codes]
+
+    majors, minors, flags, walks = [], [], [], []
+    for major, row in enumerate(tiles):
+        live = np.flatnonzero(row != _DEAD)
+        if not live.size:
+            majors.append(major)
+            minors.append(0)
+            flags.append(_FIRST | _LAST)
+            walks.append(0)
+            continue
+        run_flags = np.where(row[live] == _WHOLE, _LIVE | _FULL, _LIVE)
+        run_walks = [
+            0 if row[j] == _WHOLE
+            else walk_of(*((j, major) if by_key else (major, j)))
+            for j in live
+        ]
+        run_flags = np.tile(run_flags, group)
         run_flags[0] |= _FIRST
         run_flags[-1] |= _LAST
-        majors.append(np.full(run_flags.size, major))
-        minors.append(run if run.size else [0])
-        flags.append(run_flags)
+        majors.extend([major] * run_flags.size)
+        minors.extend((np.arange(group)[:, None] * n_minor + live).ravel())
+        flags.extend(run_flags)
+        walks.extend(run_walks * group)
     tables = tuple(
-        np.concatenate(x).astype(np.int32) for x in (majors, minors, flags)
+        np.asarray(x, np.int32) for x in (majors, minors, flags, walks, subs)
     )
     for table in tables:
         table.setflags(write=False)
@@ -212,7 +299,7 @@ def _grid(kind, t, block_q, block_k, by_key=False, group=1):
 def block_diffusion_live_tiles(mask, block_q, block_k):
     """The ``(iq, ik)`` of every tile the kernels visit under ``mask`` (an
     ``[n, 2]`` numpy array, row-major), at tiles of ``block_q x block_k``:
-    what ``BlockDiffusionMask.tile_live`` says."""
+    the tiles ``BlockDiffusionMask.span`` finds some allowed pair in."""
     return np.argwhere(_live(mask, 2 * mask.seq, block_q, block_k))
 
 
@@ -240,20 +327,27 @@ def grid_steps(t, kind=False, block_q=None, block_k=None):
     return int(np.prod(dims))
 
 
-def _positions(iq, ik, block_q, block_k):
-    qpos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    kpos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    return qpos, kpos
+def subtile_counts(t, kind=False, block_q=None, block_k=None):
+    """``(live, masked)``: the sub-tiles one forward pass computes for one
+    (batch, head) at sequence length ``t`` under the mask kind ``kind``, and
+    those of them that build a mask, at the tile and sub-tile sizes
+    ``flash_attention`` would choose. A whole tile counts all its sub-tiles
+    as live and none as masked; where the kernels walk no sub-tiles
+    (``_sub_tile`` is None) a tile is its one sub-tile."""
+    block_q, block_k = _blocks(t, block_q, block_k)
+    sub = _sub_tile(block_q, block_k)
+    tiles = _classes(kind, t, block_q, block_k, _padded(t, block_q, block_k))
+    sub_q, sub_k = _sub_shape(block_q, block_k, sub)
+    in_partial = _sub_classes(kind, t, block_q, block_k, sub)[tiles == _PARTIAL]
+    whole = (tiles == _WHOLE).sum() * (block_q // sub_q) * (block_k // sub_k)
+    live = whole + (in_partial != _DEAD).sum()
+    return int(live), int((in_partial == _PARTIAL).sum())
 
 
-def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
-    """Static-shape validity mask for one score tile, or None when every
-    entry is valid (divisible, non-causal shapes compile mask-free).
-    ``causal`` is the mask kind: ``False``, ``True`` or a
+def _keep_mask(q0, k0, size_q, size_k, causal, kv_len, t_pad):
+    """The validity mask of the ``size_q x size_k`` scores whose first
+    query and key sit at ``q0`` and ``k0``, or None where every entry is
+    valid. ``causal`` is the mask kind: ``False``, ``True`` or a
     ``BlockDiffusionMask``.
 
     Raggedness is judged against the PADDED length, not ``block_k``
@@ -263,7 +357,8 @@ def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
     ragged = kv_len < t_pad
     if not (causal or ragged):
         return None
-    qpos, kpos = _positions(iq, ik, block_q, block_k)
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (size_q, size_k), 0)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (size_q, size_k), 1)
     keep = None
     if causal is True:
         keep = qpos >= kpos
@@ -276,19 +371,22 @@ def _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad):
 
 
 class _GridStep:
-    """Where one grid step of a kernel stands: its tile ``(major, minor)``
-    and whether that starts or ends its major's run. Read from the program
-    ids on the rectangular grid, from the scalar-prefetched list of the live
-    tiles (``tables``, `_grid`) on a grid as long as that list."""
+    """Where one grid step of a kernel stands: its tile ``(major, minor)``,
+    whether that starts or ends its major's run, and what in it to compute.
+    Read from the program ids on the rectangular grid, from the
+    scalar-prefetched list of the live tiles (``tables``, `_grid`) on a grid
+    as long as that list."""
 
-    def __init__(self, tables=None):
+    def __init__(self, tables=None, bodies=None):
         if tables is None:
             self.major, self.minor = pl.program_id(1), pl.program_id(2)
             self._flags = None
         else:
+            major, minor, flags, walk, self._subs = tables
             entry = pl.program_id(1)
-            self.major, self.minor = tables[0][entry], tables[1][entry]
-            self._flags = tables[2][entry]
+            self.major, self.minor = major[entry], minor[entry]
+            self._flags, self._walk = flags[entry], walk[entry]
+            self._bodies = bodies
 
     def _flag(self, flag):
         return (self._flags & flag) != 0
@@ -304,17 +402,57 @@ class _GridStep:
             else self.minor == pl.num_programs(2) - 1
         )(finalize)
 
-    def when_live(self, tile, causal, iq, ik, block_q, block_k):
-        if self._flags is not None:
-            pl.when(self._flag(_LIVE))(tile)  # all but a dead row's entry
-        elif causal is True:
-            # true of every tile: a rectangle is only walked where none is
-            # dead. Asked still, as ever, because the single-tile causal call
-            # (gpt2-medium) is held to the program it always lowered to, op
-            # for op (tests/test_flash_block_diffusion.py pins its jaxpr)
-            pl.when(ik * block_k < (iq + 1) * block_q)(tile)
-        else:
-            tile()
+    def compute(self, update, block_q, block_k, sub):
+        """Call ``update(q_off, k_off, size_q, size_k, masked)`` over what
+        this step computes, the offsets within its tile: a whole tile once,
+        unmasked; a partial tile once for each of its live sub-tiles, in a
+        rolled loop over its run in ``subs``, masked where the sub-tile is
+        partial; a dead row's entry nothing."""
+        if self._flags is None:  # the rectangle: every tile is whole
+            update(0, 0, block_q, block_k, False)
+            return
+        # only the bodies some entry takes are built (`_bodies`)
+        whole_tiles, partial_tiles, whole_subs = self._bodies
+        if whole_tiles:
+            pl.when(self._flag(_FULL))(
+                lambda: update(0, 0, block_q, block_k, False)
+            )
+        if not partial_tiles:
+            return
+        partial = (self._flags & (_LIVE | _FULL)) == _LIVE
+        if sub is None:  # a partial tile is its one sub-tile
+            pl.when(partial)(lambda: update(0, 0, block_q, block_k, True))
+            return
+        start, subs = self._walk, self._subs
+
+        def sub_tile(i, carry):
+            code = subs[start + 1 + i]
+            q_off = pl.multiple_of(code >> 16, sub)
+            k_off = pl.multiple_of((code >> 1) & 0x7FFF, sub)
+            if not whole_subs:
+                update(q_off, k_off, sub, sub, True)
+                return carry
+            whole = (code & 1) != 0
+            pl.when(whole)(lambda: update(q_off, k_off, sub, sub, False))
+            pl.when(~whole)(lambda: update(q_off, k_off, sub, sub, True))
+            return carry
+
+        @pl.when(partial)
+        def _walk():
+            jax.lax.fori_loop(0, subs[start], sub_tile, 0)
+
+
+def _bodies(tables):
+    """``(whole_tiles, partial_tiles, whole_subs)``: which bodies some entry
+    of a listed grid takes, read on the host from its tables — a whole
+    tile's, a partial tile's walk, and in a walk a whole sub-tile's."""
+    _, _, flags, walk, subs = tables
+    kind = flags & (_LIVE | _FULL)
+    runs = {int(w) for w in walk[kind == _LIVE]}
+    return (
+        bool((kind == _LIVE | _FULL).any()), bool(runs),
+        any((subs[w + 1:w + 1 + subs[w]] & 1).any() for w in runs),
+    )
 
 
 def _tiled_call(kernel, bh, grid, *, name, in_specs, out_specs,
@@ -324,6 +462,7 @@ def _tiled_call(kernel, bh, grid, *, name, in_specs, out_specs,
     ``(block_shape, index)`` with ``index`` written over ``(b, major,
     minor)``, whichever of the two grids carries them."""
     dims, tables = grid
+    bodies = tables and _bodies(tables)
     tables = tables or ()  # the rectangle prefetches nothing
 
     def spec(shape, index):
@@ -331,14 +470,15 @@ def _tiled_call(kernel, bh, grid, *, name, in_specs, out_specs,
             return pl.BlockSpec(shape, index)
         return pl.BlockSpec(
             shape,
-            lambda b, entry, major, minor, flags: index(
+            lambda b, entry, major, minor, *_: index(
                 b, major[entry], minor[entry]
             ),
         )
 
     call = pl.pallas_call(
         lambda *refs: kernel(
-            _GridStep(refs[:len(tables)] or None), *refs[len(tables):]
+            _GridStep(refs[:len(tables)] or None, bodies),
+            *refs[len(tables):]
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables), grid=(bh, *dims),
@@ -354,9 +494,20 @@ def _tiled_call(kernel, bh, grid, *, name, in_specs, out_specs,
 # -- forward -----------------------------------------------------------------
 
 
+def _lanes(x, n):
+    """``x``, ``[rows, 128]`` with every lane alike, ``n`` lanes wide: the
+    row statistics stay two-dimensional, and the vector units never lay a
+    row vector out again (what a ``[:, 0]`` read and a ``[:, None]``
+    broadcast cost at every sub-tile)."""
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return x[:, :n] if n < _LANES else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                l_ref, *, scale, causal, block_q, block_k, kv_len, t_pad):
+                l_ref, *, scale, causal, block_q, block_k, kv_len, t_pad, sub):
     iq, ik = step.major, step.minor
+    d = acc_ref.shape[-1]
 
     @step.when_first
     def _init():
@@ -364,44 +515,46 @@ def _fwd_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _tile():
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
+    def update(q_off, k_off, size_q, size_k, masked):
+        rows, cols = pl.ds(q_off, size_q), pl.ds(k_off, size_k)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0, rows], k_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_k]
-        keep = _keep_mask(iq, ik, block_q, block_k, causal, kv_len,
-                          t_pad)
+        ) * scale  # [size_q, size_k]
+        keep = _keep_mask(
+            iq * block_q + q_off, ik * block_k + k_off, size_q, size_k,
+            causal, kv_len, t_pad,
+        ) if masked else None
+        m_prev = m_ref[rows]  # [size_q, 128], every lane alike
         if keep is not None:
             s = jnp.where(keep, s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(
-            jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        # unmasked, every score and every row's maximum is finite; masked, a
+        # row that has met no allowed key yet subtracts 0: its -inf scores
+        # still give 0, and so does its correction
+        m_safe = m_new if keep is None else jnp.where(
+            jnp.isfinite(m_new), m_new, 0.0
         )
-        l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(-1)
+        p = jnp.exp(s - _lanes(m_safe, size_k))
+        corr = jnp.exp(m_prev - m_safe)
+        l_ref[rows] = l_ref[rows] * corr + p.sum(-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[:] = acc_ref[:] * corr[:, None] + pv
-        m_ref[:, 0] = m_new
+        acc_ref[rows] = acc_ref[rows] * _lanes(corr, d) + pv
+        m_ref[rows] = m_new
 
-    step.when_live(_tile, causal, iq, ik, block_q, block_k)
+    step.compute(update, block_q, block_k, sub)
 
     @step.when_last
     def _finalize():
-        l = l_ref[:, 0]
-        m = m_ref[:, 0]
+        l = l_ref[:]
         l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / _lanes(l_safe, d)).astype(o_ref.dtype)
         # logsumexp per row; -inf marks rows with no valid key (padding)
-        lse = jnp.where(l > 0, m + jnp.log(l_safe), _NEG_INF)
-        lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref[0].shape)
+        lse = jnp.where(l > 0, m_ref[:] + jnp.log(l_safe), _NEG_INF)
+        lse_ref[0] = lse[:, :_SUB]
 
 
 def _vma(x):
@@ -420,14 +573,15 @@ def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
     group = bh // kf.shape[0]
     out_dtype = qf.dtype if out_dtype is None else out_dtype
     vma = _vma(qf)
+    sub = _sub_tile(block_q, block_k)
     q_spec = ((1, block_q, d_pad), lambda b, iq, ik: (b, iq, 0))
     kv_spec = ((1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0))
     return _tiled_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
+            _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, kv_len=kv_len, t_pad=t_pad, sub=sub,
         ),
-        bh, _grid(causal, kv_len, block_q, block_k),
+        bh, _grid(causal, kv_len, block_q, block_k, sub),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t_pad, d_pad), out_dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, t_pad, _SUB), jnp.float32, vma=vma),
@@ -450,28 +604,29 @@ def _fwd_call(qf, kf, vf, causal, scale, block_q, block_k, kv_len,
 # -- backward (FlashAttention-2 style) ---------------------------------------
 
 
-def _recompute_p(q_ref, k_ref, lse_ref, iq, ik, scale, causal, block_q,
-                 block_k, kv_len, t_pad):
-    """Rebuild the probability tile from Q/K and the saved logsumexp."""
+def _recompute_p(q, k, lse, q0, k0, scale, causal, kv_len, t_pad, masked):
+    """Rebuild the probability tile from Q/K and the saved logsumexp: ``q``
+    and ``lse`` rows from query ``q0`` on, ``k`` rows from key ``k0`` on."""
     s = jax.lax.dot_general(
-        q_ref, k_ref, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     ) * scale
-    keep = _keep_mask(iq, ik, block_q, block_k, causal, kv_len, t_pad)
-    if keep is not None:
-        s = jnp.where(keep, s, _NEG_INF)
-    lse = lse_ref[:, 0]  # [block_q] (stored _SUB wide)
-    finite = jnp.isfinite(lse)
-    p = jnp.exp(s - jnp.where(finite, lse, 0.0)[:, None])
-    # rows with lse=-inf are padding (no valid keys); -inf scores are
-    # masked slots
-    p = jnp.where(finite[:, None] & jnp.isfinite(s), p, 0.0)
-    return p
+    keep = _keep_mask(
+        q0, k0, q.shape[0], k.shape[0], causal, kv_len, t_pad
+    ) if masked else None
+    lse = lse[:, :1]  # [size_q, 1] (stored _SUB wide)
+    if keep is None:
+        # every score is finite, and so is every row's lse: the forward
+        # pass saw these same scores unmasked
+        return jnp.exp(s - lse)
+    # a masked score gives 0; a row with lse=-inf (padding, no valid key)
+    # has every score masked here, as it had in the forward pass
+    s = jnp.where(keep, s, _NEG_INF)
+    return jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0))
 
 
 def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, block_q, block_k, kv_len, t_pad):
+                    dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
+                    causal, block_q, block_k, kv_len, t_pad, sub):
     # the minor enumerates (query head of the group, q tile): with
     # grouped-query attention one KV head accumulates dK/dV over every
     # query head it serves; iq is the tile index within one head
@@ -484,35 +639,37 @@ def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _tile():
+    def update(q_off, k_off, size_q, size_k, masked):
+        rows, cols = pl.ds(q_off, size_q), pl.ds(k_off, size_k)
+        q = q_ref[0, rows]
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0], iq, ik, scale, causal,
-            block_q, block_k, kv_len, t_pad,
-        )  # [block_q, block_k]
-        do = do_ref[0]  # [block_q, d]
+            q, k_ref[0, cols], lse_ref[0, rows], iq * block_q + q_off,
+            ik * block_k + k_off, scale, causal, kv_len, t_pad, masked,
+        )  # [size_q, size_k]
+        do = do_ref[0, rows]  # [size_q, d]
         # dV += P^T dO
-        dv_acc[:] += jax.lax.dot_general(
+        dv_acc[cols] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         # dP = dO V^T ; dS = P * (dP - D) * scale
         dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+            do, v_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         # dlse: upstream cotangent on the logsumexp output (zero for
         # plain flash_attention; nonzero when lse feeds a cross-block
         # merge, e.g. ring attention) — dL/ds_ij picks up dlse_i * p_ij
         ds = p * (
-            dp - delta_ref[0][:, 0][:, None] + dlse_ref[0][:, 0][:, None]
+            dp - delta_ref[0, rows][:, :1] + dlse_ref[0, rows][:, :1]
         ) * scale
         # dK += dS^T Q
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        dk_acc[cols] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    step.when_live(_tile, causal, iq, ik, block_q, block_k)
+    step.compute(update, block_q, block_k, sub)
 
     @step.when_last
     def _finalize():
@@ -521,34 +678,36 @@ def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(step, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dlse_ref, dq_ref, dq_acc,
-                   *, scale, causal, block_q, block_k, kv_len, t_pad):
+                   dlse_ref, dq_ref, dq_acc, *, scale, causal, block_q,
+                   block_k, kv_len, t_pad, sub):
     iq, ik = step.major, step.minor
 
     @step.when_first
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def _tile():
+    def update(q_off, k_off, size_q, size_k, masked):
+        rows, cols = pl.ds(q_off, size_q), pl.ds(k_off, size_k)
+        k = k_ref[0, cols]
         p = _recompute_p(
-            q_ref[0], k_ref[0], lse_ref[0], iq, ik, scale, causal,
-            block_q, block_k, kv_len, t_pad,
+            q_ref[0, rows], k, lse_ref[0, rows], iq * block_q + q_off,
+            ik * block_k + k_off, scale, causal, kv_len, t_pad, masked,
         )
-        do = do_ref[0]
+        do = do_ref[0, rows]
         dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+            do, v_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         ds = p * (
-            dp - delta_ref[0][:, 0][:, None] + dlse_ref[0][:, 0][:, None]
+            dp - delta_ref[0, rows][:, :1] + dlse_ref[0, rows][:, :1]
         ) * scale
         # dQ += dS K
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        dq_acc[rows] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    step.when_live(_tile, causal, iq, ik, block_q, block_k)
+    step.compute(update, block_q, block_k, sub)
 
     @step.when_last
     def _finalize():
@@ -580,13 +739,14 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
     q_gqa = ((1, block_q, d_pad), by_head)
     r_gqa = ((1, block_q, _SUB), by_head)
     k_spec = ((1, block_k, d_pad), lambda b, ik, iq2: (b, ik, 0))
+    sub = _sub_tile(block_q, block_k)
+    kernel = lambda body: functools.partial(
+        body, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        kv_len=kv_len, t_pad=t_pad, sub=sub,
+    )
     dk, dv = _tiled_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
-        ),
-        bh_kv,
-        _grid(causal, kv_len, block_q, block_k, by_key=True, group=group),
+        kernel(_bwd_dkv_kernel), bh_kv,
+        _grid(causal, kv_len, block_q, block_k, sub, by_key=True, group=group),
         out_shape=(
             jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), kf.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh_kv, t_pad, d_pad), vf.dtype, vma=vma),
@@ -604,11 +764,7 @@ def _bwd_call(qf, kf, vf, of, lse, do, causal, scale, block_q, block_k,
     k_spec2 = ((1, block_k, d_pad), lambda b, iq, ik: (b // group, ik, 0))
     r_spec2 = ((1, block_q, _SUB), lambda b, iq, ik: (b, iq, 0))
     (dq,) = _tiled_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len, t_pad=t_pad,
-        ),
-        bh, _grid(causal, kv_len, block_q, block_k),
+        kernel(_bwd_dq_kernel), bh, _grid(causal, kv_len, block_q, block_k, sub),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t_pad, d_pad), qf.dtype, vma=vma),
         ),
@@ -700,8 +856,7 @@ def _flash_with_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     caller's layout: out ``[b, t, h, d]``, lse ``[b, h, t]`` (f32)."""
     b, t, h, d = q.shape
     block_q, block_k = _blocks(t, block_q, block_k)
-    tile = int(np.lcm(block_q, block_k))
-    t_pad = -(-t // tile) * tile
+    t_pad = _padded(t, block_q, block_k)
     qp, kp, vp = (_pad_to(x, t_pad, d) for x in (q, k, v))
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
         b * x.shape[2], t_pad, d
@@ -820,6 +975,30 @@ def _auto_block(t: int) -> int:
     return 128
 
 
+def _sub_tile(block_q, block_k):
+    """The side of the square sub-tiles a partial tile is walked in, or
+    None to compute it whole, masked: 512 in a tile of 1 024 or more, whole
+    below.
+
+    Measured on the v5e (PR 39: one layer's ``value_and_grad``, the own time
+    of the three kernels, the forward kernel once) at the four cells' shapes
+    — causal at T = 1 024 with heads of 64, causal at 4 096 and block
+    diffusion at 8 192 with heads of 128, tiles of 1 024 — against the
+    parent's whole masked tiles (ms): ``gpt2m_1chip_full`` 0.997 → 1.009
+    whole (the mask-free bodies alone), 2.743 in sub-tiles of 128, 1.429 of
+    256, **0.840 of 512**; ``gpt2m_1chip_b1`` 0.247 → 0.250 / 0.682 / 0.354 /
+    **0.207**; ``mistral4_1chip_b1`` 5.38 → 4.85 / 8.38 / 5.70 / **4.52**;
+    ``sdar30b_1chip_b2`` 35.31 → 26.84 / 35.91 / 25.41 / **21.69**. The walk
+    is a rolled loop, and a turn of it costs ≈ 0.4 µs however little it
+    computes (its matmul → softmax → matmul chain does not overlap the next
+    turn's), so a sub-tile must be large to pay: the same side won at either
+    head size and under either mask kind, and neither enters the rule. A
+    tile of 512 or less has no sub-tile that large, so it stays whole."""
+    if block_q == block_k and block_q > 512 and block_q % 512 == 0:
+        return 512
+    return None
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret"),
@@ -833,8 +1012,7 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     # kernel blocks span the full head axis, and Mosaic accepts any block
     # dim equal to the overall array dim (lane packing is its job; an
     # explicit pad to 128 would double the matmul FLOPs at d=64).
-    tile = int(np.lcm(block_q, block_k))
-    t_pad = -(-t // tile) * tile
+    t_pad = _padded(t, block_q, block_k)
     d_pad = d
     qp, kp, vp = (_pad_to(x, t_pad, d_pad) for x in (q, k, v))
     # fold by each tensor's OWN head count: grouped-query K/V stays

@@ -28,13 +28,13 @@ from bluefog_tpu.ops.flash import BlockDiffusionMask
 
 pytestmark = pytest.mark.slow
 
-# mask kind, batch, positions, query heads, key-value heads, live tiles, and
-# the `copy` / `copy-start` instructions of a tensor that the compile of the
-# rectangular grid held (f16631d: the layouts into and out of the folded
+# mask kind, batch, positions, query heads, key-value heads, live tiles, the
+# length of the sub-tile runs' table, and the `copy` / `copy-start`
+# instructions of a tensor (f16631d: the layouts into and out of the folded
 # [batch x heads, positions, 128], operands moved between memory spaces)
 SHAPES = {
-    "sdar30b_1chip_b2": (BlockDiffusionMask(4096, 4), 2, 8192, 32, 4, 24, 7),
-    "mistral4_1chip_b1": (True, 1, 4096, 32, 32, 10, 10),
+    "sdar30b_1chip_b2": (BlockDiffusionMask(4096, 4), 2, 8192, 32, 4, 24, 8, 7),
+    "mistral4_1chip_b1": (True, 1, 4096, 32, 32, 10, 5, 10),
 }
 
 
@@ -52,7 +52,7 @@ def one_chip():
 
 @pytest.mark.parametrize("cell", SHAPES)
 def test_the_live_tile_grids_compile_for_the_v5e(one_chip, cell):
-    kind, b, t, h, hkv, live, tensor_copies = SHAPES[cell]
+    kind, b, t, h, hkv, live, subs, tensor_copies = SHAPES[cell]
     assert flash.grid_steps(t, kind) == live  # the list, not the rectangle
 
     def loss(q, k, v):
@@ -74,13 +74,17 @@ def test_the_live_tile_grids_compile_for_the_v5e(one_chip, cell):
     calls = re.findall(r"%(bf_flash_[a-z]+)[.\d]* = .* custom-call\(.*tpu_custom_call", text)
     assert sorted(calls) == ["bf_flash_dkv", "bf_flash_dq", "bf_flash_fwd"]
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-    # each kernel takes its three tables, as long as its grid
+    # each kernel takes its four tables, as long as its grid, and the runs
+    # of sub-tiles they point into
     tables = re.findall(
-        r"operand_layout_constraints=\{s32\[(\d+)\]\{0\}, s32\[\1\]\{0\}, s32\[\1\]\{0\}, bf16", text
+        r"operand_layout_constraints=\{s32\[(\d+)\]\{0\}, s32\[\1\]\{0\}, "
+        r"s32\[\1\]\{0\}, s32\[\1\]\{0\}, s32\[(\d+)\]\{0\}, bf16", text
     )
-    assert sorted(map(int, tables)) == [live, live, h // hkv * live]
+    assert sorted(int(n) for n, _ in tables) == [live, live, h // hkv * live]
+    assert {int(m) for _, m in tables} == {subs}
     # the tables are copied into scalar memory (the forward and the dQ
-    # kernel read one set); no tensor is, that was not
+    # kernel read one set, the dK/dV kernel its own four beside the runs
+    # all three share); no tensor is, that was not
     copies = re.findall(r"= \(?(\w+)\[[\d,]+\].* copy(?:-start)?\(", text)
-    assert copies.count("s32") == 6 and copies.count("bf16") == tensor_copies, copies
-    assert len(copies) == 6 + tensor_copies, copies
+    assert copies.count("s32") == 9 and copies.count("bf16") == tensor_copies, copies
+    assert len(copies) == 9 + tensor_copies, copies

@@ -368,7 +368,14 @@ def test_scopes_and_gauges_of_one_traced_loss():
     assert peek("bluefog.moe.rows_offered") == positions * 2 * layers
     live, total = peek("bluefog.attn.tiles_live"), peek("bluefog.attn.tiles_total")
     assert 0 < live <= total and total == BATCH * 4 * layers  # one tile a head
-    assert peek("bluefog.attn.grid_steps") == total  # so the grid is the rectangle
+    assert peek("bluefog.attn.grid_steps") == total  # a list of that one tile
+    # the one causal tile of 128 over 24 positions walks no sub-tiles, and
+    # by every pair it is partial: built and masked
+    pos = np.arange(128)
+    keep = (pos[:, None] >= pos[None, :]) & (pos < SEQ)[None, :]
+    real = (pos < SEQ)[:, None]
+    assert (keep & real).any() and not (keep | ~real).all()
+    assert peek("bluefog.attn.subtiles_live") == peek("bluefog.attn.subtiles_masked") == total
 
 
 def test_the_readers_parts_tell_the_latent_path_from_the_rest_of_attention():

@@ -18,6 +18,7 @@ sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks", "tests")]
 
 import bluefog_tpu as bf  # noqa: E402
 from bluefog_tpu import metrics, models  # noqa: E402
+from bluefog_tpu.ops import flash  # noqa: E402
 from benchmarks.harness import bench, cells, sdar_costs  # noqa: E402
 
 import toy  # noqa: E402
@@ -162,15 +163,21 @@ def test_the_cells_counts_are_the_issues():
     assert abs(per_token - 3.16e9) < 1e7
 
 
-@pytest.mark.parametrize("name, masked, steps, live, total", [
-    ("sdar30b_1chip_b2", True, 9216, 9216, 24576),   # 24 of 64 tiles x 64 x 6
-    ("mistral4_1chip_b1", True, 1280, 1280, 2048),   # 10 of 16 tiles x 32 x 4
-    ("sdar30b_1chip_b2", False, 24576, 24576, 24576),
+@pytest.mark.parametrize("name, masked, steps, live, total, sub_live, sub_masked", [
+    # 24 of 64 tiles x 64 x 6; 12 whole (4 sub-tiles of 512 each), 8 with
+    # 3 live sub-tiles of which 2 partial, 4 with 2 partial
+    ("sdar30b_1chip_b2", True, 9216, 9216, 24576, 80 * 384, 24 * 384),
+    # 10 of 16 tiles x 32 x 4: 6 whole, 4 diagonal ones with 3 live and 2 partial
+    ("mistral4_1chip_b1", True, 1280, 1280, 2048, 36 * 128, 8 * 128),
+    ("sdar30b_1chip_b2", False, 24576, 24576, 24576, 64 * 4 * 384, 0),
 ])
-def test_the_attention_gauges_of_the_benchmarks_decoder_cells(name, masked, steps, live, total):
+def test_the_attention_gauges_of_the_benchmarks_decoder_cells(
+    name, masked, steps, live, total, sub_live, sub_masked
+):
     """``bluefog.attn.grid_steps`` is read from the grid the forward kernel
     is given: the live tiles under either cell's mask kind, the whole
-    rectangle for an unmasked call."""
+    rectangle for an unmasked call. ``subtiles_live`` / ``_masked`` count
+    the sub-tiles of 512 computed and masked."""
     from bluefog_tpu.models import decoder
     from bluefog_tpu.ops.flash import BlockDiffusionMask
 
@@ -182,6 +189,7 @@ def test_the_attention_gauges_of_the_benchmarks_decoder_cells(name, masked, step
     decoder._record_static_counts(job.cfg, job.batch, positions, mask if masked else None)
     peek = lambda gauge: metrics.peek(f"bluefog.attn.{gauge}").value
     assert (peek("grid_steps"), peek("tiles_live"), peek("tiles_total")) == (steps, live, total)
+    assert (peek("subtiles_live"), peek("subtiles_masked")) == (sub_live, sub_masked)
 
 
 @pytest.mark.parametrize("change", [
@@ -225,7 +233,14 @@ def test_scopes_and_gauges_of_one_traced_loss():
     assert peek("bluefog.moe.buffer_rows") == -(-tiles // 8) * 8 * 8 * layers
     live, total = peek("bluefog.attn.tiles_live"), peek("bluefog.attn.tiles_total")
     assert 0 < live <= total and total == BATCH * 4 * layers  # one tile a head
-    assert peek("bluefog.attn.grid_steps") == total  # so the grid is the rectangle
+    assert peek("bluefog.attn.grid_steps") == total  # a list of that one tile
+    # the one tile of 128 walks no sub-tiles; by every pair it is partial
+    pos = np.arange(128)
+    keep = flash.BlockDiffusionMask(SEQ, 4).allowed(pos[:, None], pos[None, :], xp=np)
+    keep &= (pos < 2 * SEQ)[None, :]
+    real = (pos < 2 * SEQ)[:, None]
+    assert (keep & real).any() and not (keep | ~real).all()
+    assert peek("bluefog.attn.subtiles_live") == peek("bluefog.attn.subtiles_masked") == total
 
 
 def test_the_causal_mask_kind_sees_no_later_token():
