@@ -33,7 +33,10 @@ routed to them. That is the contract of :func:`expert_layer`:
   count is read from what landed (``_over_tiles_in_use``). One write as
   long as the buffer is left: a buffer that a loop fills (the rows, the
   activation) starts as one value written all over (``_smeared``), and
-  nothing reads the tiles past the last chunk.
+  nothing reads the tiles past the last chunk. The way back to token space
+  (the combine, and the dispatch's backward pass) is one gather of
+  ``tokens`` rows plus a loop whose trip count is the most choices any
+  token landed (``_tokens_of_rows``), not one gather a choice.
 
 The shares add up: over a partition of the experts into held ranges the
 layers' outputs sum to the uncut layer's (``tests/test_moe.py``). On one
@@ -81,22 +84,52 @@ def route(u, w_router, k, norm_topk_prob=True, dtype=jnp.float32):
     return weights, experts.astype(jnp.int32)
 
 
-def _tokens_of_rows(buf, row_of_pair, landed, scale=None):
-    """Token space from the row buffer: token ``t`` gets the float32 sum
-    over its choices ``s`` that landed of ``buf[row of pair (t, s)]``
-    (times ``scale[t, s]``). One gather of ``tokens`` rows a choice: the
-    ``[tokens, k, d]`` view of one big gather would put ``k`` on the
-    sublanes and pad it. ``buf [rows, d]`` -> ``[tokens, d]`` float32."""
-    index = jnp.minimum(row_of_pair, buf.shape[0] - 1)
-    total = jnp.zeros((landed.shape[0], buf.shape[1]), jnp.float32)
-    for s in range(landed.shape[1]):
-        term = buf[index[:, s]].astype(jnp.float32)
-        if scale is not None:
-            term = term * scale[:, s, None]
-        # a select, not a product with 0: the tiles past the ones in use
-        # are whatever was there
-        total = total + jnp.where(landed[:, s, None], term, 0.0)
-    return total
+def _slots(landed, row_of_pair):
+    """A token's choices that landed, compacted to the front in choice
+    order: ``(slot_rows, slot_choice, most)``. ``slot_rows [tokens, k]``
+    holds the buffer rows of the landed choices from slot 0, then -1;
+    ``slot_choice [tokens, k]`` the choice a slot holds (0 past the landed
+    ones); ``most`` the most choices any token landed, a device scalar. A
+    compare-and-sum over ``[tokens, k, k]``, not a scatter."""
+    k = landed.shape[1]
+    slot = jnp.cumsum(landed, axis=1, dtype=jnp.int32) - 1  # of a landed choice
+    is_slot = landed[:, :, None] & (slot[:, :, None] == jnp.arange(k, dtype=jnp.int32))
+    choice = jnp.arange(k, dtype=jnp.int32)[:, None]
+    slot_rows = jnp.sum(is_slot * (row_of_pair[:, :, None] + 1), axis=1, dtype=jnp.int32) - 1
+    slot_choice = jnp.sum(is_slot * choice, axis=1, dtype=jnp.int32)
+    most = jnp.max(jnp.sum(landed, axis=1, dtype=jnp.int32))
+    return slot_rows, slot_choice, most
+
+
+def _tokens_of_rows(buf, slot_rows, most, scale=None):
+    """Token space from the row buffer: token ``t`` gets the float32 sum,
+    in choice order, over its choices that landed of their rows of ``buf``
+    (times ``scale [tokens, k]``, by slot) — ``slot_rows`` and ``most`` as
+    ``_slots`` makes them. Slot 0 is one gather of ``tokens`` rows; slots
+    ``1 .. most - 1`` a loop of one gather a turn whose trip count is read
+    from the device, so a call costs the most choices any token landed, not
+    ``k``. ``buf [rows, d]`` -> ``[tokens, d]`` float32."""
+    # the loop's carry starts as a gather, not as zeros (see `_smeared`)
+    total = _slot_term(buf, slot_rows, scale, 0)
+    carry = (total, buf, slot_rows, scale)
+    return jax.lax.fori_loop(1, most, _slot_turn, carry)[0]
+
+
+def _slot_term(buf, slot_rows, scale, s):
+    row = jax.lax.dynamic_index_in_dim(slot_rows, s, axis=1, keepdims=False)
+    term = buf[jnp.maximum(row, 0)].astype(jnp.float32)
+    if scale is not None:
+        term = term * jax.lax.dynamic_index_in_dim(scale, s, axis=1)
+    # a select, not a product with 0: the tiles past the ones in use are
+    # whatever was there
+    return jnp.where(row[:, None] >= 0, term, 0.0)
+
+
+def _slot_turn(s, carry):
+    """A turn of ``_tokens_of_rows``: slot ``s`` added into the total in
+    place. A function of the module, not a closure (see ``_body``)."""
+    total, *reads = carry
+    return (total + _slot_term(*reads, s), *reads)
 
 
 # -- passes over the row buffer ------------------------------------------------
@@ -173,6 +206,9 @@ class _Moves(NamedTuple):
     tiles_used: jax.Array  # [] the buffer's first tiles, the ones in use
     row_of_pair: jax.Array  # [tokens, k] where a pair landed
     landed: jax.Array  # [tokens, k] whether it did
+    slot_rows: jax.Array  # [tokens, k] rows of a token's landed pairs, then -1
+    slot_choice: jax.Array  # [tokens, k] the choice of each
+    most: jax.Array  # [] the most pairs of any token that landed
 
 
 def _pairs_of_rows(tm, first, moves):
@@ -212,7 +248,7 @@ def _dispatch_fwd(tm, u, moves):
 
 
 def _dispatch_bwd(tm, moves, dxs):
-    return _tokens_of_rows(dxs, moves.row_of_pair, moves.landed).astype(dxs.dtype), None
+    return _tokens_of_rows(dxs, moves.slot_rows, moves.most).astype(dxs.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -237,7 +273,11 @@ def _weigh_turn(tm, first, out, d_w_rows, dy, weights, *moves):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _combine(tm, out, weights, moves):
     del tm
-    return _tokens_of_rows(out, moves.row_of_pair, moves.landed, weights)
+    # the weight of each slot's choice: a compare-and-sum, not a gather of
+    # single elements, which the TPU runs slowly (1 ms a layer in sdar's cell)
+    is_choice = moves.slot_choice[:, :, None] == jnp.arange(weights.shape[1])
+    scale = jnp.sum(jnp.where(is_choice, weights[:, None, :], 0.0), axis=2)
+    return _tokens_of_rows(out, moves.slot_rows, moves.most, scale)
 
 
 def _combine_fwd(tm, out, weights, moves):
@@ -608,9 +648,11 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
         shift = jnp.append(first_tile * tm - first_rank, 0)
         row_of_pair = rank_of_pair + jnp.sum(is_group * shift, axis=1, dtype=jnp.int32)
 
+        # token -> its pairs that landed, for the way back to token space
+        row_of_pair, landed = row_of_pair.reshape(n, k), landed.reshape(n, k)
         moves = _Moves(
             jnp.pad(sorted_pairs, (0, tm)), tile_rank, tile_rows, tiles_used,
-            row_of_pair.reshape(n, k), landed.reshape(n, k),
+            row_of_pair, landed, *_slots(landed, row_of_pair),
         )
         xs = _dispatch(tm, u.astype(dtype), moves)
     with jax.named_scope("bf.moe.experts"):

@@ -198,6 +198,98 @@ def test_moving_rows_is_a_gather_in_both_directions():
     assert "cond" not in primitives and "while" in primitives
 
 
+def k_gathers(buf, row_of_pair, landed, scale=None):
+    """The way back to token space as one gather a choice, every choice,
+    the pairs that did not land selected away: what ``_tokens_of_rows``
+    must equal."""
+    total = jnp.zeros((landed.shape[0], buf.shape[1]), jnp.float32)
+    for s in range(landed.shape[1]):
+        term = buf[row_of_pair[:, s]].astype(jnp.float32)
+        if scale is not None:
+            term = term * scale[:, s, None]
+        total = total + jnp.where(landed[:, s, None], term, 0.0)
+    return total
+
+
+def landed_choices(case, rng):
+    """``landed [N, K]``: one choice a token (the tiled router's), every
+    choice, none, two a token, or the router's own draw over 4 of E held."""
+    if case == "tiled":
+        return np.eye(K, dtype=bool)[rng.randint(0, K, N)]
+    if case in ("all-held", "none-held"):
+        return np.full((N, K), case == "all-held")
+    if case == "two-a-token":
+        return np.stack([np.isin(np.arange(K), rng.permutation(K)[:2]) for _ in range(N)])
+    w = weights(12)
+    _, chosen = moe.route(w["u"], w["router"], K)
+    return (np.asarray(chosen) >= 4) & (np.asarray(chosen) < 8)
+
+
+@pytest.mark.parametrize("use", ["combine", "dispatch_bwd"])
+@pytest.mark.parametrize("case", ["tiled", "all-held", "none-held", "two-a-token", "router"])
+def test_the_way_back_to_token_space_is_the_k_gather_sum(case, use):
+    """The combine (rows weighted) and the dispatch's backward pass (rows
+    as they are) give, token by token, the sum of one gather a choice, to
+    the bit but for the sign of a zero: the same terms in the same order.
+    The rows no landed pair holds are NaN, so a term that is not selected
+    away shows; the loop runs ``most - 1`` turns. The weights have 12
+    fraction bits, so that a bfloat16 row times one is exact and a fused
+    multiply-add (XLA:CPU makes them) rounds as a product and a sum do:
+    only the order of the sum can tell the two apart."""
+    rng = np.random.RandomState(13)
+    landed = landed_choices(case, rng)
+    rows = N * K + 8
+    row_of_pair = rng.randint(0, rows, (N, K))
+    row_of_pair[landed] = rng.permutation(rows)[:landed.sum()]
+    buf = rng.randn(rows, D).astype(np.float32)
+    buf[np.setdiff1d(np.arange(rows), row_of_pair[landed])] = np.nan
+    top = jnp.asarray(np.round(rng.dirichlet(np.ones(K), N) * 4096) / 4096, jnp.float32)
+    landed, row_of_pair = jnp.asarray(landed), jnp.asarray(row_of_pair, jnp.int32)
+    moves = moe._Moves(
+        *[jnp.zeros((), jnp.int32)] * 4, row_of_pair, landed,
+        *moe._slots(landed, row_of_pair),
+    )
+    assert int(moves.most) == int(landed.sum(1).max())
+    if use == "combine":
+        buf = jnp.asarray(buf, jnp.bfloat16)
+        got = jax.jit(moe._combine, static_argnums=0)(8, buf, top, moves)
+        want = jax.jit(k_gathers)(buf, row_of_pair, landed, top)
+    else:
+        buf = jnp.asarray(buf)
+        got, _ = jax.jit(moe._dispatch_bwd, static_argnums=0)(8, moves, buf)
+        want = jax.jit(k_gathers)(buf, row_of_pair, landed)
+    assert got.shape == (N, D) and got.dtype == jnp.float32
+    bits = lambda x: (np.asarray(x) + np.float32(0)).view(np.uint32)  # -0 -> +0
+    np.testing.assert_array_equal(bits(got), bits(want))
+    if case == "none-held":
+        assert not np.asarray(got).any()
+
+
+def test_the_way_back_is_two_gathers_of_token_rows_outside_a_loop():
+    """In the gradient of the whole layer a gather that yields a ``[tokens,
+    d]`` value lies outside a loop twice — the first slot of the combine
+    and of the dispatch's backward pass — not once a choice; the other
+    slots are a loop's, and there is no branch."""
+    w = weights(14)
+    top, chosen = moe.route(w["u"], w["router"], K)
+
+    def loss(u, top, gate, up, down):
+        return moe.expert_layer(u, top, chosen, gate, up, down, held_start=4)[0].sum()
+
+    sl = slice(4, 12)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        w["u"], top, w["gate"][sl], w["up"][sl], w["down"][sl]
+    )
+    gathers = {"outside": 0, "inside": 0}
+    primitives = set()
+    for eqn, inside in equations(jaxpr.jaxpr):
+        primitives.add(eqn.primitive.name)
+        if eqn.primitive.name == "gather" and eqn.outvars[0].aval.shape == (N, D):
+            gathers["inside" if "while" in inside else "outside"] += 1
+    assert gathers == {"outside": 2, "inside": 2}, gathers
+    assert "cond" not in primitives
+
+
 @pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
 def test_no_pass_outside_a_loop_is_as_long_as_the_buffer(path):
     """In the gradient of the whole layer (all five) nothing that moves or
