@@ -10,7 +10,9 @@ is built from the keys a sparse-expert decoder's ``config.json`` carries —
 ``vocab_size`` — plus what no such file says (:class:`DecoderConfig`).
 Two of the source's keys choose a layer's kind: ``kv_lora_rank`` present
 makes its attention **latent** (below), ``n_shared_experts`` > 0 puts a
-shared expert beside the routed ones.
+shared expert beside the routed ones. SmallThinker's names of the expert
+sizes (``moe_num_primary_experts``, ``moe_num_active_primary_experts``,
+``moe_ffn_hidden_size``) are read as the three above.
 
 One layer, input ``x [positions, hidden]``::
 
@@ -53,6 +55,17 @@ has the recurrence)::
     o = gated_delta(q, k, v, g, beta)
     h = x + (RMSNorm_d(o) * sigmoid(u W_ga W_gb)) W_o
 
+**A sliding window** (``sliding_window_size`` and a ``sliding_window_layout``
+of 0 / 1 a layer): a layer marked 1 sees the last ``sliding_window_size``
+positions only (``j <= i`` and ``i - j < window``,
+:class:`bluefog_tpu.ops.flash.SlidingWindowMask`: the kernels walk the band
+alone), under a model called causal; a layer marked 0 is causal over the
+whole sequence. ``rope_layout`` chooses rotary positions the same way, a
+layer at a time (0: no positions, NoPE). The router may read the
+attention's input ``u`` in place of ``u'`` (``router_input``, not the
+source's: "router placed before attention"), and the experts' activation
+may be ReLU (``hidden_act``; ReGLU, ``relu(u' Wg_e) * (u' Wu_e)``).
+
 Softmax attention may go without rotary positions (``use_rope`` false) and
 carry an output gate (``use_gqa_gate``: ``att * sigmoid(u W_gate)``,
 elementwise, before ``W_o``). **A head share** (``attention_shares`` > 1)
@@ -75,7 +88,9 @@ noised half only.
 
 Scopes (``jax.named_scope``, read back from the compiled step's
 ``op_name``s): ``bf.attn`` (projections, norms, rotary, the kernel) and
-inside it ``bf.attn.latent`` (latent attention's down- and up-projections,
+inside it ``bf.attn.window`` (a window layer's whole attention sublayer:
+projections, rotary, the kernels and ``o_proj``), ``bf.attn.latent``
+(latent attention's down- and up-projections,
 its two norms, rotary and the assembly of the keys: what a latent-aware
 kernel would not need; the kernels and ``o_proj`` stay directly under
 ``bf.attn``) and ``bf.attn.delta`` (the whole linear-attention sublayer:
@@ -102,7 +117,11 @@ tile counts all its sub-tiles live and none masked); with latent attention
 ``bluefog.attn.kv_latent_bytes`` (the compressed stream a call makes:
 positions x (kv_lora_rank + qk_rope_head_dim) x layers x itemsize) and
 ``bluefog.attn.kv_expanded_bytes`` (the per-head keys and values the
-kernels read instead); with a shared expert ``bluefog.moe.shared_rows``
+kernels read instead); with a sliding window ``bluefog.attn.window_layers``
+(the layers under it) and ``bluefog.attn.window_tiles_live`` /
+``bluefog.attn.window_tiles_total`` (``tiles_live`` / ``tiles_total`` of
+those layers alone: each layer's tiles are counted under its own mask
+kind); with a shared expert ``bluefog.moe.shared_rows``
 (positions x layers a call); ``bluefog.attn.heads_held`` (query heads a
 softmax layer holds); with linear attention ``bluefog.delta.chunks`` (chunk
 steps of a forward pass: batch x heads held x chunks x linear layers) and
@@ -128,7 +147,8 @@ __all__ = [
     "block_diffusion_loss", "next_token_loss",
 ]
 
-MaskKind = Union[None, str, flash.BlockDiffusionMask]
+MaskKind = Union[None, str, flash.BlockDiffusionMask, flash.SlidingWindowMask]
+_MASK_OBJECTS = (flash.BlockDiffusionMask, flash.SlidingWindowMask)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,6 +274,13 @@ class DecoderConfig:
     gqa_layers: Optional[tuple] = None  # None: every layer softmax attention
     gqa_interval: Optional[int] = None
     kda_allow_neg_eigval: bool = False
+    # the experts' activation; a window and rotary positions a layer (0 / 1
+    # entries, the first num_hidden_layers read; None: no window, and
+    # use_rope on every layer)
+    hidden_act: str = "silu"
+    sliding_window_size: Optional[int] = None
+    sliding_window_layout: Optional[tuple] = None
+    rope_layout: Optional[tuple] = None
     # linear_attn_config's keys (DecoderConfig.from_source reads the group)
     linear_num_heads: Optional[int] = None
     linear_head_dim: Optional[int] = None
@@ -278,14 +305,35 @@ class DecoderConfig:
     # every attention layer's heads are held 1 / attention_shares a chip;
     # which share changes no number here: its parameters are its heads'
     attention_shares: int = 1
+    # what the router reads: the post-attention norm's output u' or the
+    # input norm's, the attention's own input u
+    router_input: str = "post_attn_norm"
 
     def __post_init__(self):
         if self.experts_total is None:
             object.__setattr__(self, "experts_total", self.num_experts)
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads must be a multiple of kv heads")
-        if self.use_rope and self.head_dim % 2:
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None and len(layout) < self.num_hidden_layers:
+                raise ValueError(
+                    f"{name} has {len(layout)} entries for "
+                    f"{self.num_hidden_layers} layers"
+                )
+        if self.sliding_window_layout is not None and not self.sliding_window_size:
+            raise ValueError("sliding_window_layout needs a sliding_window_size")
+        if any(self.layer_rope) and self.head_dim % 2:
             raise ValueError("rotary positions need an even head_dim")
+        if self.hidden_act not in moe.ACTIVATIONS:
+            raise ValueError(
+                f"hidden_act = {self.hidden_act!r}: this stack builds only "
+                f"{moe.ACTIVATIONS}"
+            )
+        if self.router_input not in ("post_attn_norm", "input_norm"):
+            raise ValueError(f"router_input {self.router_input!r}")
+        if any(w and k == "linear" for k, w in zip(self.layer_kinds, self.layer_windows)):
+            raise ValueError("sliding_window_layout marks a linear layer: no window there")
         heads = [self.num_attention_heads, self.num_key_value_heads]
         if self.gqa_layers is not None:
             step = (self.gqa_interval or 0) + 1
@@ -324,6 +372,8 @@ class DecoderConfig:
             return
         if not self.q_lora_rank:
             raise ValueError("latent attention here has the low-rank query path only")
+        if self.rope_layout is not None:
+            raise ValueError("rope_layout: latent attention rotates its rotary part on every layer")
         if not self.rope_interleave or self.qk_rope_head_dim % 2:
             raise ValueError(
                 "latent attention rotates interleaved pairs: rope_interleave "
@@ -348,6 +398,25 @@ class DecoderConfig:
             for i in range(self.num_hidden_layers)
         )
 
+    @property
+    def layer_windows(self):
+        """Each layer's window, or ``None`` for attention over the whole
+        sequence: ``sliding_window_size`` where ``sliding_window_layout``
+        marks the layer 1."""
+        layout = self.sliding_window_layout or (0,) * self.num_hidden_layers
+        return tuple(
+            self.sliding_window_size if layout[i] else None
+            for i in range(self.num_hidden_layers)
+        )
+
+    @property
+    def layer_rope(self):
+        """Whether each layer's softmax attention turns rotary positions:
+        ``rope_layout``'s entry, or ``use_rope`` on every layer without one."""
+        if self.rope_layout is None:
+            return (self.use_rope,) * self.num_hidden_layers
+        return tuple(bool(self.rope_layout[i]) for i in range(self.num_hidden_layers))
+
     def held(self, heads):
         """Of ``heads`` (a layer's published count), how many this share holds."""
         return heads // self.attention_shares
@@ -359,12 +428,14 @@ class DecoderConfig:
         what the source does not say (``own``)."""
         refusals = {
             "attention_bias": (False,), "tie_word_embeddings": (False,),
-            "hidden_act": ("silu",), "decoder_sparse_step": (1,),
+            "hidden_act": moe.ACTIVATIONS, "decoder_sparse_step": (1,),
             "mlp_only_layers": ([],), "rope_scaling": (None,),
             "use_sliding_window": (False,), "sliding_window": (None,),
             "mlp_bias": (False,), "first_k_dense_replace": (0,),
             "n_group": (1,), "topk_group": (1,),
             "kda_use_full_proj": (False,), "partial_rotary_factor": (1,),
+            # sigmoid scores, the alternative, have no reference here
+            "moe_primary_router_apply_softmax": (True,),
         }
         for key, allowed in refusals.items():
             if key in entry and entry[key] not in allowed:
@@ -372,8 +443,14 @@ class DecoderConfig:
                     f"{key} = {entry[key]!r}: this stack builds only {allowed}"
                 )
         entry = dict(entry)
-        if "n_routed_experts" in entry:
-            entry.setdefault("num_experts", entry["n_routed_experts"])
+        for theirs, ours in (
+            ("n_routed_experts", "num_experts"),
+            ("moe_num_primary_experts", "num_experts"),
+            ("moe_num_active_primary_experts", "num_experts_per_tok"),
+            ("moe_ffn_hidden_size", "moe_intermediate_size"),
+        ):
+            if theirs in entry:
+                entry.setdefault(ours, entry[theirs])
         linear = entry.pop("linear_attn_config", None)
         if linear is not None:
             if linear.get("num_kv_heads") is not None:
@@ -386,8 +463,9 @@ class DecoderConfig:
                 linear_head_dim=linear["head_dim"],
                 linear_conv_size=linear["short_conv_kernel_size"],
             )
-        if entry.get("gqa_layers") is not None:
-            entry["gqa_layers"] = tuple(entry["gqa_layers"])
+        for name in ("gqa_layers", "sliding_window_layout", "rope_layout"):
+            if entry.get(name) is not None:
+                entry[name] = tuple(entry[name])
         if entry.get("rope_parameters") is not None:
             rope = RopeParameters.from_source(entry["rope_parameters"])
             entry.update(rope_parameters=rope, rope_theta=rope.rope_theta)
@@ -397,8 +475,20 @@ class DecoderConfig:
 
 def _kernel_kind(mask: MaskKind):
     """A model's mask kind as ``ops/flash.py`` names it: ``False`` (none),
-    ``True`` (``"causal"``) or the ``BlockDiffusionMask`` itself."""
-    return mask if isinstance(mask, flash.BlockDiffusionMask) else mask == "causal"
+    ``True`` (``"causal"``) or the mask object itself."""
+    return mask if isinstance(mask, _MASK_OBJECTS) else mask == "causal"
+
+
+def _layer_mask(mask: MaskKind, window):
+    """The mask kind of a layer with ``window`` (``None``: the model's own)
+    under the model's ``mask``: a window narrows causal attention only."""
+    if window is None:
+        return mask
+    if mask != "causal":
+        raise ValueError(
+            f"a sliding window narrows causal attention: mask {mask!r} refused"
+        )
+    return flash.SlidingWindowMask(window)
 
 
 def _attend(q, k, v, mask: MaskKind, scale=None):
@@ -517,6 +607,7 @@ class LatentAttention(nn.Module):
 
 class Attention(nn.Module):
     cfg: DecoderConfig
+    rope: bool  # rotary positions: DecoderConfig.layer_rope's entry
 
     @nn.compact
     def __call__(self, x, positions, mask):
@@ -534,7 +625,7 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, dt, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, dt, name="k_norm")(k)
-        if cfg.use_rope:
+        if self.rope:
             q = rotary(q, positions, cfg.rope_theta)
             k = rotary(k, positions, cfg.rope_theta)
         att = _attend(q, k, v, mask)
@@ -616,12 +707,13 @@ class DeltaAttention(nn.Module):
 
 class SparseExperts(nn.Module):
     """The router over ``experts_total`` and this chip's ``num_experts``
-    experts as three stacked leaves (``ops/moe.py`` has the contract)."""
+    experts as three stacked leaves (``ops/moe.py`` has the contract). The
+    experts read ``u``, the router ``route_from`` (default ``u``)."""
 
     cfg: DecoderConfig
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, route_from=None):
         cfg = self.cfg
         d, f, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
         w_router = self.param(
@@ -634,7 +726,8 @@ class SparseExperts(nn.Module):
         rows = u.reshape(b * t, d)
         with jax.named_scope("bf.moe.route"):
             weights, experts = moe.route(
-                rows, w_router, cfg.num_experts_per_tok, cfg.norm_topk_prob,
+                rows if route_from is None else route_from.reshape(b * t, d),
+                w_router, cfg.num_experts_per_tok, cfg.norm_topk_prob,
                 dtype=cfg.router_dtype,
             )
         if cfg.routed_scaling_factor != 1:
@@ -644,6 +737,7 @@ class SparseExperts(nn.Module):
         y, counts = moe.expert_layer(
             rows, weights, experts, w_gate, w_up, w_down,
             held_start=cfg.experts_start, dtype=cfg.compute_dtype,
+            activation=cfg.hidden_act,
         )
         return y.reshape(b, t, d), counts
 
@@ -662,31 +756,43 @@ class SharedExpert(nn.Module):
         gate = _dense(cfg, width, "gate_proj")(u).astype(jnp.float32)
         up = _dense(cfg, width, "up_proj")(u).astype(jnp.float32)
         # the activation in float32, as the routed experts' (``ops/moe.py``)
-        return _dense(cfg, cfg.hidden_size, "down_proj")(jax.nn.silu(gate) * up)
+        act = getattr(jax.nn, cfg.hidden_act)
+        return _dense(cfg, cfg.hidden_size, "down_proj")(act(gate) * up)
 
 
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     kind: str = "softmax"  # DecoderConfig.layer_kinds
+    window: Optional[int] = None  # DecoderConfig.layer_windows
+    rope: bool = dataclasses.field(kw_only=True)  # DecoderConfig.layer_rope
 
     @nn.compact
     def __call__(self, x, positions, mask):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype, name=name)
-        attention = Attention if cfg.kv_lora_rank is None else LatentAttention
         with jax.named_scope("bf.attn"):
-            u = norm("input_norm")(x)
+            u_attn = norm("input_norm")(x)
             if self.kind == "linear":
                 if mask != "causal":
                     raise ValueError(
                         f"linear attention is causal: mask {mask!r} refused"
                     )
                 with jax.named_scope("bf.attn.delta"):
-                    x = x + DeltaAttention(cfg, name="attn")(u)
+                    x = x + DeltaAttention(cfg, name="attn")(u_attn)
             else:
-                x = x + attention(cfg, name="attn")(u, positions, mask)
+                attention = (
+                    Attention(cfg, self.rope, name="attn") if cfg.kv_lora_rank is None
+                    else LatentAttention(cfg, name="attn")
+                )
+                if self.window is None:
+                    x = x + attention(u_attn, positions, mask)
+                else:
+                    with jax.named_scope("bf.attn.window"):
+                        window = _layer_mask(mask, self.window)
+                        x = x + attention(u_attn, positions, window)
         u = norm("post_attn_norm")(x)
-        y, counts = SparseExperts(cfg, name="experts")(u)
+        route_from = u_attn if cfg.router_input == "input_norm" else None
+        y, counts = SparseExperts(cfg, name="experts")(u, route_from)
         if cfg.n_shared_experts:
             with jax.named_scope("bf.moe.shared"):
                 y = y + SharedExpert(cfg, name="shared_expert")(u)
@@ -713,8 +819,10 @@ class DecoderLM(nn.Module):
             else DecoderLayer
         )
         self.layers = [
-            layer(cfg, kind, name=f"layer_{i}")
-            for i, kind in enumerate(cfg.layer_kinds)
+            layer(cfg, kind, window, rope=rope, name=f"layer_{i}")
+            for i, (kind, window, rope) in enumerate(
+                zip(cfg.layer_kinds, cfg.layer_windows, cfg.layer_rope)
+            )
         ]
         self.final_norm = RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
         self.lm_head = nn.Dense(
@@ -744,18 +852,31 @@ class DecoderLM(nn.Module):
         return self.head(h), counts
 
 
+def _attention_counts(positions, slots_by_kind):
+    """``{gauge: count}`` of a forward pass of the attention kernels at
+    ``positions``, summed over ``slots_by_kind``: ``{mask kind (ops.flash's):
+    (batch, head, layer) slots under it}``."""
+    totals = dict.fromkeys(
+        ("tiles_live", "tiles_total", "grid_steps", "subtiles_live", "subtiles_masked"), 0
+    )
+    for kind, slots in slots_by_kind.items():
+        live, total = flash.tile_counts(positions, kind)
+        sub_live, sub_masked = flash.subtile_counts(positions, kind)
+        for name, n in (
+            ("tiles_live", live), ("tiles_total", total),
+            ("grid_steps", flash.grid_steps(positions, kind)),
+            ("subtiles_live", sub_live), ("subtiles_masked", sub_masked),
+        ):
+            totals[name] += n * slots
+    return totals
+
+
 def record_attention_counts(positions, kind, slots):
     """The attention kernels' host gauges for a call at ``positions`` under
     the mask kind ``kind`` (``ops.flash``'s), summed over ``slots`` (batch,
     head, layer) slots: written while tracing, the step does not change."""
-    live, total = flash.tile_counts(positions, kind)
-    sub_live, sub_masked = flash.subtile_counts(positions, kind)
-    for name, n in (
-        ("tiles_live", live), ("tiles_total", total),
-        ("grid_steps", flash.grid_steps(positions, kind)),
-        ("subtiles_live", sub_live), ("subtiles_masked", sub_masked),
-    ):
-        metrics_mod.gauge(f"bluefog.attn.{name}").set(n * slots)
+    for name, n in _attention_counts(positions, {kind: slots}).items():
+        metrics_mod.gauge(f"bluefog.attn.{name}").set(n)
 
 
 def _record_static_counts(cfg, batch, positions, mask):
@@ -776,9 +897,20 @@ def _record_static_counts(cfg, batch, positions, mask):
     kinds = cfg.layer_kinds
     heads = cfg.held(cfg.num_attention_heads)
     metrics_mod.gauge("bluefog.attn.heads_held").set(heads)
-    record_attention_counts(
-        positions, _kernel_kind(mask), batch * heads * kinds.count("softmax"),
-    )
+    # each softmax layer's tiles under its own mask kind
+    layer_kinds = [
+        _kernel_kind(_layer_mask(mask, window))
+        for kind, window in zip(kinds, cfg.layer_windows) if kind == "softmax"
+    ]
+    slots = lambda of: {k: of.count(k) * batch * heads for k in of}
+    for name, n in _attention_counts(positions, slots(layer_kinds)).items():
+        metrics_mod.gauge(f"bluefog.attn.{name}").set(n)
+    windowed = [k for k in layer_kinds if isinstance(k, flash.SlidingWindowMask)]
+    if windowed:
+        counts = _attention_counts(positions, slots(windowed))
+        metrics_mod.gauge("bluefog.attn.window_layers").set(len(windowed))
+        for name in ("tiles_live", "tiles_total"):
+            metrics_mod.gauge(f"bluefog.attn.window_{name}").set(counts[name])
     if "linear" in kinds:
         chunks = (
             batch * cfg.held(cfg.linear_num_heads) * delta.chunk_count(positions)

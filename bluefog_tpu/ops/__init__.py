@@ -24,7 +24,8 @@ from bluefog_tpu.ops.attention import (
 )
 from bluefog_tpu.ops.delta import chunk_gated_delta, gated_delta_reference
 from bluefog_tpu.ops.flash import (
-    BlockDiffusionMask, flash_attention, flash_attention_supported,
+    BlockDiffusionMask, SlidingWindowMask, flash_attention,
+    flash_attention_supported,
 )
 from bluefog_tpu.ops.moe import expert_layer, route
 
@@ -37,6 +38,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_supported",
     "BlockDiffusionMask",
+    "SlidingWindowMask",
     "expert_layer",
     "route",
     "chunk_gated_delta",

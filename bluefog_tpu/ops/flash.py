@@ -21,13 +21,16 @@ probabilities from Q/K and the saved logsumexp instead of materializing
 the T×T matrix).
 
 Mask kinds, all static (positions come from iotas in the kernel, no mask
-tensor ever exists in HBM): none, ``causal=True``, and
+tensor ever exists in HBM): none, ``causal=True``,
 ``mask=BlockDiffusionMask(seq, block)`` — the training mask of block
 diffusion over a doubled sequence (``seq`` clean positions, then their
 ``seq`` noised copies; causal over blocks of ``block``, bidirectional
-inside one). What each part of the square holds is known from the shapes
-(``_classes``, on the host): a tile, and a sub-tile inside it, is dead (no
-allowed pair), whole (every pair allowed and every key real) or partial.
+inside one) — and ``mask=SlidingWindowMask(window)``, causal attention to
+the last ``window`` positions (a query sees itself and the ``window - 1``
+before it): the grids walk only the band along the diagonal. What each
+part of the square holds is known from the shapes (``_classes``, on the
+host): a tile, and a sub-tile inside it, is dead (no allowed pair), whole
+(every pair allowed and every key real) or partial.
 Where any tile is not whole the three kernels' grids walk a
 scalar-prefetched list of the live tiles and are as long as that list
 (``_grid``): a dead tile costs neither a grid step nor a K/V fetch. A whole
@@ -36,8 +39,9 @@ walks its live sub-tiles (512² in a tile of 1 024, ``_sub_tile``) in a
 rolled loop over a scalar-prefetched run of them, slicing Q, K, V, dO and
 the accumulators' rows, and only its partial sub-tiles build a mask: the
 causal diagonal computes three sub-tiles of four, block diffusion's
-noised-noised diagonal two. A configuration whose every tile is whole (no
-mask at a length the tiles divide) keeps the rectangular grid.
+noised-noised diagonal two, a window's far edge three. A configuration
+whose every tile is whole (no mask at a length the tiles divide) keeps the
+rectangular grid.
 
 Grouped-query heads (K/V with ``h_kv`` heads, ``h % h_kv == 0``) never
 exist expanded: a KV head serves its query group from the index maps, and
@@ -51,6 +55,8 @@ unconditionally — except under a ``mask``: a model that asks for the
 block-diffusion kernel is refused (``ValueError``) where the shapes would
 send it down the dense path, never taken there silently.
 ``interpret=True`` runs the kernels in the Pallas interpreter (CPU CI).
+The dense path lays a mask kind's ``allowed`` out in full, so a model under
+a sliding window runs the same mask on the CPU.
 """
 
 import dataclasses
@@ -66,6 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "BlockDiffusionMask",
+    "SlidingWindowMask",
     "block_diffusion_live_tiles",
     "tile_counts",
     "grid_steps",
@@ -142,6 +149,35 @@ class BlockDiffusionMask:
         return some, every
 
 
+@dataclasses.dataclass(frozen=True)
+class SlidingWindowMask:
+    """Causal attention to the last ``window`` positions: the query at ``i``
+    sees the key at ``j`` iff ``j <= i`` and ``i - j < window``. A
+    ``window`` of at least the sequence's length is the causal mask. Static:
+    it is part of the kernel's configuration."""
+
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"window must be positive: {self}")
+
+    def allowed(self, qpos, kpos, xp=jnp):
+        """Elementwise, as ``BlockDiffusionMask.allowed`` (``xp`` unused:
+        the arithmetic is the same in ``numpy`` and ``jax.numpy``)."""
+        del xp
+        # booleans meet only in & | ~: Mosaic has no select between masks
+        return (kpos <= qpos) & (qpos - kpos < self.window)
+
+    def span(self, q0, q1, k0, k1):
+        """``(some, every)`` of each rectangle ``[q0, q1) x [k0, k1)``, as
+        ``BlockDiffusionMask.span``: ``i - j`` takes every value from ``q0 -
+        k1 + 1`` to ``q1 - 1 - k0`` over the rectangle, and the mask allows
+        the values ``0 .. window - 1``."""
+        low, high = q0 - (k1 - 1), (q1 - 1) - k0
+        return (high >= 0) & (low < self.window), (low >= 0) & (high < self.window)
+
+
 def _block_of(pos, block):
     # a shift where it can be one: the vector units have no integer divide
     if block & (block - 1) == 0:
@@ -152,7 +188,7 @@ def _block_of(pos, block):
 def _span(kind, q0, q1, k0, k1):
     """``(some, every)`` pair of each rectangle ``[q0, q1) x [k0, k1)``
     allowed under the mask kind ``kind`` (``False``, ``True`` for causal,
-    or a ``BlockDiffusionMask``)."""
+    or a mask object: ``BlockDiffusionMask``, ``SlidingWindowMask``)."""
     if kind is True:
         return q1 - 1 >= k0, q0 >= k1 - 1
     if kind:
@@ -312,7 +348,7 @@ def _blocks(t, block_q, block_k):
 def tile_counts(t, kind=False, block_q=None, block_k=None):
     """``(live, total)``: the tiles one forward pass visits for one (batch,
     head) at sequence length ``t`` under the mask kind ``kind`` (``False``,
-    ``True`` for causal, or a ``BlockDiffusionMask``), and the tiles of the
+    ``True`` for causal, or a mask object), and the tiles of the
     padded square, at the tile sizes ``flash_attention`` would choose."""
     live = _live(kind, t, *_blocks(t, block_q, block_k))
     return int(live.sum()), live.size
@@ -347,8 +383,8 @@ def subtile_counts(t, kind=False, block_q=None, block_k=None):
 def _keep_mask(q0, k0, size_q, size_k, causal, kv_len, t_pad):
     """The validity mask of the ``size_q x size_k`` scores whose first
     query and key sit at ``q0`` and ``k0``, or None where every entry is
-    valid. ``causal`` is the mask kind: ``False``, ``True`` or a
-    ``BlockDiffusionMask``.
+    valid. ``causal`` is the mask kind: ``False``, ``True`` or a mask
+    object.
 
     Raggedness is judged against the PADDED length, not ``block_k``
     alone: with block_q != block_k the lcm rounding can append
@@ -1031,7 +1067,7 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    mask: Optional[BlockDiffusionMask] = None):
+                    mask=None):
     """Flash attention on ``[batch, seq, heads, head_dim]`` tensors.
 
     Uses the Pallas TPU kernels (forward AND backward — safe inside
@@ -1043,8 +1079,9 @@ def flash_attention(q, k, v, causal: bool = False,
 
     Mask kinds: none, ``causal=True``, or ``mask=`` a
     :class:`BlockDiffusionMask` over a sequence of ``2 * mask.seq``
-    positions (not with ``causal``). A ``mask`` is never sent down the
-    dense path for its shapes: what the kernels cannot take raises."""
+    positions or a :class:`SlidingWindowMask` (not with ``causal``). A
+    ``mask`` is never sent down the dense path for its shapes: what the
+    kernels cannot take raises."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     from bluefog_tpu.ops.attention import reference_attention
@@ -1055,11 +1092,12 @@ def flash_attention(q, k, v, causal: bool = False,
     if mask is not None:
         if causal:
             raise ValueError("give causal=True or a mask, not both")
-        if not supported or q.shape[1] != 2 * mask.seq:
+        doubled = isinstance(mask, BlockDiffusionMask)
+        if not supported or (doubled and q.shape[1] != 2 * mask.seq):
+            over = f"2 * seq = {2 * mask.seq} positions" if doubled else "one sequence"
             raise ValueError(
-                f"{mask} needs self-attention over 2 * seq = "
-                f"{2 * mask.seq} positions, got q {q.shape}, k {k.shape}, "
-                f"v {v.shape}: refused rather than run densely"
+                f"{mask} needs self-attention over {over}, got q {q.shape}, "
+                f"k {k.shape}, v {v.shape}: refused rather than run densely"
             )
         causal = mask  # the kernels' one static mask kind
     if not supported:

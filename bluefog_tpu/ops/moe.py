@@ -331,35 +331,58 @@ def _twice_bwd(tm, tiles_used, ds):
 _twice.defvjp(_twice_fwd, _twice_bwd)
 
 
-def _silu_times(gate, up):
-    return (
-        jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    ).astype(gate.dtype)
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = tuple(_ACTIVATIONS)
 
 
-def _act_turn(tm, first, _, gate, up):
-    return (_silu_times(gate, up),)
+@functools.lru_cache(maxsize=None)
+def _turns(activation):
+    """The forward and backward turns of ``_activation`` under
+    ``activation``: one pair an activation, so ``_body`` traces each once.
+    Under relu the forward turn also counts, a row at a time, the entries
+    whose ReLU is 0."""
+    fn = _ACTIVATIONS[activation]
+
+    def times(gate, up):
+        return (
+            fn(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        ).astype(gate.dtype)
+
+    def turn(tm, first, *blocks):
+        gate, up = blocks[-2:]
+        if activation == "silu":
+            return (times(gate, up),)
+        return times(gate, up), jnp.sum(gate <= 0, axis=1, dtype=jnp.int32)
+
+    def bwd_turn(tm, first, gate, up, d_act):
+        return jax.vjp(times, gate, up)[1](d_act)
+
+    return turn, bwd_turn
 
 
-def _act_bwd_turn(tm, first, gate, up, d_act):
-    return jax.vjp(_silu_times, gate, up)[1](d_act)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _activation(activation, tm, gate, up, tiles_used):
+    """``(act(gate) * up,)`` in float32, back in the products' dtype, over
+    the tiles in use; its derivative is autodiff's own, a chunk at a time.
+    Under relu the tuple also holds each row's count of zeros of
+    ``relu(gate)`` (int32 ``[rows]``, read in the same pass; a row past the
+    tiles in use holds whatever was there)."""
+    bufs = (_smeared(gate.shape, gate),)
+    if activation == "relu":
+        bufs += (_smeared(gate.shape[:1], tiles_used),)
+    return _over_tiles_in_use(tm, tiles_used, _turns(activation)[0], bufs, (gate, up))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _activation(tm, gate, up, tiles_used):
-    """``silu(gate) * up`` in float32, back in the products' dtype, over
-    the tiles in use; its derivative is autodiff's own, a chunk at a time."""
-    act = _smeared(gate.shape, gate)
-    return _over_tiles_in_use(tm, tiles_used, _act_turn, (act,), (gate, up))[0]
+def _activation_fwd(activation, tm, gate, up, tiles_used):
+    return _activation(activation, tm, gate, up, tiles_used), (gate, up, tiles_used)
 
 
-def _activation_fwd(tm, gate, up, tiles_used):
-    return _activation(tm, gate, up, tiles_used), (gate, up, tiles_used)
-
-
-def _activation_bwd(tm, res, d_act):
+def _activation_bwd(activation, tm, res, cts):
     *gate_up, tiles_used = res
-    return *_over_tiles_in_use(tm, tiles_used, _act_bwd_turn, gate_up, (d_act,)), None
+    d_act = cts[0]  # relu's counts have no cotangent
+    return *_over_tiles_in_use(
+        tm, tiles_used, _turns(activation)[1], gate_up, (d_act,)
+    ), None
 
 
 _activation.defvjp(_activation_fwd, _activation_bwd)
@@ -580,7 +603,7 @@ grouped_product.defvjp(_grouped_product_fwd, _grouped_product_bwd)
 
 
 def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
-                 held_start=0, dtype=None, interpret=False):
+                 held_start=0, dtype=None, activation="silu", interpret=False):
     """This chip's experts' part of a sparse-expert feed-forward layer.
 
     ``u [tokens, hidden]``; ``weights`` / ``experts`` ``[tokens, k]`` from
@@ -590,8 +613,9 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
     held_start + experts_held - 1``. Returns ``(y, counts)``:
 
     ``y [tokens, hidden]`` in ``dtype`` (default ``u``'s) is ``sum over the
-    token's choices e that are held of weight_e * down_e(silu(gate_e(u)) *
-    up_e(u))``; products in ``dtype``, accumulated in float32.
+    token's choices e that are held of weight_e * down_e(act(gate_e(u)) *
+    up_e(u))``; products in ``dtype``, accumulated in float32; ``act`` is
+    ``activation``, ``"silu"`` or ``"relu"`` (ReGLU), in float32.
 
     One path at any imbalance, up to every choice of every token landing
     here: the buffer holds all ``tokens x k`` pairs (and a tile of slack a
@@ -603,8 +627,12 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
     [experts_held]`` (pairs that landed on each held expert),
     ``rows_absent`` (pairs whose expert is another chip's) and
     ``rows_dropped`` (landed pairs that reached no group: the buffer holds
-    every pair, so this is 0 by construction and says so).
+    every pair, so this is 0 by construction and says so); under ``"relu"``
+    also ``act_zeros``, the entries of the landed rows' ``relu(gate_e(u))``
+    that are 0 (of ``landed rows x width``: the sparsity ReGLU leaves).
     """
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r}: one of {ACTIVATIONS}")
     dtype = u.dtype if dtype is None else dtype
     n, k = experts.shape
     held = w_gate.shape[0]
@@ -662,9 +690,17 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
             )
 
         xs_gate, xs_up = _twice(tm, xs, tiles_used)
-        act = _activation(
-            tm, product(xs_gate, w_gate), product(xs_up, w_up), tiles_used
+        act, *zeros_of_rows = _activation(
+            activation, tm, product(xs_gate, w_gate), product(xs_up, w_up),
+            tiles_used,
         )
+        if activation == "relu":
+            # the rows that hold a landed pair: a tile's first `tile_rows`
+            holds_pair = jnp.arange(tm) < tile_rows[:, None]
+            act_zeros = jnp.sum(
+                jnp.where(holds_pair.reshape(-1), zeros_of_rows[0], 0),
+                dtype=jnp.int32,
+            )
         out = product(act, w_down)
     with jax.named_scope("bf.moe.combine"):
         y = _combine(tm, out, weights, moves).astype(dtype)
@@ -674,4 +710,6 @@ def expert_layer(u, weights, experts, w_gate, w_up, w_down, *,
         "rows_absent": rows_absent,
         "rows_dropped": rows_dropped,
     }
+    if activation == "relu":
+        counts["act_zeros"] = act_zeros
     return y, counts

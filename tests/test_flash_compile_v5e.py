@@ -24,17 +24,20 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bluefog_tpu.ops import flash
-from bluefog_tpu.ops.flash import BlockDiffusionMask
+from bluefog_tpu.ops.flash import BlockDiffusionMask, SlidingWindowMask
 
 pytestmark = pytest.mark.slow
 
 # mask kind, batch, positions, query heads, key-value heads, live tiles, the
-# length of the sub-tile runs' table, and the `copy` / `copy-start`
-# instructions of a tensor (f16631d: the layouts into and out of the folded
-# [batch x heads, positions, 128], operands moved between memory spaces)
+# length of the sub-tile runs' table, the `copy` / `copy-start` instructions
+# of the tables into scalar memory, and those of a tensor (f16631d: the
+# layouts into and out of the folded [batch x heads, positions, 128],
+# operands moved between memory spaces)
 SHAPES = {
-    "sdar30b_1chip_b2": (BlockDiffusionMask(4096, 4), 2, 8192, 32, 4, 24, 8, 7),
-    "mistral4_1chip_b1": (True, 1, 4096, 32, 32, 10, 5, 10),
+    "sdar30b_1chip_b2": (BlockDiffusionMask(4096, 4), 2, 8192, 32, 4, 24, 8, 9, 7),
+    "mistral4_1chip_b1": (True, 1, 4096, 32, 32, 10, 5, 9, 10),
+    # the window layers: 70 of 256 tiles, 7 query heads a key-value head
+    "smallthinker_1chip_seq16k": (SlidingWindowMask(4096), 1, 16384, 28, 4, 70, 9, 0, 6),
 }
 
 
@@ -52,7 +55,7 @@ def one_chip():
 
 @pytest.mark.parametrize("cell", SHAPES)
 def test_the_live_tile_grids_compile_for_the_v5e(one_chip, cell):
-    kind, b, t, h, hkv, live, subs, tensor_copies = SHAPES[cell]
+    kind, b, t, h, hkv, live, subs, table_copies, tensor_copies = SHAPES[cell]
     assert flash.grid_steps(t, kind) == live  # the list, not the rectangle
 
     def loss(q, k, v):
@@ -84,7 +87,9 @@ def test_the_live_tile_grids_compile_for_the_v5e(one_chip, cell):
     assert {int(m) for _, m in tables} == {subs}
     # the tables are copied into scalar memory (the forward and the dQ
     # kernel read one set, the dK/dV kernel its own four beside the runs
-    # all three share); no tensor is, that was not
+    # all three share; at the window's shape XLA copies none); no tensor
+    # is, that was not
     copies = re.findall(r"= \(?(\w+)\[[\d,]+\].* copy(?:-start)?\(", text)
-    assert copies.count("s32") == 9 and copies.count("bf16") == tensor_copies, copies
-    assert len(copies) == 9 + tensor_copies, copies
+    assert copies.count("s32") == table_copies, copies
+    assert copies.count("bf16") == tensor_copies, copies
+    assert len(copies) == table_copies + tensor_copies, copies

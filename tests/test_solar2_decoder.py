@@ -192,7 +192,7 @@ def test_the_four_head_shares_add_up_to_the_uncut_layer(kind):
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 40, 32))
     pos = jnp.arange(40)
     if kind == "softmax":
-        module = lambda cfg: decoder.Attention(cfg)
+        module = lambda cfg: decoder.Attention(cfg, cfg.use_rope)
         call = lambda m, p: m.apply({"params": p}, x, pos, "causal")
     else:
         module = lambda cfg: decoder.DeltaAttention(cfg)
@@ -261,7 +261,7 @@ def test_settings_the_stack_cannot_build_are_refused(change, message):
 
 def test_linear_layers_refuse_a_mask_other_than_causal():
     cfg = whole()
-    layer = decoder.DecoderLayer(cfg, "linear")
+    layer = decoder.DecoderLayer(cfg, "linear", rope=False)
     x = jnp.zeros((1, 8, 32))
     with pytest.raises(ValueError, match="linear attention is causal"):
         layer.init(jax.random.PRNGKey(0), x, jnp.arange(8), None)
