@@ -26,23 +26,30 @@ where the ``u`` solve a unit lower-triangular system::
 
 Two parts, split where the work is parallel and where it is sequential:
 
-- **Inside a chunk** (``_intra``, plain XLA over every chunk at once, and
-  differentiated by jax): ``G``, ``A``, ``P``, the triangular solve and the
+- **Inside a chunk**: ``G``, ``A``, ``P``, the triangular solve and the
   four decayed operands. No factor is ever ``e^{+G}``: ``e^{G_r}`` is at
   most 1, and ``e^{G_r - G_s}`` for ``s <= r`` is built from the difference
-  of the exponents — inside a sub-chunk of ``SUB`` tokens elementwise
-  (``_diag_dots``, whose backward is written out so that the ``[SUB, SUB,
-  dk]`` exponentials stay inside their fusions), between sub-chunks as a
-  product of two factors that both decay, through the cumulative decay at
-  the start of the row's sub-chunk. Nothing is clamped: at a decay of -1.6
-  a token (``A`` = 16, ``dt`` = 0.1) the factorised ``(q e^{G}) (k
-  e^{-G})^T`` of a chunk of 64 would need ``e^{102}``, past float32.
-- **Across chunks** (the recurrence in ``S``): one Pallas kernel,
-  ``bf_delta_fwd``, grid (batch x heads, chunks), chunks in order, the state
-  in VMEM, float32; it writes the state entering every chunk for the
-  backward kernel ``bf_delta_bwd``, which walks the chunks in reverse with
-  ``dS`` in VMEM and gives the cotangents of the chunk operands; jax's
-  derivative of ``_intra`` turns those into ``dq, dk, dv, dg, dbeta``.
+  of the exponents — inside a sub-chunk of ``SUB`` tokens elementwise,
+  between sub-chunks as a product of two factors that both decay, through
+  the cumulative decay at the start of the row's sub-chunk. Nothing is
+  clamped: at a decay of -1.6 a token (``A`` = 16, ``dt`` = 0.1) the
+  factorised ``(q e^{G}) (k e^{-G})^T`` of a chunk of 64 would need
+  ``e^{102}``, past float32. Where it runs: **on the TPU the forward kernel
+  builds them itself**, a chunk at a time in VMEM (``_chunk_operands``:
+  ``G`` as a product with a triangle of ones, the pair products a row block
+  of ``SUB`` at a time, the solve by block forward substitution), from the
+  folded ``q, k, v, g`` and ``beta``; the backward pass builds them again
+  in plain XLA over every chunk at once (``_intra``) and differentiates
+  that with jax (``_diag_dots``' backward is written out so that the
+  ``[SUB, SUB, dk]`` exponentials stay inside their fusions). Off the TPU
+  ``_intra`` serves both passes.
+- **Across chunks** (the recurrence in ``S``): the Pallas kernel
+  ``bf_delta_fwd``, grid (batch x heads ÷ ``STEP_HEADS``, chunks), chunks in
+  order, the states in VMEM, float32; it writes the state entering every
+  chunk for the backward kernel ``bf_delta_bwd``, which walks the chunks in
+  reverse with ``dS`` in VMEM and gives the cotangents of the chunk
+  operands; jax's derivative of ``_intra`` turns those into ``dq, dk, dv,
+  dg, dbeta``.
 
 Everything is float32, matrix products at ``Precision.HIGHEST``. Off the TPU
 the same two recurrences run as ``lax.scan`` (``_fwd_scan`` /
@@ -53,6 +60,7 @@ does not divide is padded with steps that change nothing (``k = v = 0``,
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +71,11 @@ __all__ = ["chunk_gated_delta", "gated_delta_reference", "chunk_count"]
 
 CHUNK = 64
 SUB = 16  # tokens whose mutual decays are built elementwise; divides CHUNK
+# heads a step of the forward kernel takes at most: a chunk's operands are
+# chains of small dependent products, and several heads' chains side by side
+# keep the core busy (one layer of the solar2 cell on a v5e: 3.10 ms with
+# one head a step, 1.87 with four, 1.82 with eight)
+STEP_HEADS = 4
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -158,9 +171,9 @@ def _pair_products(x, y, gb, strict):
 
 
 def _intra(q, k, v, g, beta):
-    """The chunk operands of the scan, from ``[BH, N, C, d]`` inputs and
-    ``beta [BH, N, C]``: ``q e^G``, ``W``, ``U~``, ``P``, ``k e^{G_C -
-    G}`` and ``e^{G_C} [BH, N, 1, dk]``."""
+    """The chunk operands of the scan, every chunk at once in XLA, from
+    ``[BH, N, C, d]`` inputs and ``beta [BH, N, C]``: ``q e^G``, ``W``,
+    ``U~``, ``P``, ``k e^{G_C - G}`` and ``e^{G_C} [BH, N, 1, dk]``."""
     bh, n, c, dk = q.shape
     m = c // SUB
     gcum = jnp.cumsum(g, axis=2)
@@ -183,8 +196,11 @@ def _intra(q, k, v, g, beta):
 
 
 def _mm(a, b, contract=(1, 0)):
+    """A matrix product over the last two axes (``contract`` counts from
+    the first of them), batched over any leading ones."""
+    lead = tuple(range(a.ndim - 2))
     return jax.lax.dot_general(
-        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        a, b, (((contract[0] + len(lead),), (contract[1] + len(lead),)), (lead, lead)),
         precision=_HI, preferred_element_type=jnp.float32,
     )
 
@@ -193,7 +209,7 @@ def _chunk_forward(z, qt, w, ut, p, kh, gam):
     """One chunk: the state entering it ``z`` -> ``(o, z after)``."""
     u = ut - _mm(w, z, (1, 1))
     o = _mm(qt, z, (1, 1)) + _mm(p, u)
-    return o, z * gam + _mm(u.T, kh)
+    return o, z * gam + _mm(jnp.swapaxes(u, -1, -2), kh)
 
 
 def _chunk_backward(z, dz, qt, w, ut, p, kh, gam, do):
@@ -240,21 +256,73 @@ def _bwd_scan(qt, w, ut, p, kh, gam, z, do):
     return tuple(jnp.moveaxis(a, 0, 1) for a in grads)
 
 
+# -- inside a chunk, in VMEM: ``_intra`` for the forward kernel --------------------
+
+
+def _chunk_operands(q, k, v, g, beta):
+    """``_intra`` for one chunk of each of a few heads: ``q, k, v, g [H, C,
+    d]``, ``beta [H, 1, C]`` -> ``(q e^G, W, U~, P, k e^{G_C - G}, e^{G_C}
+    [H, 1, dk])``, with the same exactness rule, a row block of ``SUB``
+    tokens at a time. The solve is block forward substitution: ``X_i = (I +
+    A_ii)^{-1} (Y_i - A_i,<i X_<i)``, the block's own rows eliminated one at
+    a time (a row of ``X`` is final once the rows above it are subtracted)."""
+    heads, c, dk = q.shape
+    m = c // SUB
+    f32 = jnp.float32
+    iota = lambda shape, dim: jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+    row, col = iota((c, c), 0), iota((c, c), 1)
+    tri = jnp.broadcast_to((col <= row).astype(f32), (heads, c, c))
+    gcum = _mm(tri, g)  # the cumulative decay on the MXU
+    last = gcum[:, c - 1:]
+    beta_col = jnp.sum(jnp.where(row == col, beta, 0.0), axis=2, keepdims=True)
+    y = beta_col * jnp.concatenate([k * jnp.exp(gcum), v], axis=2)
+    tok, sub, lane = iota((c, dk), 0), iota((SUB, dk), 0), iota((SUB, c), 1)
+    p_rows, xs = [], []
+    for i in range(m):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        qb, kb, gb, bb = q[:, rows], k[:, rows], gcum[:, rows], beta_col[:, rows]
+        pb, z = jnp.zeros((heads, SUB, c), f32), y[:, rows]
+        if i:  # the sub-chunks before: two factors that both decay, through R_i
+            ref = gcum[:, i * SUB - 1:i * SUB]
+            left = jnp.exp(gb - ref)
+            right = k * jnp.exp(jnp.where(tok < i * SUB, ref - gcum, -jnp.inf))
+            off = _mm(jnp.concatenate([qb * left, kb * left], axis=1), right, (1, 1))
+            # Y_i - A_i,<i X_<i: A_i,<i is beta times the keys' rows of off
+            done = jnp.concatenate(xs + [jnp.zeros((heads, (m - i) * SUB, 2 * dk), f32)], axis=1)
+            pb, z = off[:, :SUB], z - _mm(bb * off[:, SUB:], done)
+        a_cols = []
+        for j in range(SUB):  # the diagonal block, a column s at a time
+            s = i * SUB + j
+            e = jnp.exp(jnp.where(sub >= j, gb - gcum[:, s:s + 1], -jnp.inf)) * k[:, s:s + 1]
+            pb = jnp.where(lane == s, jnp.sum(qb * e, axis=2, keepdims=True), pb)
+            a = bb * jnp.sum(kb * e, axis=2, keepdims=True)  # A[., s] over the block
+            a_cols.append(jnp.where(sub[:, :1] > j, a, 0.0))
+        for j in range(SUB - 1):
+            z = z - a_cols[j] * z[:, j:j + 1]
+        p_rows.append(pb)
+        xs.append(z)
+    x = jnp.concatenate(xs, axis=1)
+    return (
+        q * jnp.exp(gcum), x[..., :dk], x[..., dk:], jnp.concatenate(p_rows, axis=1),
+        k * jnp.exp(last - gcum), jnp.exp(last),
+    )
+
+
 # -- the kernels -------------------------------------------------------------------
 
 
-def _fwd_kernel(qt_ref, w_ref, ut_ref, p_ref, kh_ref, gam_ref, o_ref, zs_ref, z_ref):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, zs_ref, z_ref):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         z_ref[:] = jnp.zeros_like(z_ref)
 
     z = z_ref[:]
-    zs_ref[0, 0] = z
-    o, z_next = _chunk_forward(
-        z, qt_ref[0, 0], w_ref[0, 0], ut_ref[0, 0], p_ref[0, 0], kh_ref[0, 0],
-        gam_ref[0, 0],
+    zs_ref[:, 0] = z
+    operands = _chunk_operands(
+        q_ref[:, 0], k_ref[:, 0], v_ref[:, 0], g_ref[:, 0], beta_ref[:, 0]
     )
-    o_ref[0, 0] = o
+    o, z_next = _chunk_forward(z, *operands)
+    o_ref[:, 0] = o
     z_ref[:] = z_next
 
 
@@ -265,36 +333,38 @@ def _bwd_kernel(qt_ref, w_ref, ut_ref, p_ref, kh_ref, gam_ref, zs_ref, do_ref,
         dz_ref[:] = jnp.zeros_like(dz_ref)
 
     dz_in, *grads = _chunk_backward(
-        zs_ref[0, 0], dz_ref[:], qt_ref[0, 0], w_ref[0, 0], ut_ref[0, 0],
+        zs_ref[0, 0], dz_ref[0], qt_ref[0, 0], w_ref[0, 0], ut_ref[0, 0],
         p_ref[0, 0], kh_ref[0, 0], gam_ref[0, 0], do_ref[0, 0],
     )
     for ref, grad in zip(
         (dqt_ref, dw_ref, dut_ref, dp_ref, dkh_ref, dgam_ref), grads
     ):
         ref[0, 0] = grad
-    dz_ref[:] = dz_in
+    dz_ref[0] = dz_in
 
 
-def _call(kernel, name, inputs, outputs, reverse, interpret):
-    """``pallas_call`` over (batch x heads, chunks), one chunk's block of
-    every ``[BH, N, a, b]`` operand a step, the chunks in order (or in
-    reverse), one float32 ``[dv, dk]`` carry in VMEM."""
+def _call(kernel, name, inputs, outputs, reverse, interpret, heads=1):
+    """``pallas_call`` over (batch x heads ÷ ``heads``, chunks), one chunk's
+    block of every ``[BH, N, a, b]`` operand a step for ``heads`` heads, the
+    chunks in order (or in reverse), a float32 ``[dv, dk]`` carry a head in
+    VMEM."""
     bh, n, _, dk = inputs[0].shape
     dv = inputs[2].shape[-1]
     chunk = (lambda b, i: (b, n - 1 - i, 0, 0)) if reverse else (
         lambda b, i: (b, i, 0, 0)
     )
-    spec = lambda shape: pl.BlockSpec((1, 1, *shape[2:]), chunk)
+    spec = lambda shape: pl.BlockSpec((heads, 1, *shape[2:]), chunk)
+
     vma = jax.typeof(inputs[0]).vma
     return pl.pallas_call(
         kernel,
-        grid=(bh, n),
+        grid=(bh // heads, n),
         in_specs=[spec(x.shape) for x in inputs],
         out_specs=tuple(spec(s) for s in outputs),
         out_shape=tuple(
             jax.ShapeDtypeStruct(s, jnp.float32, vma=vma) for s in outputs
         ),
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
@@ -303,13 +373,18 @@ def _call(kernel, name, inputs, outputs, reverse, interpret):
     )(*inputs)
 
 
-def _fwd_call(qt, w, ut, p, kh, gam, interpret=False):
-    bh, n, c, dk = qt.shape
-    dv = ut.shape[-1]
+def _fwd_call(q, k, v, g, beta, interpret=False):
+    bh, n, c, dk = q.shape
+    dv = v.shape[-1]
     return _call(
-        _fwd_kernel, "bf_delta_fwd", (qt, w, ut, p, kh, gam),
+        _fwd_kernel, "bf_delta_fwd", (q, k, v, g, beta[:, :, None]),
         ((bh, n, c, dv), (bh, n, dv, dk)), False, interpret,
+        heads=math.gcd(bh, STEP_HEADS),
     )
+
+
+def _fwd_xla(q, k, v, g, beta):
+    return _fwd_scan(*_intra(q, k, v, g, beta))
 
 
 def _bwd_call(qt, w, ut, p, kh, gam, z, do, interpret=False):
@@ -335,7 +410,7 @@ def _delta(q, k, v, g, beta, interpret):
 
 
 def _delta_fwd(q, k, v, g, beta, interpret):
-    o, z = _on_platform(_fwd_call, _fwd_scan, interpret, *_intra(q, k, v, g, beta))
+    o, z = _on_platform(_fwd_call, _fwd_xla, interpret, q, k, v, g, beta)
     return o, (q, k, v, g, beta, z)
 
 
@@ -349,19 +424,21 @@ def _delta_bwd(interpret, res, do):
 _delta.defvjp(_delta_fwd, _delta_bwd)
 
 
+def _fold(x):
+    """``[b, t, h, ...]`` -> ``[b * h, N, C, ...]`` float32, the length padded
+    to whole chunks with steps that change nothing."""
+    b, t, h = x.shape[:3]
+    n = chunk_count(t)
+    x = jnp.pad(x.astype(jnp.float32), [(0, 0), (0, n * CHUNK - t)] + [(0, 0)] * (x.ndim - 2))
+    x = jnp.moveaxis(x, 2, 1)  # [b, h, t_pad, ...]
+    return x.reshape(b * h, n, CHUNK, *x.shape[3:])
+
+
 def chunk_gated_delta(q, k, v, g, beta, *, interpret=False):
     """The gated delta rule over ``q, k, g [b, t, h, dk]``, ``v [b, t, h,
     dv]``, ``beta [b, t, h]`` -> ``o [b, t, h, dv]`` float32 (the module's
     header has the equations); differentiable in all five."""
-    b, t, h, dk = q.shape
-    n = chunk_count(t)
-    pad = n * CHUNK - t
-
-    def fold(x):
-        x = jnp.pad(x.astype(jnp.float32), [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        x = jnp.moveaxis(x, 2, 1)  # [b, h, t_pad, ...]
-        return x.reshape(b * h, n, CHUNK, *x.shape[3:])
-
-    o = _delta(*map(fold, (q, k, v, g, beta)), interpret)
-    o = o.reshape(b, h, n * CHUNK, -1)[:, :, :t]
+    b, t, h, _ = q.shape
+    o = _delta(*map(_fold, (q, k, v, g, beta)), interpret)
+    o = o.reshape(b, h, -1, o.shape[-1])[:, :, :t]
     return jnp.moveaxis(o, 1, 2)

@@ -68,6 +68,41 @@ def test_all_five_gradients_match_the_token_recurrence(name, beta_range, decay_r
         close(a, b, 5e-5)
 
 
+@pytest.mark.parametrize("t", [128, 100])
+@pytest.mark.parametrize("name, beta_range, decay_range", CASES, ids=[c[0] for c in CASES])
+def test_the_fused_forward_kernel_matches_the_xla_chunk_operands(name, beta_range, decay_range, t):
+    """``bf_delta_fwd`` builds its chunk operands itself (in VMEM on the
+    chip, here in the interpreter): the same ``o`` and the same states
+    entering each chunk as the CPU's ``_fwd_scan(*_intra(...))``."""
+    folded = [delta._fold(x) for x in inputs(5, t, beta_range, decay_range)]
+    o, z = jax.jit(lambda *a: delta._fwd_call(*a, interpret=True))(*folded)
+    want_o, want_z = jax.jit(delta._fwd_xla)(*folded)
+    assert o.shape == want_o.shape and z.shape == want_z.shape == (B * H, 2, D, D)
+    close(o, want_o, 2e-5)
+    close(z, want_z, 2e-5)
+
+
+def test_the_kernels_solve_holds_where_every_key_is_the_same():
+    """The in-VMEM solve (block forward substitution) at the worst-conditioned
+    system a chunk can hold: one key repeated, ``beta`` = 2, almost no decay.
+    Against the float64 solve, no further off than XLA's triangular solve."""
+    c = delta.CHUNK
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    k = jnp.broadcast_to(jax.random.normal(ks[0], (1, D)), (c, D))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = jax.random.normal(ks[1], (c, D)) / 4
+    v = jax.random.normal(ks[2], (c, D))
+    for decay, b in ((0.0, 2.0), (0.01, 2.0), (0.05, 1.99)):
+        g, beta = jnp.full((c, D), -decay), jnp.full((1, 1, c), b)
+        _, w, ut, *_ = jax.jit(delta._chunk_operands)(q[None], k[None], v[None], g[None], beta)
+        kk, vv, gcum = (np.asarray(x, np.float64) for x in (k, v, np.cumsum(np.asarray(g, np.float64), 0)))
+        seen = np.arange(c)[:, None] > np.arange(c)[None, :]
+        a = b * np.where(seen, (kk @ kk.T) * np.exp(-decay * (np.arange(c)[:, None] - np.arange(c)[None, :])), 0)
+        x = np.linalg.solve(np.eye(c) + a, b * np.concatenate([kk * np.exp(gcum), vv], axis=1))
+        close(w[0], x[:, :D], 1e-4)
+        close(ut[0], x[:, D:], 1e-4)
+
+
 def test_no_output_sees_a_later_token():
     args = inputs(3, 130, (0.0, 2.0), (0.0, 1.6))
     base = delta.chunk_gated_delta(*args)
