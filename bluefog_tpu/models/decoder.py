@@ -126,7 +126,10 @@ kind); with a shared expert ``bluefog.moe.shared_rows``
 softmax layer holds); with linear attention ``bluefog.delta.chunks`` (chunk
 steps of a forward pass: batch x heads held x chunks x linear layers),
 ``bluefog.delta.fused_fwd_chunks`` (those of them whose operands the forward
-kernel builds in VMEM: all on the TPU, none elsewhere) and
+kernel builds in VMEM: all on the TPU, none elsewhere),
+``bluefog.delta.fused_bwd_chunks`` (those of a backward pass whose in-chunk
+derivative the backward kernel takes in VMEM: all on the TPU, none
+elsewhere) and
 ``bluefog.delta.state_bytes`` (the float32 states entering each chunk that
 forward pass keeps for the backward one). The device's own counts come
 back beside the hidden states (``counts``), for the caller to return
@@ -919,9 +922,9 @@ def _record_static_counts(cfg, batch, positions, mask):
             * kinds.count("linear")
         )
         metrics_mod.gauge("bluefog.delta.chunks").set(chunks)
-        metrics_mod.gauge("bluefog.delta.fused_fwd_chunks").set(
-            chunks if jax.default_backend() == "tpu" else 0
-        )
+        fused = chunks if jax.default_backend() == "tpu" else 0
+        metrics_mod.gauge("bluefog.delta.fused_fwd_chunks").set(fused)
+        metrics_mod.gauge("bluefog.delta.fused_bwd_chunks").set(fused)
         metrics_mod.gauge("bluefog.delta.state_bytes").set(
             chunks * cfg.linear_head_dim ** 2 * 4
         )

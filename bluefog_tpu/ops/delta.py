@@ -34,22 +34,22 @@ Two parts, split where the work is parallel and where it is sequential:
   the cumulative decay at the start of the row's sub-chunk. Nothing is
   clamped: at a decay of -1.6 a token (``A`` = 16, ``dt`` = 0.1) the
   factorised ``(q e^{G}) (k e^{-G})^T`` of a chunk of 64 would need
-  ``e^{102}``, past float32. Where it runs: **on the TPU the forward kernel
-  builds them itself**, a chunk at a time in VMEM (``_chunk_operands``:
-  ``G`` as a product with a triangle of ones, the pair products a row block
-  of ``SUB`` at a time, the solve by block forward substitution), from the
-  folded ``q, k, v, g`` and ``beta``; the backward pass builds them again
-  in plain XLA over every chunk at once (``_intra``) and differentiates
-  that with jax (``_diag_dots``' backward is written out so that the
-  ``[SUB, SUB, dk]`` exponentials stay inside their fusions). Off the TPU
-  ``_intra`` serves both passes.
+  ``e^{102}``, past float32. Where it runs: **on the TPU both kernels
+  build them themselves**, a chunk at a time in VMEM from the folded ``q,
+  k, v, g`` and ``beta`` (``_chunk_build``: ``G`` as a product with a
+  triangle of ones, the pair products a row block of ``SUB`` at a time, the
+  solve by block forward substitution), and the backward kernel takes their
+  derivative there too (``_chunk_grads``: the solve's by block back
+  substitution, the pair products' by ``_diag_dots_bwd``'s rule, the
+  cumulative sum's as a product with the transposed triangle). Off the TPU
+  ``_intra`` builds them in plain XLA over every chunk at once and jax
+  differentiates it (``_diag_dots``' backward is written out so that the
+  ``[SUB, SUB, dk]`` exponentials stay inside their fusions).
 - **Across chunks** (the recurrence in ``S``): the Pallas kernel
   ``bf_delta_fwd``, grid (batch x heads ÷ ``STEP_HEADS``, chunks), chunks in
   order, the states in VMEM, float32; it writes the state entering every
   chunk for the backward kernel ``bf_delta_bwd``, which walks the chunks in
-  reverse with ``dS`` in VMEM and gives the cotangents of the chunk
-  operands; jax's derivative of ``_intra`` turns those into ``dq, dk, dv,
-  dg, dbeta``.
+  reverse with ``dS`` in VMEM and gives ``dq, dk, dv, dg, dbeta``.
 
 Everything is float32, matrix products at ``Precision.HIGHEST``. Off the TPU
 the same two recurrences run as ``lax.scan`` (``_fwd_scan`` /
@@ -71,10 +71,11 @@ __all__ = ["chunk_gated_delta", "gated_delta_reference", "chunk_count"]
 
 CHUNK = 64
 SUB = 16  # tokens whose mutual decays are built elementwise; divides CHUNK
-# heads a step of the forward kernel takes at most: a chunk's operands are
-# chains of small dependent products, and several heads' chains side by side
-# keep the core busy (one layer of the solar2 cell on a v5e: 3.10 ms with
-# one head a step, 1.87 with four, 1.82 with eight)
+# heads a step of either kernel takes at most: a chunk's work is chains of
+# small dependent products, and several heads' chains side by side keep the
+# core busy (one layer of the solar2 cell on a v5e: the forward kernel 3.10
+# ms with one head a step, 1.87 with four, 1.82 with eight; the backward
+# 5.61 with two, 5.24 with four, and eight overflow the scoped VMEM)
 STEP_HEADS = 4
 _HI = jax.lax.Precision.HIGHEST
 
@@ -216,12 +217,13 @@ def _chunk_backward(z, dz, qt, w, ut, p, kh, gam, do):
     """One chunk in reverse: ``dz`` is the cotangent of the state leaving
     it -> ``(cotangent of the state entering it, dqt, dw, dut, dp, dkh,
     dgam)``."""
+    t = lambda a: jnp.swapaxes(a, -1, -2)
     u = ut - _mm(w, z, (1, 1))
-    du = _mm(p.T, do) + _mm(kh, dz, (1, 1))
-    dz_in = dz * gam + _mm(do.T, qt) - _mm(du.T, w)
+    du = _mm(t(p), do) + _mm(kh, dz, (1, 1))
+    dz_in = dz * gam + _mm(t(do), qt) - _mm(t(du), w)
     return (
         dz_in, _mm(do, z), -_mm(du, z), du, _mm(do, u, (1, 1)), _mm(u, dz),
-        jnp.sum(dz * z, axis=0, keepdims=True),
+        jnp.sum(dz * z, axis=-2, keepdims=True),
     )
 
 
@@ -256,32 +258,37 @@ def _bwd_scan(qt, w, ut, p, kh, gam, z, do):
     return tuple(jnp.moveaxis(a, 0, 1) for a in grads)
 
 
-# -- inside a chunk, in VMEM: ``_intra`` for the forward kernel --------------------
+# -- inside a chunk, in VMEM: ``_intra`` and its derivative for the kernels ----------
 
 
-def _chunk_operands(q, k, v, g, beta):
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _chunk_build(q, k, v, g, beta):
     """``_intra`` for one chunk of each of a few heads: ``q, k, v, g [H, C,
-    d]``, ``beta [H, 1, C]`` -> ``(q e^G, W, U~, P, k e^{G_C - G}, e^{G_C}
-    [H, 1, dk])``, with the same exactness rule, a row block of ``SUB``
-    tokens at a time. The solve is block forward substitution: ``X_i = (I +
-    A_ii)^{-1} (Y_i - A_i,<i X_<i)``, the block's own rows eliminated one at
-    a time (a row of ``X`` is final once the rows above it are subtracted)."""
+    d]``, ``beta [H, 1, C]`` -> ``(G, beta as a column [H, C, 1], P, X = [W |
+    U~], kk)``, with the same exactness rule, a row block of ``SUB`` tokens at
+    a time. ``kk`` holds ``sum_c k_rc k_sc e^{G_rc - G_sc}`` (``s < r``, no
+    ``beta``) as the solve made it, a row block each: the rows before the
+    block (``None`` in the first) and the block's own columns ``[H, SUB,
+    1]``. The solve is block forward substitution: ``X_i = (I + A_ii)^{-1}
+    (Y_i - A_i,<i X_<i)``, the block's own rows eliminated one at a time (a
+    row of ``X`` is final once the rows above it are subtracted)."""
     heads, c, dk = q.shape
     m = c // SUB
     f32 = jnp.float32
-    iota = lambda shape, dim: jax.lax.broadcasted_iota(jnp.int32, shape, dim)
-    row, col = iota((c, c), 0), iota((c, c), 1)
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
     tri = jnp.broadcast_to((col <= row).astype(f32), (heads, c, c))
     gcum = _mm(tri, g)  # the cumulative decay on the MXU
-    last = gcum[:, c - 1:]
     beta_col = jnp.sum(jnp.where(row == col, beta, 0.0), axis=2, keepdims=True)
     y = beta_col * jnp.concatenate([k * jnp.exp(gcum), v], axis=2)
-    tok, sub, lane = iota((c, dk), 0), iota((SUB, dk), 0), iota((SUB, c), 1)
-    p_rows, xs = [], []
+    tok, sub, lane = _iota((c, dk), 0), _iota((SUB, dk), 0), _iota((SUB, c), 1)
+    p_rows, xs, kk = [], [], []
     for i in range(m):
         rows = slice(i * SUB, (i + 1) * SUB)
         qb, kb, gb, bb = q[:, rows], k[:, rows], gcum[:, rows], beta_col[:, rows]
-        pb, z = jnp.zeros((heads, SUB, c), f32), y[:, rows]
+        pb, z, kk_off = jnp.zeros((heads, SUB, c), f32), y[:, rows], None
         if i:  # the sub-chunks before: two factors that both decay, through R_i
             ref = gcum[:, i * SUB - 1:i * SUB]
             left = jnp.exp(gb - ref)
@@ -289,22 +296,145 @@ def _chunk_operands(q, k, v, g, beta):
             off = _mm(jnp.concatenate([qb * left, kb * left], axis=1), right, (1, 1))
             # Y_i - A_i,<i X_<i: A_i,<i is beta times the keys' rows of off
             done = jnp.concatenate(xs + [jnp.zeros((heads, (m - i) * SUB, 2 * dk), f32)], axis=1)
-            pb, z = off[:, :SUB], z - _mm(bb * off[:, SUB:], done)
-        a_cols = []
+            pb, kk_off = off[:, :SUB], off[:, SUB:]
+            z = z - _mm(bb * kk_off, done)
+        kk_cols = []
         for j in range(SUB):  # the diagonal block, a column s at a time
             s = i * SUB + j
             e = jnp.exp(jnp.where(sub >= j, gb - gcum[:, s:s + 1], -jnp.inf)) * k[:, s:s + 1]
             pb = jnp.where(lane == s, jnp.sum(qb * e, axis=2, keepdims=True), pb)
-            a = bb * jnp.sum(kb * e, axis=2, keepdims=True)  # A[., s] over the block
-            a_cols.append(jnp.where(sub[:, :1] > j, a, 0.0))
+            kk_cols.append(jnp.where(sub[:, :1] > j, jnp.sum(kb * e, axis=2, keepdims=True), 0.0))
+        a_cols = [bb * a for a in kk_cols]  # A[., s] over the block
         for j in range(SUB - 1):
             z = z - a_cols[j] * z[:, j:j + 1]
         p_rows.append(pb)
         xs.append(z)
-    x = jnp.concatenate(xs, axis=1)
+        kk.append((kk_off, kk_cols))
+    return gcum, beta_col, jnp.concatenate(p_rows, axis=1), jnp.concatenate(xs, axis=1), kk
+
+
+def _decays(gcum):
+    """``(e^G, e^{G_C - G}, e^{G_C} [H, 1, dk])``."""
+    last = gcum[:, -1:]
+    return jnp.exp(gcum), jnp.exp(last - gcum), jnp.exp(last)
+
+
+def _chunk_operands(q, k, v, g, beta):
+    """The forward kernel's chunk operands (``_chunk_build``): ``(q e^G, W,
+    U~, P, k e^{G_C - G}, e^{G_C} [H, 1, dk])``."""
+    dk = q.shape[-1]
+    gcum, _, p, x, _ = _chunk_build(q, k, v, g, beta)
+    eg, ec, gam = _decays(gcum)
+    return q * eg, x[..., :dk], x[..., dk:], p, k * ec, gam
+
+
+def _chunk_grads(q, k, v, g, beta, z, dz, do):
+    """One chunk of each of a few heads in reverse, from its raw inputs:
+    ``z`` the states entering it, ``dz`` the cotangent of those leaving it
+    ``[H, dv, dk]``, ``do [H, C, dv]`` -> ``(cotangent of the states entering
+    it, dq, dk, dv, dg, dbeta [H, 1, C])``. The operands are built again
+    (``_chunk_build``), the recurrence's step taken back (``_chunk_backward``),
+    then ``_intra``'s derivative written out under the same exactness rule:
+    the solve's by block back substitution from the last row block up (``dY =
+    (I + A)^{-T} dX``), the pair products' by ``_diag_dots_bwd``'s rule
+    (between sub-chunks through ``R_i`` on the MXU, inside one from the
+    exponents' differences a column at a time), the cumulative decay's as a
+    product with the transposed triangle of ones."""
+    heads, c, dk = q.shape
+    m = c // SUB
+    f32 = jnp.float32
+    gcum, beta_col, p, x, kk = _chunk_build(q, k, v, g, beta)
+    eg, ec, gam = _decays(gcum)
+    qt, kh = q * eg, k * ec
+    dz_in, dqt, dw, dut, dp, dkh, dgam = _chunk_backward(
+        z, dz, qt, x[..., :dk], x[..., dk:], p, kh, gam, do
+    )
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    tok, sub, lane = _iota((c, dk), 0), _iota((SUB, dk), 0), _iota((SUB, c), 1)
+    # the keys' pair products without beta, and A, as [H, C, C]
+    mkk = []
+    for i, (kk_off, kk_cols) in enumerate(kk):
+        rows = jnp.zeros((heads, SUB, c), f32) if kk_off is None else kk_off
+        for j, a in enumerate(kk_cols):
+            rows = jnp.where(lane == i * SUB + j, a, rows)
+        mkk.append(rows)
+    mkk = jnp.concatenate(mkk, axis=1)
+    at = jnp.swapaxes(beta_col * mkk, -1, -2)
+    # dY = (I + A)^{-T} dX: unit upper triangular, from the last row up
+    dx = jnp.concatenate([dw, dut], axis=2)
+    dys = []
+    for i in reversed(range(m)):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        atb, zb = at[:, rows], dx[:, rows]  # atb[r', s] = A[s, r']
+        if dys:
+            done = jnp.concatenate([jnp.zeros((heads, (i + 1) * SUB, 2 * dk), f32)] + dys, axis=1)
+            zb = zb - _mm(atb, done)
+        a_rows = [
+            jnp.sum(jnp.where(lane == i * SUB + j, atb, 0.0), axis=2, keepdims=True)
+            for j in range(SUB)
+        ]
+        for j in reversed(range(1, SUB)):
+            zb = zb - a_rows[j] * zb[:, j:j + 1]
+        dys.insert(0, zb)
+    dy = jnp.concatenate(dys, axis=1)
+    da = -jnp.where(col < row, _mm(dy, x, (1, 1)), 0.0)
+    dmkk = beta_col * da
+    kg = k * eg
+    dbeta = (
+        jnp.sum(dy * jnp.concatenate([kg, v], axis=2), axis=2, keepdims=True)
+        + jnp.sum(da * mkk, axis=2, keepdims=True)
+    )
+    dkg = beta_col * dy[..., :dk]  # the cotangent of k e^G
+    # the pair products: P with dP (s <= r), the keys' with dM (s < r)
+    dxq, dxk, dyk_diag = [], [], []
+    dyk = jnp.zeros((heads, c, dk), f32)
+    for i in range(m):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        qb, kb, gb = q[:, rows], k[:, rows], gcum[:, rows]
+        dpb, dmb = dp[:, rows], dmkk[:, rows]
+        dq_b = dk_b = jnp.zeros((heads, SUB, dk), f32)
+        if i:  # the sub-chunks before, through R_i
+            ref = gcum[:, i * SUB - 1:i * SUB]
+            left = jnp.exp(gb - ref)
+            ex = jnp.exp(jnp.where(tok < i * SUB, ref - gcum, -jnp.inf))
+            both = jnp.concatenate([dpb, dmb], axis=1)
+            t = _mm(both, k * ex)
+            dq_b, dk_b = left * t[:, :SUB], left * t[:, SUB:]
+            dyk = dyk + ex * _mm(
+                jnp.swapaxes(both, -1, -2),
+                jnp.concatenate([qb * left, kb * left], axis=1),
+            )
+        dy_b = jnp.zeros((heads, SUB, dk), f32)
+        for j in range(SUB):  # the diagonal block, a column s at a time
+            s = i * SUB + j
+            e = jnp.exp(jnp.where(sub >= j, gb - gcum[:, s:s + 1], -jnp.inf))
+            cp = jnp.sum(jnp.where(lane == s, dpb, 0.0), axis=2, keepdims=True)
+            cm = jnp.sum(jnp.where(lane == s, dmb, 0.0), axis=2, keepdims=True)
+            ek = e * k[:, s:s + 1]
+            dq_b = dq_b + cp * ek
+            dk_b = dk_b + cm * ek
+            dy_b = jnp.where(
+                sub == j, jnp.sum((cp * qb + cm * kb) * e, axis=1, keepdims=True), dy_b
+            )
+        dxq.append(dq_b)
+        dxk.append(dk_b)
+        dyk_diag.append(dy_b)
+    dxq = jnp.concatenate(dxq, axis=1)
+    dxk = jnp.concatenate(dxk, axis=1)
+    dyk = dyk + jnp.concatenate(dyk_diag, axis=1)
+    # the decayed operands, then G = tri g taken back
+    dgcum = dkg * kg + q * dxq + k * (dxk - dyk) + dqt * qt - dkh * kh
+    # e^{G_C} and k e^{G_C - G} through G_C: added to the last row first
+    dlast = jnp.sum(dkh * kh, axis=1, keepdims=True) + dgam * gam
+    dgcum = dgcum + jnp.where(tok == c - 1, dlast, 0.0)
+    triu = jnp.broadcast_to((col >= row).astype(f32), (heads, c, c))
     return (
-        q * jnp.exp(gcum), x[..., :dk], x[..., dk:], jnp.concatenate(p_rows, axis=1),
-        k * jnp.exp(last - gcum), jnp.exp(last),
+        dz_in,
+        dqt * eg + dxq,
+        dkg * eg + dxk + dyk + dkh * ec,
+        beta_col * dy[..., dk:],
+        _mm(triu, dgcum),
+        jnp.sum(jnp.where(row == col, dbeta, 0.0), axis=1, keepdims=True),
     )
 
 
@@ -326,29 +456,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, zs_ref, z_ref):
     z_ref[:] = z_next
 
 
-def _bwd_kernel(qt_ref, w_ref, ut_ref, p_ref, kh_ref, gam_ref, zs_ref, do_ref,
-                dqt_ref, dw_ref, dut_ref, dp_ref, dkh_ref, dgam_ref, dz_ref):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, zs_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dz_ref):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         dz_ref[:] = jnp.zeros_like(dz_ref)
 
-    dz_in, *grads = _chunk_backward(
-        zs_ref[0, 0], dz_ref[0], qt_ref[0, 0], w_ref[0, 0], ut_ref[0, 0],
-        p_ref[0, 0], kh_ref[0, 0], gam_ref[0, 0], do_ref[0, 0],
+    dz_in, *grads = _chunk_grads(
+        q_ref[:, 0], k_ref[:, 0], v_ref[:, 0], g_ref[:, 0], beta_ref[:, 0],
+        zs_ref[:, 0], dz_ref[:], do_ref[:, 0],
     )
-    for ref, grad in zip(
-        (dqt_ref, dw_ref, dut_ref, dp_ref, dkh_ref, dgam_ref), grads
-    ):
-        ref[0, 0] = grad
-    dz_ref[0] = dz_in
+    for ref, grad in zip((dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref), grads):
+        ref[:, 0] = grad
+    dz_ref[:] = dz_in
 
 
-def _call(kernel, name, inputs, outputs, reverse, interpret, heads=1):
-    """``pallas_call`` over (batch x heads ÷ ``heads``, chunks), one chunk's
-    block of every ``[BH, N, a, b]`` operand a step for ``heads`` heads, the
-    chunks in order (or in reverse), a float32 ``[dv, dk]`` carry a head in
-    VMEM."""
+def _call(kernel, name, inputs, outputs, reverse, interpret):
+    """``pallas_call`` over (batch x heads ÷ ``STEP_HEADS``, chunks), one
+    chunk's block of every ``[BH, N, a, b]`` operand a step for ``STEP_HEADS``
+    heads (fewer where they do not divide ``BH``), the chunks in order (or in
+    reverse), a float32 ``[dv, dk]`` carry a head in VMEM."""
     bh, n, _, dk = inputs[0].shape
+    heads = math.gcd(bh, STEP_HEADS)
     dv = inputs[2].shape[-1]
     chunk = (lambda b, i: (b, n - 1 - i, 0, 0)) if reverse else (
         lambda b, i: (b, i, 0, 0)
@@ -379,7 +508,6 @@ def _fwd_call(q, k, v, g, beta, interpret=False):
     return _call(
         _fwd_kernel, "bf_delta_fwd", (q, k, v, g, beta[:, :, None]),
         ((bh, n, c, dv), (bh, n, dv, dk)), False, interpret,
-        heads=math.gcd(bh, STEP_HEADS),
     )
 
 
@@ -387,12 +515,19 @@ def _fwd_xla(q, k, v, g, beta):
     return _fwd_scan(*_intra(q, k, v, g, beta))
 
 
-def _bwd_call(qt, w, ut, p, kh, gam, z, do, interpret=False):
-    shapes = tuple(x.shape for x in (qt, w, ut, p, kh, gam))
-    return _call(
-        _bwd_kernel, "bf_delta_bwd", (qt, w, ut, p, kh, gam, z, do), shapes,
-        True, interpret,
+def _bwd_call(q, k, v, g, beta, z, do, interpret=False):
+    bh, n, c, _ = q.shape
+    shapes = tuple(x.shape for x in (q, k, v, g)) + ((bh, n, 1, c),)
+    *grads, dbeta = _call(
+        _bwd_kernel, "bf_delta_bwd", (q, k, v, g, beta[:, :, None], z, do),
+        shapes, True, interpret,
     )
+    return (*grads, dbeta.reshape(beta.shape))
+
+
+def _bwd_xla(q, k, v, g, beta, z, do):
+    operands, pull = jax.vjp(_intra, q, k, v, g, beta)
+    return pull(tuple(_bwd_scan(*operands, z, do)))
 
 
 def _on_platform(kernel, scan, interpret, *args):
@@ -415,10 +550,7 @@ def _delta_fwd(q, k, v, g, beta, interpret):
 
 
 def _delta_bwd(interpret, res, do):
-    *inputs, z = res
-    operands, pull = jax.vjp(_intra, *inputs)
-    grads = _on_platform(_bwd_call, _bwd_scan, interpret, *operands, z, do)
-    return pull(tuple(grads))
+    return _on_platform(_bwd_call, _bwd_xla, interpret, *res, do)
 
 
 _delta.defvjp(_delta_fwd, _delta_bwd)

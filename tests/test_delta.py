@@ -24,14 +24,14 @@ CASES = [
 ]
 
 
-def inputs(key, t, beta_range, decay_range, d=D):
+def inputs(key, t, beta_range, decay_range, d=D, b=B, h=H):
     ks = jax.random.split(jax.random.PRNGKey(key), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[0], (B, t, H, d))) / np.sqrt(d)
-    k = unit(jax.random.normal(ks[1], (B, t, H, d)))
-    v = jax.random.normal(ks[2], (B, t, H, d))
-    g = -jax.random.uniform(ks[3], (B, t, H, d), minval=decay_range[0], maxval=decay_range[1])
-    beta = jax.random.uniform(ks[4], (B, t, H), minval=beta_range[0], maxval=beta_range[1])
+    q = unit(jax.random.normal(ks[0], (b, t, h, d))) / np.sqrt(d)
+    k = unit(jax.random.normal(ks[1], (b, t, h, d)))
+    v = jax.random.normal(ks[2], (b, t, h, d))
+    g = -jax.random.uniform(ks[3], (b, t, h, d), minval=decay_range[0], maxval=decay_range[1])
+    beta = jax.random.uniform(ks[4], (b, t, h), minval=beta_range[0], maxval=beta_range[1])
     return q, k, v, g, beta
 
 
@@ -80,6 +80,62 @@ def test_the_fused_forward_kernel_matches_the_xla_chunk_operands(name, beta_rang
     assert o.shape == want_o.shape and z.shape == want_z.shape == (B * H, 2, D, D)
     close(o, want_o, 2e-5)
     close(z, want_z, 2e-5)
+
+
+@pytest.mark.parametrize("name, beta_range, decay_range", CASES, ids=[c[0] for c in CASES])
+def test_the_chunk_derivative_is_jaxs_derivative_of_the_chunk(name, beta_range, decay_range):
+    """``_chunk_grads`` (the backward kernel's body, written out) against
+    jax's derivative of the forward kernel's chunk: ``_chunk_operands`` then
+    ``_chunk_forward``, from the states entering it."""
+    q, k, v, g, beta = (
+        delta._fold(x)[:, 0] for x in inputs(7, delta.CHUNK, beta_range, decay_range)
+    )
+    beta = beta[:, None]
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    z, dz = (jax.random.normal(key, (B * H, D, D)) for key in ks[:2])
+    do = jax.random.normal(ks[2], (B * H, delta.CHUNK, D))
+    chunk = lambda q, k, v, g, beta, z: delta._chunk_forward(
+        z, *delta._chunk_operands(q, k, v, g, beta)
+    )
+    _, pull = jax.vjp(chunk, q, k, v, g, beta, z)
+    *want, want_dz = jax.jit(pull)((do, dz))
+    got_dz, *got = jax.jit(delta._chunk_grads)(q, k, v, g, beta, z, dz, do)
+    for name_, a, b in zip("q k v g beta".split(), got, want):
+        assert a.shape == b.shape, name_
+        close(a, b, 5e-5)
+    close(got_dz, want_dz, 5e-5)
+
+
+_fused_bwd = jax.jit(lambda *a: delta._bwd_call(*a, interpret=True))
+_xla_bwd = jax.jit(delta._bwd_xla)
+
+
+def check_fused_backward(key, t, beta_range, decay_range, tol, b=B, h=H):
+    folded = [delta._fold(x) for x in inputs(key, t, beta_range, decay_range, b=b, h=h)]
+    _, z = jax.jit(delta._fwd_xla)(*folded)
+    do = delta._fold(jax.random.normal(jax.random.PRNGKey(key + 1), (b, t, h, D)))
+    got = _fused_bwd(*folded, z, do)
+    want = _xla_bwd(*folded, z, do)
+    for name_, a, b_ in zip("q k v g beta".split(), got, want):
+        assert a.shape == b_.shape, name_
+        close(a, b_, tol)
+
+
+@pytest.mark.parametrize("t", [128, 100])
+@pytest.mark.parametrize("name, beta_range, decay_range", CASES, ids=[c[0] for c in CASES])
+def test_the_fused_backward_kernel_matches_the_xla_derivative(name, beta_range, decay_range, t):
+    """``bf_delta_bwd`` gives ``dq, dk, dv, dg, dbeta`` itself (in VMEM on
+    the chip, here in the interpreter): the same as the CPU's branch, jax's
+    derivative of ``_intra`` around ``_bwd_scan``. Beyond the cell's gates
+    each path's ``dg`` lies up to 6e-5 of the largest from the float64
+    token recurrence (the fused one the nearer), so the two may differ by
+    twice that there."""
+    check_fused_backward(9, t, beta_range, decay_range, 1.2e-4 if name == "beyond" else 2e-5)
+
+
+@pytest.mark.parametrize("heads", [1, 2, 8])
+def test_the_fused_backward_kernel_at_one_two_and_four_heads_a_step(heads):
+    check_fused_backward(11, 100, (0.0, 2.0), (1.2, 1.6), 2e-5, b=1, h=heads)
 
 
 def test_the_kernels_solve_holds_where_every_key_is_the_same():

@@ -1,9 +1,10 @@
 """One linear-attention layer's scan at the ``solar2_1chip_seq8k`` cell's
 shape, its loss and all five gradients, compiled for a *described* TPU v5e
-(no chip attached): what the CPU cannot show about the forward kernel —
-that Mosaic takes the in-chunk work it now does (the cumulative decay, the
-pair products, the triangular solve) inside the scoped VMEM limit, and that
-none of that work is left to XLA in the forward pass.
+(no chip attached): what the CPU cannot show about the two kernels — that
+Mosaic takes the in-chunk work they now do (the cumulative decay, the pair
+products, the triangular solve, and in the backward kernel their
+derivatives) inside the scoped VMEM limit, and that none of that work is
+left to XLA in either pass.
 
 Marked ``slow`` (``-m 'not slow'`` leaves it out; ``python -m pytest
 tests/test_delta_compile_v5e.py -m slow``): the TPU compiler takes every
@@ -56,17 +57,20 @@ def compiled(one_chip):
 
 
 def test_the_forward_pass_leaves_the_in_chunk_work_to_the_kernel(compiled):
+    """And the backward pass to ``bf_delta_bwd``, which takes the in-chunk
+    derivative too."""
     text = compiled.as_text()
     # one forward kernel and one backward kernel, within the scoped VMEM
     # limit (Mosaic refuses the compile otherwise)
     calls = re.findall(r"custom_call_target=\"tpu_custom_call\".*?op_name=\"([^\"]*)\"", text)
     assert sorted(name.rsplit("/", 2)[-2] for name in calls) == ["bf_delta_bwd", "bf_delta_fwd"]
-    # the forward pass (no transpose in its name) holds no triangular solve
-    # and no cumulative sum: they are inside bf_delta_fwd
+    # neither pass (the backward's names hold a transpose) has a triangular
+    # solve or a cumulative sum outside the kernels: the forward's are in
+    # bf_delta_fwd, the backward's and their derivatives in bf_delta_bwd
     forward, backward = [], []
     for name in re.findall(r'op_name="([^"]*)"', text):
         (backward if "transpose(" in name else forward).append(name)
+    assert forward and backward
     for word in ("triangular_solve", "cumsum"):
         assert not [n for n in forward if word in n], word
-        # the backward pass's derivative of the in-chunk work is still XLA's
-        assert [n for n in backward if word in n], word
+        assert not [n for n in backward if word in n], word

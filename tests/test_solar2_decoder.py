@@ -279,6 +279,7 @@ def test_scopes_and_gauges_of_one_traced_loss():
     chunks = BATCH * 16 * 2 * 3  # 16 heads held, 72 tokens in 2 chunks, 3 linear layers
     assert peek("bluefog.delta.chunks") == chunks
     assert peek("bluefog.delta.fused_fwd_chunks") == 0  # the CPU's scan builds them in XLA
+    assert peek("bluefog.delta.fused_bwd_chunks") == 0  # and jax differentiates them there
     assert peek("bluefog.delta.state_bytes") == chunks * 8 * 8 * 4
     assert peek("bluefog.attn.heads_held") == 16
     assert peek("bluefog.attn.tiles_total") == BATCH * 16 * 1  # one softmax layer
