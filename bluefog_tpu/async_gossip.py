@@ -96,8 +96,8 @@ the drift at f32 rounding, not quantization precision).
 **Async off** (``BLUEFOG_ASYNC=0`` or ``enabled=False``):
 :func:`make_async_train_step` returns the wrapped optimizer's own
 ``make_train_step`` callable — the current synchronous path, bitwise
-identical by construction (pinned by tests/test_async.py and
-``BENCH_MODE=async``).
+identical by construction (pinned by
+tests/test_async.py::test_async_off_is_bitwise_synchronous_path).
 
 Env knobs: ``BLUEFOG_ASYNC`` (default on — the builder is the opt-in),
 ``BLUEFOG_ASYNC_MAX_AGE`` (default 8 local window steps),

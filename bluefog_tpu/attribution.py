@@ -17,8 +17,8 @@ wall-clock plus separate probe dispatches on throwaway buffers — so
 unsampled steps dispatch the bitwise-identical program under the same
 cache key as doctor-off (there is no ``doctor`` component in any
 compiled-step cache key to diverge on), and the training trajectory is
-pinned bitwise doctor-on vs doctor-off (tests/test_doctor.py,
-``BENCH_MODE=attribution``).
+pinned bitwise doctor-on vs doctor-off
+(tests/test_doctor.py::test_doctor_off_is_bitwise_and_structurally_invisible).
 
 **What one sample measures.**
 
@@ -47,8 +47,7 @@ pinned bitwise doctor-on vs doctor-off (tests/test_doctor.py,
   bound, not a scheduler trace).
 - ``anchor_tflops`` — a fixed small bf16 matmul timed every sample: the
   ambient-compute anchor that separates "the host got slower" from
-  "the program got slower" (the bench-level twin is the 8192^3 anchor
-  line every ``BENCH_MODE`` emits; see docs/doctor.md).
+  "the program got slower" (see docs/doctor.md).
 
 **Online baselines and advisories.** Every series above (plus the
 consensus-distance gauge, wire-byte and recompile counters read from
@@ -80,7 +79,8 @@ faults (now with an optional ``peer=`` edge target) add a deterministic
 delay to probe dispatches whose perm crosses the degraded edge
 (:meth:`bluefog_tpu.elastic.recovery.ElasticSession.
 simulated_wire_factors`), so "the advisory names the injected edge" is
-a reproducible unit test (``BENCH_MODE=attribution``).
+a reproducible unit test
+(tests/test_doctor.py::test_degraded_link_advisory_names_injected_edge).
 
 Env knobs: ``BLUEFOG_DOCTOR=1`` enables (default off),
 ``BLUEFOG_DOCTOR_INTERVAL`` (default 100 communicating steps),
@@ -105,7 +105,6 @@ __all__ = [
     "probe_elems_cap",
     "start",
     "stop",
-    "activate",
     "active",
     "dispatch_timer",
     "observe_step",
@@ -154,10 +153,9 @@ def enabled() -> bool:
 def doctor_interval() -> int:
     """Sampling period in communicating steps
     (``BLUEFOG_DOCTOR_INTERVAL``, default 100). A sample costs roughly
-    one settled step plus a handful of tiny probe dispatches, so the
-    default keeps the amortized cost under the 1 % acceptance bound
-    re-checked by ``BENCH_MODE=attribution``; shrink it when actively
-    chasing a regression."""
+    one settled step plus a handful of tiny probe dispatches, so at the
+    default it amortizes to about one step in a hundred; shrink it when
+    actively chasing a regression."""
     from bluefog_tpu.logging_util import env_int
 
     return max(1, env_int(INTERVAL_ENV, 100))
@@ -867,16 +865,6 @@ def start(interval: Optional[int] = None, **kwargs) -> StepDoctor:
 def stop() -> None:
     global _doctor
     _doctor = None
-
-
-def activate(doctor: Optional[StepDoctor]) -> Optional[StepDoctor]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its baselines — the A/B rotation in
-    ``BENCH_MODE=attribution`` toggles one session on and off around
-    individual steps."""
-    global _doctor
-    _doctor = doctor
-    return doctor
 
 
 def active() -> Optional[StepDoctor]:

@@ -27,7 +27,7 @@ like a PR-4 elastic repair (live-token-aware cache keys, zero stale
 dispatches, optax state preserved by construction, EF/delay buffers
 self-invalidating on structure change). Controller-off steps therefore
 dispatch the bitwise-identical program under the same cache key —
-pinned structurally and bitwise by ``BENCH_MODE=autotune``.
+pinned structurally and bitwise by tests/test_step_core.py.
 
 **Triggers.** A sample harvests *new* advisories since the previous
 sample: the doctor's ``degraded_link``/``straggler`` (per-edge measured
@@ -121,7 +121,6 @@ __all__ = [
     "score_candidate",
     "start",
     "stop",
-    "activate",
     "active",
     "observe_step",
     "dump",
@@ -197,8 +196,7 @@ def autotune_interval() -> int:
     """Sampling period in communicating steps
     (``BLUEFOG_AUTOTUNE_INTERVAL``, default 50). A sample is host
     arithmetic only (advisory harvest + at most one bounded candidate
-    search); the default keeps the amortized cost well under the 1 %
-    acceptance bound re-measured by ``BENCH_MODE=autotune``."""
+    search), paid one step in fifty at the default."""
     from bluefog_tpu.logging_util import env_int
 
     return max(1, env_int(INTERVAL_ENV, 50))
@@ -1402,17 +1400,6 @@ def start(interval: Optional[int] = None, **kwargs) -> TopologyAutotuner:
 def stop() -> None:
     global _tuner
     _tuner = None
-
-
-def activate(tuner: Optional[TopologyAutotuner]
-             ) -> Optional[TopologyAutotuner]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its baselines — the A/B rotation in
-    ``BENCH_MODE=autotune`` toggles one session on and off around
-    individual steps."""
-    global _tuner
-    _tuner = tuner
-    return tuner
 
 
 def active() -> Optional[TopologyAutotuner]:

@@ -243,8 +243,8 @@ def calibrate(
 
     The result is cached process-wide (``force=True`` re-probes) and
     every cost function below prices with it from then on. Invoked
-    explicitly by ``BENCH_MODE=plan`` and lazily by the chooser when
-    ``BLUEFOG_PLAN_CALIBRATE=1``.
+    lazily by the chooser when ``BLUEFOG_PLAN_CALIBRATE=1``;
+    tests/test_plan_compiler.py runs it on the suite's virtual mesh.
 
     ``link_class`` selects which class's constants the probe installs.
     Only ``"ici"`` is probe-able from inside one pod (the ambient

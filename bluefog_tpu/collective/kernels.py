@@ -6,10 +6,10 @@ The composite int8/int4 wire (``inner._chunk_quantize`` /
 combines) pays its quantize -> pack -> ppermute -> unpack -> dequant
 chain as separate XLA ops with full-width f32 intermediates: the
 ``xhat`` reconstruction the difference form needs, and one dequantized
-full-width temporary per received round. PR 15's committed baseline
-(``MEMORY_EVIDENCE.json`` ``memory_wire_temps``) pins the consequence —
-at payload 4096 the quantized combines' measured scratch *exceeds* the
-exact path's (int8 24 736 B / int4 20 640 B vs fp32 16 384 B).
+full-width temporary per received round. The consequence, measured on
+XLA:CPU at payload 4096: the quantized combines' scratch *exceeds* the
+exact path's (int8 24 736 B / int4 20 640 B vs fp32 16 384 B; priced by
+``scaling.quantized_temporaries_bytes``).
 
 This module erases that staging cost with two fused kernels (the
 XLA-collective analogue of EQuARX's fused quantized allreduce,
@@ -192,7 +192,8 @@ def _pin_wire_buffer(payload: jnp.ndarray, scales: jnp.ndarray):
     through — without it the accumulate fusion re-derives the quantize
     chain from the f32 input and materializes its full-width ``divide``
     (16 KiB at payload 4096, the exact temporary this module removes;
-    measured in BENCH_MODE=quant's kernel-vs-composite rows)."""
+    measured by
+    tests/test_wire_kernels.py::test_fused_scratch_below_fp32_row)."""
     if _native():
         return payload
     pred = scales.reshape(-1)[0].astype(jnp.float32) > -1.0

@@ -51,9 +51,10 @@ Plan grammar (``BLUEFOG_FAULT_PLAN``), semicolon-separated clauses::
   simulated_compute_dilation` — the compute analogue of the
   link-scoped ``degrade``). An optional ``steps=S`` bounds the
   dilation to ``S`` session steps; without it the fault is permanent.
-  This is the 10x-straggler chaos primitive the ``BENCH_MODE=async``
-  evidence drives: rank-scoped by definition (``peer=`` is rejected —
-  a slow *chip* has no single slow edge).
+  This is the 10x-straggler chaos primitive
+  tests/test_async.py::test_straggler_costs_only_its_own_share drives:
+  rank-scoped by definition (``peer=`` is rejected — a slow *chip* has
+  no single slow edge).
 - ``oom``      — simulated device allocation failure: at ``step`` the
   rank's dispatch raises
   :class:`bluefog_tpu.memory.SimulatedResourceExhausted` (a

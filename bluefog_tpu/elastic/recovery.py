@@ -259,10 +259,10 @@ class ElasticSession:
         layer's deterministic stand-in for a physically slow chip
         (the compute analogue of :meth:`simulated_wire_factors`). The
         asynchronous gossip engine multiplies a dilated rank's cadence
-        period by ``ceil(factor)``; the ``BENCH_MODE=async`` straggler
-        scenario models synchronous step time as ``max_r(factor_r)``
-        per step. A fault with ``steps=S`` expires after ``S`` session
-        steps; without it the dilation is permanent."""
+        period by ``ceil(factor)``, while a synchronous step would
+        cost ``max_r(factor_r)`` local-step times. A fault with
+        ``steps=S`` expires after ``S`` session steps; without it the
+        dilation is permanent."""
         out: Dict[int, float] = {}
         for f in self.plan.faults:
             if f.kind != "slow" or self.step < f.step:

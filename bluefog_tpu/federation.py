@@ -80,7 +80,6 @@ __all__ = [
     "wire_summary",
     "Fabric",
     "get_fabric",
-    "clear_fabric_cache",
     "FederatedFleet",
 ]
 
@@ -570,8 +569,9 @@ def wire_summary(
     full rate, DCN bytes amortized over the period, and the flat
     baseline — the per-step DCN bytes a FLAT fabric of the same base
     topology would push through cross-pod links (every cross-pod edge
-    rides DCN every step at the gossip wire). The ``>= 8x`` DCN-cut
-    evidence claim (FEDERATE_EVIDENCE.json) is this ratio."""
+    rides DCN every step at the gossip wire). The ``>= 8x`` DCN cut at
+    a matched consensus rate is this ratio
+    (tests/test_federation.py::test_dcn_cut_at_matched_consensus_rate)."""
     from bluefog_tpu import fleetsim, metrics
 
     period = dcn_period() if period is None else int(period)
@@ -695,10 +695,6 @@ def get_fabric(size: int, kind: str = "exp2") -> Optional[Fabric]:
     return fab
 
 
-def clear_fabric_cache() -> None:
-    _FABRIC_CACHE.clear()
-
-
 # -- whole-pod elastic semantics (the fleet-scale exercise) -------------------
 
 
@@ -712,7 +708,8 @@ class FederatedFleet:
     ``_repair`` pass prunes the pod, re-elects gateways among the
     survivors, rewires the inter-pod ring, and bumps the topology
     version ONCE — the plan cache can never serve a stale gateway.
-    Exercised at O(pods x chips) by ``BENCH_MODE=federate``."""
+    Exercised by
+    tests/test_federation.py::test_federated_fleet_pod_loss_one_event."""
 
     def __init__(self, layout: PodLayout, kind: str = "exp2",
                  policy: str = "receiver", plan=None,

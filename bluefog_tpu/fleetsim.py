@@ -41,8 +41,7 @@ control-plane mechanism driven for real:
 
 Everything is deterministic on the step clock, so churn storms,
 cascading repairs, and whole-region loss at N=1024 are plain tier-1
-unit tests; ``BENCH_MODE=fleetscale`` commits the measured control-
-plane scaling as ``FLEETSCALE_EVIDENCE.json``.
+unit tests (tests/test_fleetsim.py).
 """
 
 import json

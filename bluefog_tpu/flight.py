@@ -21,8 +21,8 @@ Design constraints, in order:
    an ``itertools.count`` (atomic under the GIL) and writes its own slot
    ``seq % capacity`` — concurrent writers (the training loop, the
    watchdog thread) never share a slot, and readers sort the snapshot by
-   sequence. ``BENCH_MODE=flight`` re-checks the <=1 % per-step bound
-   and the bitwise on/off trajectory pin every round.
+   sequence. tests/test_flight.py::test_recorder_on_off_trajectory_bitwise
+   pins the trajectory bitwise recorder on vs off.
 2. **Bounded memory.** ``BLUEFOG_FLIGHT_CAPACITY`` slots (default 8192);
    old events are overwritten, never accumulated. Side tables that the
    postmortem needs regardless of ring age (the compiled CommPlan

@@ -44,7 +44,7 @@ out of the repaired plan, so the estimate converges to the live-set
 aggregate). The lane is a *separate* tiny dispatch on sampled steps
 only: the training program is untouched, so unsampled steps dispatch
 the bitwise-identical health-off program under the same cache key —
-the PR-3/PR-7 sampling discipline, re-proven by ``BENCH_MODE=health``.
+the sampling discipline (tests/test_step_core.py).
 
 **(c) Serving surface.** ``BLUEFOG_HEALTH_PORT`` starts a per-rank
 stdlib HTTP endpoint: ``/healthz`` (RAG verdict from advisory recency +
@@ -93,7 +93,6 @@ __all__ = [
     "FLEET_FIELDS",
     "start",
     "stop",
-    "activate",
     "active",
     "observe_step",
     "serve",
@@ -176,8 +175,7 @@ def health_interval() -> int:
     (``BLUEFOG_HEALTH_INTERVAL``, default 20 — twice the metrics drain
     period, so the consensus gauge has refreshed between health
     samples). A sample is host arithmetic plus one tiny push-sum lane
-    dispatch; the default keeps the amortized cost under the 1 %
-    acceptance bound re-measured by ``BENCH_MODE=health``."""
+    dispatch, paid one step in twenty at the default."""
     from bluefog_tpu.logging_util import env_int
 
     return max(1, env_int(INTERVAL_ENV, 20))
@@ -1504,15 +1502,6 @@ def start(interval: Optional[int] = None, **kwargs) -> HealthPlane:
 def stop() -> None:
     global _plane
     _plane = None
-
-
-def activate(plane: Optional[HealthPlane]) -> Optional[HealthPlane]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its baselines — the A/B rotation in ``BENCH_MODE=health``
-    toggles one session on and off around individual steps."""
-    global _plane
-    _plane = plane
-    return plane
 
 
 def active() -> Optional[HealthPlane]:

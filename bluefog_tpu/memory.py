@@ -20,7 +20,8 @@ communicating steps take a sample; the observatory is purely host-side
 (``jax.live_arrays()`` census + device memory stats + RSS reads), so
 unsampled steps — and sampled ones — dispatch the bitwise-identical
 observatory-off training program under the SAME cache key
-(structural + bitwise pinned by ``BENCH_MODE=memory``).
+(structural + bitwise pinned by
+tests/test_memory.py::test_observatory_compiles_nothing_and_stays_bitwise).
 
 **Per sample:**
 
@@ -103,7 +104,6 @@ __all__ = [
     "phase_scope",
     "start",
     "stop",
-    "activate",
     "active",
     "observe_step",
     "on_oom",
@@ -127,8 +127,8 @@ FILE_ENV = "BLUEFOG_MEMORY_FILE"
 # and the per-slot scatter residuals), "delay" the delayed-combine
 # double buffers, "windows" every win_create buffer (value + neighbor
 # slots + p lanes), "wire_temp" is reserved for the XLA temporary
-# accounting (BENCH_MODE=memory reads it from the compiled program,
-# not from live arrays), "other" is everything unattributed — batches,
+# accounting (read from the compiled program's memory analysis, not
+# from live arrays), "other" is everything unattributed — batches,
 # user state, framework internals.
 CATEGORIES = (
     "params", "opt_state", "grads", "residuals", "delay", "windows",
@@ -180,8 +180,7 @@ def memory_interval() -> int:
     """Sampling period in communicating steps
     (``BLUEFOG_MEMORY_INTERVAL``, default 20). A sample is one
     ``jax.live_arrays()`` walk plus O(leaves) id lookups — host-only —
-    so the default keeps the amortized cost under the 1 % acceptance
-    bound re-measured by ``BENCH_MODE=memory``."""
+    paid one step in twenty at the default."""
     from bluefog_tpu.logging_util import env_int
 
     return max(1, env_int(INTERVAL_ENV, 20))
@@ -534,7 +533,7 @@ class MemoryObservatory:
         for cat in CATEGORIES:
             if cat == "wire_temp":
                 # reserved for the compiled-program scratch accounting
-                # (BENCH_MODE=memory reads it from memory_analysis());
+                # (read from the compiled program's memory_analysis());
                 # the live-array census can never populate it, and a
                 # permanently-zero gauge is registry noise
                 continue
@@ -787,16 +786,6 @@ def start(interval: Optional[int] = None, **kwargs) -> MemoryObservatory:
 def stop() -> None:
     global _observatory
     _observatory = None
-
-
-def activate(obs: Optional[MemoryObservatory]
-             ) -> Optional[MemoryObservatory]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its state — the A/B rotation in ``BENCH_MODE=memory``
-    toggles one session on and off around individual steps."""
-    global _observatory
-    _observatory = obs
-    return obs
 
 
 def active() -> Optional[MemoryObservatory]:

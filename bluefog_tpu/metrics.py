@@ -24,8 +24,7 @@ additionally outputs tiny pre-scaled subsample slices
 sample from the asynchronously copied-back payload
 (:func:`fold_device_payload`). In-graph reductions over the live
 training trees were measured to derail the XLA CPU schedule by far more
-than their arithmetic; O(cap) slice outputs are free, and the <2 %
-overhead bound at interval 10 is re-checked by ``BENCH_MODE=metrics``.
+than their arithmetic; O(cap) slice outputs are free.
 Enabling metrics adds *outputs* but identical parameter/optimizer
 math — the training state is pinned bitwise-identical metrics-on vs
 metrics-off (tests/test_metrics.py).

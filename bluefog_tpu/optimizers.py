@@ -1857,8 +1857,7 @@ class _GossipOptimizer:
         # Two-program sampling: only the 1-in-interval sampled step pays
         # the metric computation — every other step dispatches a program
         # whose cache key EQUALS the metrics-off key, so 9 of 10 steps
-        # are the metrics-off program by construction (the design that
-        # keeps BENCH_MODE=metrics under its 2% bound; an in-graph
+        # are the metrics-off program by construction (an in-graph
         # lax.cond was measured to drag every step).
         met = met_enabled and (
             self._comm_count % metrics_mod.metrics_interval() == 0
@@ -2704,8 +2703,8 @@ class _GossipOptimizer:
         """Optimized HLO text of the most recently dispatched fused train
         step, lowered against the given operands (only their avals
         matter; the recorded dispatch operands are kept as
-        ShapeDtypeStructs). Evidence/debug hook for
-        ``BENCH_MODE=overlap`` and ``tests/test_overlap.py`` — it owns
+        ShapeDtypeStructs). Debug hook for ``chip_smoke.py`` and the
+        HLO tests (``tests/test_overlap.py`` and others) — it owns
         the compiled fn's operand structure so callers never have to
         poke cache-key internals."""
         fn, wops, ef_in, buf_in, accum_in = self._last_fused

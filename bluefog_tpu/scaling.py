@@ -162,8 +162,9 @@ def quantized_temporaries_bytes(n_elems: int,
     exactly what a fused Pallas kernel (EQuARX, arxiv 2506.17615)
     would never materialize, which makes this function the committed
     *before*-baseline the ROADMAP kernel-fusion item must beat
-    (``BENCH_MODE=memory`` pairs it with the measured XLA
-    ``temp_size_in_bytes`` of the compiled combine).
+    (tests/test_wire_kernels.py::test_fused_scratch_below_fp32_row
+    pairs it with the measured XLA ``temp_size_in_bytes`` of the
+    compiled combine).
 
     Block-scaled tiers stage whole 512-element blocks (the payload is
     padded to the scale grid before the ppermute). fp32 ships verbatim
@@ -235,17 +236,17 @@ def optimizer_state_bytes(
     world: Optional[int] = None,
 ) -> int:
     """Canonical PER-RANK optimizer-state byte accounting — the single
-    number the shard evidence (``BENCH_MODE=shard``), the health
-    ``/fleet`` report's shard block, and ``tools/shard_plan.py`` all
-    quote (docs/sharding.md).
+    number the shard tests, the health ``/fleet`` report's shard block,
+    and ``tools/shard_plan.py`` all quote (docs/sharding.md).
 
     Two modes:
 
     - **measured**: pass ``state=`` (a live worker-stacked state tree)
       — returns the real allocated bytes divided by the worker count
       (``world=``, default inferred from the leading axis). This is
-      what SHARD_EVIDENCE.json's 1/N claim is gated on: actual array
-      bytes, not a model.
+      what the 1/N claim is held to
+      (tests/test_sharding.py::test_state_bytes_measured_equals_analytic):
+      actual array bytes, not a model.
     - **analytic**: pass ``params`` (worker-stacked) and ``opt`` (a
       distributed optimizer or a raw optax transformation) — sizes the
       state via ``jax.eval_shape`` of ``tx.init`` without allocating
@@ -401,8 +402,9 @@ def plan_comm_summary(plan: CommPlan, payload_bytes: int,
     cost). ``payload_bytes`` is the UNCOMPRESSED per-bucket payload;
     ``wire`` reprices it per :func:`wire_payload_bytes` (scale sidecar
     included) and reports the per-bucket ``effective_compression_ratio``
-    = uncompressed bytes / wire bytes — the number the quantized-wire
-    evidence (``BENCH_MODE=quant``) gates its >=2x-vs-int8 claim on."""
+    = uncompressed bytes / wire bytes, the ratio behind the >=2x
+    int4-vs-int8 wire claim
+    (tests/test_compression.py::test_wire_tiers_ship_bytes_in_tier_order)."""
     from bluefog_tpu.collective import compiler as _compiler
 
     info = plan.compile_info

@@ -9,7 +9,8 @@ all-gather over the worker fabric redistributes the updated parameter
 slices. Per-rank optimizer-state memory drops to ~1/N of the replicated
 footprint (plus 512-element alignment slack), which is what lets the
 fleet train a model whose *replicated* Adam state exceeds a single
-chip's budget (``BENCH_MODE=shard``, SHARD_EVIDENCE.json).
+chip's budget
+(tests/test_sharding.py::test_state_bytes_measured_equals_analytic).
 
 Where sharding is exact — and where it cannot be
 ------------------------------------------------

@@ -26,7 +26,7 @@ Error budgets and multi-window burn rates
     means "spending exactly the sustainable rate". Two windows fire
     Google-SRE-style alerts: the **fast** window catches acute
     degradation within :func:`page_sample_bound` samples of onset
-    (the documented page bound, asserted by ``BENCH_MODE=slo``); the
+    (the documented page bound, asserted by tests/test_slo.py); the
     **slow** window catches ramps that the health plane's EWMA+MAD
     hygiene rules deliberately never trip on (an out-of-band sample
     never absorbs into the baseline, so a slow drift tracks the
@@ -42,10 +42,11 @@ Canary lane
     its own ``slo_canary`` op-cache family, training cache keys are
     untouched, and unsampled steps dispatch the bitwise-identical
     slo-off program under the SAME cache key (pinned structurally and
-    bitwise by ``BENCH_MODE=slo``). The host compares every delivered
-    edge against the :mod:`bluefog_tpu.collective.wire_ref` numpy
-    replay — a black-box end-to-end fabric verdict that names the
-    failing edge even when the training series are quiet. Chaos
+    bitwise by tests/test_slo.py and tests/test_step_core.py). The
+    host compares every delivered edge against the
+    :mod:`bluefog_tpu.collective.wire_ref` numpy replay — a black-box
+    end-to-end fabric verdict that names the failing edge even when the
+    training series are quiet. Chaos
     parity: a tier-1 virtual mesh has no physically lossy link, so
     active ``degrade`` faults corrupt the *delivered* canary
     host-side (the elastic session's deterministic wire simulation,
@@ -128,8 +129,8 @@ def canary_enabled() -> bool:
 #
 # Pure functions over newest-last 0/1 bad-sample flags — THE oracle
 # surface: the engine computes through these and nothing else, and the
-# tests + BENCH_MODE=slo recompute them independently in numpy over
-# the same flag series (acceptance claim e).
+# tests recompute them independently in numpy over the same flag
+# series.
 
 
 def burn_rate(flags: Sequence[int], window: int,
@@ -174,8 +175,7 @@ def page_sample_bound(fast_window: int, fast_burn: float,
     so the page fires at ``m = ceil(fast_burn × budget_frac ×
     fast_window)`` — never more than ``fast_window`` samples when the
     thresholds are sane (``fast_burn ≤ 1 / budget_frac``), which
-    ``BENCH_MODE=slo`` claim (a) asserts against the measured firing
-    sample."""
+    tests/test_slo.py asserts against the measured firing sample."""
     return int(math.ceil(fast_burn * budget_frac * fast_window))
 
 
@@ -943,15 +943,6 @@ def start(interval: Optional[int] = None, **kwargs) -> SLOEngine:
 def stop() -> None:
     global _engine
     _engine = None
-
-
-def activate(engine: Optional[SLOEngine]) -> Optional[SLOEngine]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its state — the A/B rotation in ``BENCH_MODE=slo``
-    toggles one session on and off around individual steps."""
-    global _engine
-    _engine = engine
-    return engine
 
 
 def active() -> Optional[SLOEngine]:

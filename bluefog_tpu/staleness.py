@@ -25,7 +25,8 @@ Sampling is the PR-3 discipline: 1-in-``BLUEFOG_STALENESS_INTERVAL``
 communicating steps dispatch the lane as a SEPARATE tiny program
 (cached under its own ``staleness_lane`` op-cache family); unsampled
 steps dispatch the bitwise-identical observatory-off training program
-under the same cache key, re-proven by ``BENCH_MODE=staleness``.
+under the same cache key (tests/test_staleness.py,
+tests/test_step_core.py).
 
 **Three exchange surfaces:**
 
@@ -92,7 +93,6 @@ __all__ = [
     "age_adjusted_rate",
     "start",
     "stop",
-    "activate",
     "active",
     "observe_step",
     "observe_window",
@@ -138,9 +138,8 @@ def enabled() -> bool:
 def staleness_interval() -> int:
     """Sampling period in communicating steps
     (``BLUEFOG_STALENESS_INTERVAL``, default 20). A sample is one tiny
-    int32 lane dispatch plus O(edges) host folding; the default keeps
-    the amortized cost under the 1 % acceptance bound re-measured by
-    ``BENCH_MODE=staleness``."""
+    int32 lane dispatch plus O(edges) host folding, paid one step in
+    twenty at the default."""
     from bluefog_tpu.logging_util import env_int
 
     return max(1, env_int(INTERVAL_ENV, 20))
@@ -689,16 +688,6 @@ def start(interval: Optional[int] = None, **kwargs
 def stop() -> None:
     global _observatory
     _observatory = None
-
-
-def activate(obs: Optional[StalenessObservatory]
-             ) -> Optional[StalenessObservatory]:
-    """Install (or clear, with None) a pre-built session WITHOUT
-    resetting its state — the A/B rotation in ``BENCH_MODE=staleness``
-    toggles one session on and off around individual steps."""
-    global _observatory
-    _observatory = obs
-    return obs
 
 
 def active() -> Optional[StalenessObservatory]:

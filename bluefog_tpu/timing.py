@@ -1,5 +1,6 @@
 # Copyright 2026. Licensed under the Apache License, Version 2.0.
-"""Measurement helpers shared by bench.py, tools/, and
+"""Measurement helpers shared by ``chip_smoke.py``, the plan compiler's
+calibration probe, :mod:`bluefog_tpu.attribution` and
 :mod:`bluefog_tpu.scaling`.
 
 :func:`settle` synchronizes by reading one element back through a tiny
@@ -26,8 +27,7 @@ __all__ = ["settle", "timed_differenced"]
 _TAKE = None
 
 
-def timed_differenced(step, steps: int, windows: int,
-                      with_degenerate: bool = False):
+def timed_differenced(step, steps: int, windows: int):
     """Differenced-window timing: per window, time ``steps`` calls +
     settle and ``2*steps`` calls + settle; the difference is ``steps``
     calls of pure compute with the settle cost cancelled EXACTLY — the
@@ -36,19 +36,16 @@ def timed_differenced(step, steps: int, windows: int,
 
     A window whose difference comes out ``<= 0`` (an ambient stall
     landed inside the first half) is DEGENERATE: its clamped value would
-    publish as a fake ~0 time (the r05 evidence artifact's
-    ``dense_fwdbwd_ms: 0.0``). Each degenerate window gets one retry;
+    read as a fake ~0 time. Each degenerate window gets one retry;
     windows still degenerate after that are excluded from the result as
     long as at least one clean window exists. Only when EVERY window is
-    degenerate do the clamped values come back, flagged.
+    degenerate do the clamped values come back.
 
     ``step()`` advances whatever state it closes over and returns the
     settle target (keep it SCALAR — settling a large tensor pays its
     transfer). Returns the per-call times of the clean windows,
     sorted ascending (``[0]`` is the best window; the spread is the
-    honest noise disclosure). With ``with_degenerate=True`` returns
-    ``(times, degenerate)`` where ``degenerate`` is True only in the
-    all-windows-clamped case."""
+    honest noise disclosure)."""
     import time
 
     out = step()
@@ -76,9 +73,8 @@ def timed_differenced(step, steps: int, windows: int,
         diffs.append(diff)
     clean = sorted(d / steps for d in diffs if d > 0)
     if clean:
-        return (clean, False) if with_degenerate else clean
-    clamped = sorted(max(d, 1e-9) / steps for d in diffs)
-    return (clamped, True) if with_degenerate else clamped
+        return clean
+    return sorted(max(d, 1e-9) / steps for d in diffs)
 
 
 def settle(x) -> float:

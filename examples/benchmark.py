@@ -5,8 +5,9 @@
 TPU-native rendition of reference ``examples/pytorch_benchmark.py``: times
 the full decentralized train step (forward + backward + inner update +
 gossip, one compiled program) for a chosen model and optimizer family and
-prints imgs/sec. Use the repo-root ``bench.py`` for the driver-facing
-headline number; this example is the user-facing knob-twiddling version.
+prints imgs/sec. The benchmark of record is ``benchmarks/run.py`` (its
+cells in ``BENCHMARK.json``, its numbers in ``PERF.md``); this example is
+the user-facing knob-twiddling version.
 """
 
 import argparse

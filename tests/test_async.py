@@ -11,6 +11,7 @@ import time
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -316,6 +317,66 @@ def test_slow_fault_dilates_cadence():
     # rank 1 participated only on ticks 0 and 4: 8 ticks x 8 ranks
     # minus 6 skipped = 58 local steps
     assert step.engine._local_steps == 8 * SIZE - 6
+
+
+def test_straggler_costs_only_its_own_share():
+    """A 10x compute-dilated rank (the ``slow`` chaos fault) gates every
+    synchronous step, so the synchronous fleet runs at 1/10 of nominal;
+    the async engine's own counters show the fleet within ~1/N of
+    nominal: the slow rank skips only its own local steps."""
+    dilation, slow_rank = 10, SIZE - 2
+    z0 = problem(8)
+    bf.set_topology(tu.RingGraph(SIZE, connect_style=1))
+    session = bf.elastic.start(policy="push_sum")
+    session.inject("slow", rank=slow_rank, step=0, factor=dilation)
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(0.05))
+    params = {"w": jnp.asarray(z0)}
+    state = opt.init(params)
+    step = bf.make_async_train_step(opt, quad_loss, max_age=4)
+    batch = jnp.asarray(z0 + 1.0)
+    eng = step.engine
+    while eng._tick < 40:
+        params, state, _ = step(params, state, batch)
+        jax.block_until_ready(params)
+    participation = eng._local_steps / (eng._tick * SIZE)
+    assert participation >= 1.0 - 1.5 / SIZE, participation
+    # exactly the slow rank's skipped ticks are missing
+    ran = -(-eng._tick // dilation)
+    assert eng._local_steps == eng._tick * (SIZE - 1) + ran
+    assert participation > 1.0 / dilation
+    assert np.isfinite(np.asarray(params["w"])).all()
+
+
+@pytest.mark.parametrize("wire", ["fp32", "int8_ef", "int4_ef"])
+def test_push_sum_mass_conserved_per_wire_tier(wire):
+    """Random per-rank cadences at lr=0: the total x mass (window plus
+    pending buffers) and the p mass stay at their initial values to f32
+    rounding on every wire tier — the sender absorbs the quantization
+    residual of the mass it ships."""
+    dim = 512
+    z0 = problem(5, dim)
+    rng = np.random.RandomState(5)
+    cadence = {r: int(p) for r, p in enumerate(rng.randint(1, 5, SIZE))}
+    bf.set_topology(tu.RingGraph(SIZE, connect_style=1))
+    opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(0.0))
+    params = {"w": jnp.asarray(z0)}
+    state = opt.init(params)
+    step = bf.make_async_train_step(
+        opt, quad_loss, cadence=cadence, wire=wire, max_age=10 ** 6
+    )
+    assert step.engine.wire == async_gossip.async_wire(wire)
+    batch = jnp.asarray(z0)
+    mass0 = float(np.sum(z0, dtype=np.float64))
+    bound = 1e-5 * float(np.abs(z0).sum())
+    for _ in range(15):
+        params, state, _ = step(params, state, batch)
+        win = win_mod._get_win(bf.get_context(), step.engine._name)
+        total = float(np.sum(np.asarray(win.value), dtype=np.float64)) \
+            + float(np.sum(np.asarray(win.buffers), dtype=np.float64))
+        ptotal = float(np.sum(np.asarray(win.p), dtype=np.float64)) \
+            + float(np.sum(np.asarray(win.p_buffers), dtype=np.float64))
+        assert abs(total - mass0) < bound, (total, mass0)
+        assert abs(ptotal - SIZE) < 1e-5, ptotal
 
 
 # -- elastic repair / re-window -----------------------------------------------

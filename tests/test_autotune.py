@@ -377,6 +377,65 @@ def test_closed_loop_doctor_detects_controller_migrates():
 
 
 @pytest.mark.chaos
+def test_mixing_recovers_after_routing_around_a_lossy_link():
+    """The health plane's side of the loop, on a deterministic host
+    replay of a lossy link (factor 0.05 on edge 2->3 of a ring): the
+    measured mixing efficiency falls below the spectral promise and
+    ``mixing_degraded`` names the edge; the controller swaps to a graph
+    without it, after which the efficiency is back near 1 and the
+    chaos-priced step time has recovered."""
+    src, dst, factor = 2, 3, 0.05
+    ctx = bf.get_context()
+    session = bf.elastic.start(policy="average")
+    session.inject("degrade", rank=src, step=30, factor=factor, peer=dst)
+    plane = health.start(interval=1)
+    tuner = autotune.start(interval=1, cooldown=8)
+    rng = np.random.RandomState(0)
+    x = rng.randn(SIZE, 64)
+    payload = compiler.DEFAULT_PAYLOAD_BYTES
+    last_v = ctx.topo_version
+    step_s = []
+    for t in range(130):
+        session.before_dispatch(None)
+        if ctx.topo_version != last_v:
+            # a fresh signal for the new graph's decay fit
+            last_v = ctx.topo_version
+            x = rng.randn(SIZE, 64)
+        w = tu.mixing_matrix(bf.load_topology())
+        lossy = [
+            (key, f) for key, f in session.simulated_wire_factors().items()
+            if isinstance(key, tuple) and w[key[0], key[1]] != 0.0
+        ]
+        y = w.T @ x
+        for (s, d), f in lossy:
+            y[d] += (1.0 - f) * w[s, d] * (x[d] - x[s])
+        x = y
+        dist = float(np.sqrt(((x - x.mean(0)) ** 2).sum(1)).mean())
+        plane.observe(ctx, step=t, consensus=dist)
+        pen = sum(
+            compiler.degraded_round_penalty_s(payload, f) for _e, f in lossy
+        )
+        step_s.append(0.010 + pen)
+        tuner.observe(ctx, step=t, step_s=0.010 + pen)
+
+    advs = [a for a in plane.advisories if a.kind == "mixing_degraded"]
+    assert advs, "the lossy link never slowed mixing"
+    named = {
+        tuple(e) for a in advs for e in a.detail.get("suspect_edges", [])
+    }
+    assert (src, dst) in named
+    swap = next(d for d in tuner.decisions if d.action == "swap")
+    assert tu.mixing_matrix(bf.load_topology())[src, dst] == 0.0
+    recovered = [
+        s["mixing_efficiency"] for s in plane.samples
+        if s.get("mixing_efficiency") is not None and s["step"] > swap.step + 5
+    ]
+    assert recovered and recovered[-1] >= 0.9
+    assert advs[0].detail["mixing_efficiency"] < recovered[-1]
+    assert max(step_s) >= 2.0 * step_s[-1]
+    assert session.stale_dispatches == 0
+
+
 def test_migration_respects_dead_ranks():
     """Candidates are pre-repaired to the live set: after a kill +
     repair, a controller migration installs a matrix whose dead slot

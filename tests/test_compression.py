@@ -530,6 +530,48 @@ def test_int4_ef_removes_int4_noise_floor():
     assert spread_ef < 1e-3
 
 
+@pytest.mark.parametrize("dim", [512, 4096])
+def test_wire_tiers_ship_bytes_in_tier_order(dim):
+    """Over one Exp2 step, at whole 512-element scale groups, the six
+    wire tiers ship int4 < int8 < bf16 < fp32 bytes, the block-scale
+    sidecar priced in; each error-feedback tier ships exactly its base
+    tier's bytes, and int4 halves int8."""
+    plan = planlib.plan_from_topology(
+        tu.ExponentialTwoGraph(SIZE), weighted=True
+    )
+    b = {
+        w: plan.wire_bytes(dim, 4, wire=None if w == "fp32" else w)
+        for w in ("fp32", "bf16", "int8", "int8_ef", "int4", "int4_ef")
+    }
+    assert b["fp32"] == len(plan.rounds) * dim * 4
+    assert 0 < b["int4"] < b["int8"] < b["bf16"] < b["fp32"], b
+    assert b["int8_ef"] == b["int8"] and b["int4_ef"] == b["int4"], b
+    assert b["int8"] == 2 * b["int4"], b
+
+
+def test_int4_ef_consensus_no_worse_than_int8():
+    """On the same pure-consensus problem (zero gradients isolate the
+    wire), the 4-bit error-feedback tier reaches a consensus at least
+    as tight as plain int8's at half its wire bytes: error feedback
+    erases the coarser quantizer's floor."""
+    c = np.random.RandomState(23).randn(SIZE, 1024).astype(np.float32)
+    zero = {"w": jnp.zeros((SIZE, 1024), jnp.float32)}
+
+    def spread(compression):
+        opt = bf.DistributedNeighborAllreduceOptimizer(optax.sgd(0.0))
+        opt.compression = compression
+        params = {"w": bf.worker_values(lambda r: c[r])}
+        state = opt.init(params)
+        for _ in range(60):
+            params, state = opt.step(params, state, zero)
+            jax.block_until_ready((params, state))
+        w = np.asarray(params["w"])
+        return np.abs(w - w.mean(0)).max()
+
+    s8, s4ef = spread("int8"), spread("int4_ef")
+    assert s4ef <= s8, (s8, s4ef)
+
+
 def test_int4_ef_restricted_paths():
     opt = bf.DistributedAllreduceOptimizer(optax.sgd(0.1))
     opt.compression = "int4_ef"

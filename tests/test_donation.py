@@ -431,6 +431,5 @@ def test_tiers_on_touch_no_donated_array(cpu_devices, monkeypatch):
             assert _gauge("bluefog.step_donation_unused") == 0
         assert memory.active().samples, "the census never ran"
     finally:
-        for tier in (attribution, health, staleness, memory):
+        for tier in (attribution, health, staleness, memory, slo):
             tier.stop()
-        slo.activate(None)

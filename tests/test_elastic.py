@@ -63,6 +63,18 @@ def _init(n=SIZE):
     bf.init(devices=jax.devices("cpu")[:n])
 
 
+def _settle(*trees):
+    """Wait for a step before dispatching the next. With dozens of
+    unsettled collective programs queued on XLA:CPU's virtual devices
+    (a busy machine, the suite's compile cache off), one device's share
+    of a collective-permute can fail to reach its rendezvous, and the
+    runtime aborts the whole process at its 40 s termination timeout.
+    A loop that settles each step keeps one program in flight."""
+    import jax
+
+    jax.block_until_ready(trees)
+
+
 # -- fault-plan grammar -------------------------------------------------------
 
 
@@ -626,6 +638,7 @@ def test_chaos_kill_int8_converges_to_survivor_consensus(order):
         params, state = guard.step(
             params, state, {"w": bf.worker_values(lambda r: g[r])}
         )
+        _settle(params, state)
 
     assert session.stale_dispatches == 0
     assert len(session.repairs) == 1
@@ -1011,6 +1024,7 @@ def test_chaos_kill_ef_residuals_self_invalidate(wire):
     ef_sig_pre = ef_pre = None
     for t in range(60):
         params, state = guard.step(params, state, zero)
+        _settle(params, state)
         if t == 4:  # one step before the kill lands
             ef_sig_pre = opt._ef_sig
             ef_pre = opt._ef

@@ -189,6 +189,56 @@ def test_wire_summary_per_edge_dcn_accounting():
     assert ws["dcn_cut_ratio"] >= 8.0
 
 
+def test_dcn_cut_at_matched_consensus_rate():
+    """Against the cheapest flat Exp2 opponent that still contracts at
+    least as fast as the federated fabric (flat gossip every k-th step,
+    k the largest keeping its measured per-step rate no weaker), the
+    federated fabric's period-chosen int4 DCN leg ships >= 8x fewer
+    cross-pod bytes per step."""
+    n = 16
+    layout = fed.parse_pods("2x8", n)
+    period = fed.choose_dcn_period(layout, 0.985)["period"]
+    measured_fed = fed.simulate_consensus(
+        [(n, fed.intra_edges(layout))] * period
+        + [(n, fed.inter_edges(layout))],
+        steps=max(4, 256 // period), comm_steps_per_cycle=period,
+    )
+    measured_flat = fed.simulate_consensus(
+        [(n, fleetsim.base_edges(n, "exp2"))], steps=64
+    )
+    assert 0 < measured_flat < measured_fed < 1
+    k = max(1, int(np.floor(np.log(measured_flat) / np.log(measured_fed))))
+    # the matched flat opponent is at least as strong
+    assert measured_flat ** (1.0 / k) <= measured_fed + 1e-6
+    ws = fed.wire_summary(
+        layout, 1 << 20, itemsize=4, ici_wire=None,
+        dcn_wire_tier="int4", period=period,
+    )
+    cut = ws["flat_dcn_bytes_per_step"] / k / ws["dcn_wire_bytes_per_step"]
+    assert cut >= 8.0, (cut, k, period)
+
+
+def test_federation_report_renders_the_live_block(tmp_path, monkeypatch,
+                                                  capsys):
+    """tools/federation_report.py renders a health dump's federation
+    block: pods, gateways, DCN period and wire, predicted rate."""
+    import json
+
+    from tools import federation_report
+
+    monkeypatch.setenv(fed.PODS_ENV, "2x8")
+    monkeypatch.setenv(fed.DCN_PERIOD_ENV, "4")
+    block = fed.get_fabric(16).to_json()
+    dump = tmp_path / "health.json"
+    dump.write_text(json.dumps({"federation": block}))
+    assert federation_report.main(["--health", str(dump), "--json"]) == 0
+    assert json.loads(capsys.readouterr().out)["live"] == block
+    assert federation_report.main(["--health", str(dump)]) == 0
+    out = capsys.readouterr().out
+    assert "2 pods over 16 ranks" in out and "DCN every 4 steps" in out
+    assert federation_report.main([]) == 2
+
+
 # -- CommPlan lowering / link classes -----------------------------------------
 
 

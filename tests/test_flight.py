@@ -574,33 +574,49 @@ def test_launcher_flight_dir_env_and_artifacts(tmp_path):
     assert "trace_merge.py" in buf.getvalue()
 
 
-def test_flight_evidence_file_committed():
-    """FLIGHT_EVIDENCE.json (the committed BENCH_MODE=flight output)
-    carries the acceptance facts: <=1% recorder overhead, bitwise
-    on/off pin, merged-trace round counts matching the compiled plan,
-    and a postmortem that names the fault-plan-killed rank."""
-    path = os.path.join(REPO, "FLIGHT_EVIDENCE.json")
-    assert os.path.exists(path), "FLIGHT_EVIDENCE.json missing"
-    lines = [
-        json.loads(l) for l in open(path).read().splitlines()
-        if l.startswith("{")
+@pytest.mark.parametrize("fused", [False, True], ids=["opt_step", "fused"])
+def test_recorder_on_off_trajectory_bitwise(fused, monkeypatch):
+    """The recorder only writes host-side ring events: a training run with
+    it on is bitwise the run with it off, for the two-program
+    ``opt.step`` and the fused step alike, and only the run with it on
+    leaves events."""
+    import jax
+    import optax
+
+    rng = np.random.RandomState(0)
+    w0 = (rng.randn(16, 16) / 4).astype(np.float32)
+    xs = bf.worker_values(lambda r: rng.randn(4, 16).astype(np.float32))
+    grads = [
+        bf.worker_values(lambda r: rng.randn(16, 16).astype(np.float32))
+        for _ in range(5)
     ]
-    prov = [l for l in lines if l.get("metric") == "provenance"]
-    assert prov and prov[0]["git_sha"]
-    over = [
-        l for l in lines if l.get("metric") == "flight_recorder_overhead"
-    ]
-    assert over and over[0]["overhead_pct"] <= 1.0
-    assert over[0]["bitwise_identical"] is True
-    merge = [
-        l for l in lines if l.get("metric") == "flight_trace_merge"
-    ]
-    assert merge and merge[0]["merged_valid_json"]
-    assert merge[0]["per_step_rounds_match_plan"]
-    assert (
-        merge[0]["plan_rounds_reported"]
-        == merge[0]["plan_rounds_compiled"]
-    )
-    pm = [l for l in lines if l.get("metric") == "flight_postmortem"]
-    assert pm and pm[0]["named_correctly"] is True
-    assert pm[0]["dead_ranks_reported"] == [pm[0]["kill_rank"]]
+
+    def loss_fn(p, x):
+        return jnp.mean(jnp.tanh(x @ p["w"]) ** 2)
+
+    def run(on):
+        monkeypatch.setenv("BLUEFOG_FLIGHT", "1" if on else "0")
+        flight.reconfigure()
+        opt = bf.DistributedNeighborAllreduceOptimizer(
+            optax.sgd(0.05, momentum=0.9)
+        )
+        params = {"w": bf.worker_values(lambda r: w0)}
+        state = opt.init(params)
+        if fused:
+            train_step = bf.make_train_step(opt, loss_fn)
+            for _ in grads:
+                params, state, _loss = train_step(params, state, xs)
+        else:
+            for g in grads:
+                params, state = opt.step(params, state, {"w": g})
+        leaves = [
+            np.asarray(a) for a in jax.tree_util.tree_leaves((params, state))
+        ]
+        return leaves, len(flight.events())
+
+    off, n_off = run(False)
+    on, n_on = run(True)
+    assert n_off == 0 and n_on > 0
+    assert len(off) == len(on)
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a, b)

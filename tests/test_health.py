@@ -224,6 +224,52 @@ def test_observatory_measures_on_contract_efficiency():
     assert metrics.peek("bluefog.health.samples").value >= 25
 
 
+def _eager_decay(graph, steps=40):
+    """Drive the real eager combine (``bf.neighbor_allreduce``) on the
+    mesh from random iterates and feed each step's consensus distance
+    to a fresh plane; stop before the f32 rounding floor, where the fit
+    would measure the noise rather than the mixing rate."""
+    ctx = bf.get_context()
+    bf.set_topology(graph)
+    plane = health.start(interval=1)
+    rng = np.random.RandomState(0)
+    x = bf.worker_values(lambda r: rng.randn(4096).astype(np.float32))
+    d0, last = None, None
+    for t in range(steps):
+        x = bf.neighbor_allreduce(x)
+        xs = np.asarray(x, np.float64)
+        d = float(np.sqrt(((xs - xs.mean(0)) ** 2).sum(1)).mean())
+        d0 = d if d0 is None else d0
+        if d < d0 * 1e-4:
+            break
+        last = plane.observe(ctx, step=t, consensus=d)
+    health.stop()
+    return last
+
+
+@pytest.mark.parametrize("make", [tu.RingGraph, tu.ExponentialTwoGraph],
+                         ids=["ring", "exp2"])
+def test_real_combine_decays_at_the_spectral_rate(make):
+    """On the device combine, the measured consensus decay lands within
+    15 % (in log rate) of the spectral prediction, and the plane
+    projects a time to consensus."""
+    g = make(SIZE)
+    s = _eager_decay(g)
+    pred = tu.consensus_decay_rate(tu.mixing_matrix(g))
+    assert 0 < pred < 1
+    assert s["predicted_rate"] == pytest.approx(pred, abs=1e-9)
+    assert 0 < s["measured_rate"] < 1
+    assert abs(s["mixing_efficiency"] - 1.0) <= 0.15, s
+    assert s["time_to_eps_steps"] > 0
+
+
+def test_exp2_mixes_faster_than_ring_on_the_real_combine():
+    ring = _eager_decay(tu.RingGraph(SIZE))
+    exp2 = _eager_decay(tu.ExponentialTwoGraph(SIZE))
+    assert exp2["measured_rate"] < ring["measured_rate"]
+    assert exp2["time_to_eps_steps"] < ring["time_to_eps_steps"]
+
+
 def test_mixing_degraded_fires_and_names_injected_edge(tmp_path):
     """The chaos acceptance path: a lossy link measurably slows mixing
     below the spectral promise; the advisory fires on every surface

@@ -144,7 +144,8 @@ def test_quantized_temporaries_bytes_fused_arm():
     scale sidecar) — the encode output plus the one in-flight received
     copy — and never a full-width reconstruction. It must land below
     both the composite model AND the raw fp32 payload (4 B/elem), the
-    BENCH_ASSERT gate the evidence run enforces on measured bytes."""
+    bound tests/test_wire_kernels.py::test_fused_scratch_below_fp32_row
+    holds the measured bytes to."""
     f = scaling.quantized_temporaries_bytes
     # int8: packed = padded int8 lanes, sidecar = f32 scale per block
     assert f(4096, "int8", fused=True) == 2 * (4096 + (4096 // 512) * 4)

@@ -473,6 +473,34 @@ def test_metrics_on_off_bitwise_identical_fused(monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_unsampled_metric_steps_run_the_metrics_off_program(monkeypatch):
+    """Two-program sampling: with metrics on at interval 2, the sampled
+    steps build ONE extra program and every other step dispatches the
+    metrics-off program under its own cache key."""
+    monkeypatch.setenv("BLUEFOG_METRICS", "0")
+    ctx = bf.get_context()
+    c = np.random.RandomState(3).randn(SIZE, 300).astype(np.float32)
+    opt = FACTORIES["cta"](optax.sgd(0.1, momentum=0.9))
+    p = {"w": bf.worker_values(lambda r: c[r])}
+    s = opt.init(p)
+
+    def step_keys():
+        return {
+            k for k in ctx.op_cache
+            if isinstance(k, tuple) and k and k[0] == "opt_step"
+        }
+
+    p, s = opt.step(p, s, {"w": p["w"] - jnp.asarray(c)})
+    off = step_keys()
+    assert len(off) == 1
+    monkeypatch.setenv("BLUEFOG_METRICS", "1")
+    monkeypatch.setenv("BLUEFOG_METRICS_INTERVAL", "2")
+    for _ in range(4):
+        p, s = opt.step(p, s, {"w": p["w"] - jnp.asarray(c)})
+    on = step_keys()
+    assert off < on and len(on - off) == 1
+
+
 def test_metrics_drain_interval(monkeypatch):
     """No registry update before the interval elapses; the periodic
     path (swap at one boundary, fold at the next — no explicit flush)

@@ -22,10 +22,6 @@ Three layers of proof, mirroring the repo's HLO-verification style
 """
 
 import functools
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import networkx as nx
@@ -41,8 +37,6 @@ from bluefog_tpu.collective import compiler, inner, plan as planlib
 
 SIZE = 8
 AXIS = "workers"
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_spmd(fn, *arrays, out_specs=P(AXIS)):
@@ -96,19 +90,24 @@ def test_auto_never_worse_than_offset_and_reaches_bound():
         assert res.rounds == res.lower_bound or not edges
 
 
-def test_circulant_topologies_keep_offset_fast_path():
-    for g, rounds in (
-        (topo.ExponentialTwoGraph(SIZE), 3),
-        (topo.RingGraph(SIZE), 2),  # offsets {+1, -1}; the self loop is no round
-        (topo.FullyConnectedGraph(SIZE), 7),
-    ):
-        plan = planlib.plan_from_topology(g, weighted=True)
-        assert plan.compile_info.method == "offset"
-        assert len(plan.rounds) == rounds
-        # circulant rounds are FULL permutations riding ICI
-        assert all(len(r.perm) == SIZE for r in plan.rounds)
-        naive = planlib.plan_from_topology(g, weighted=True, method="offset")
-        assert plan.perms == naive.perms
+@pytest.mark.parametrize(
+    "make,rounds",
+    [
+        (topo.ExponentialTwoGraph, 3),
+        (topo.RingGraph, 2),  # offsets {+1, -1}; the self loop is no round
+        (topo.FullyConnectedGraph, 7),
+    ],
+    ids=["exp2", "ring", "full"],
+)
+def test_circulant_topologies_keep_offset_fast_path(make, rounds):
+    g = make(SIZE)
+    plan = planlib.plan_from_topology(g, weighted=True)
+    assert plan.compile_info.method == "offset"
+    assert len(plan.rounds) == rounds
+    # circulant rounds are FULL permutations riding ICI
+    assert all(len(r.perm) == SIZE for r in plan.rounds)
+    naive = planlib.plan_from_topology(g, weighted=True, method="offset")
+    assert plan.perms == naive.perms
 
 
 def test_compile_cache_dedupes_repeated_lowerings():
@@ -157,6 +156,8 @@ def test_random_regular_digraph_properties(degree):
         ("star", lambda: topo.StarGraph(SIZE)),
         ("mesh2d", lambda: topo.MeshGrid2DGraph(SIZE)),
         ("random_d2", lambda: topo.RandomRegularDigraph(SIZE, 2, seed=3)),
+        ("random_d3", lambda: topo.RandomRegularDigraph(SIZE, 3, seed=1)),
+        ("exp2", lambda: topo.ExponentialTwoGraph(SIZE)),
     ],
 )
 def test_optimized_hlo_emits_bound_many_permutes(name, make):
@@ -170,17 +171,21 @@ def test_optimized_hlo_emits_bound_many_permutes(name, make):
     assert cp["count"] <= info.offset_rounds
 
 
-def test_random_digraph_hlo_beats_naive_lowering():
-    g = topo.RandomRegularDigraph(SIZE, 2, seed=3)
+@pytest.mark.parametrize("degree,seed", [(2, 3), (3, 1)])
+def test_random_digraph_hlo_beats_naive_lowering(degree, seed):
+    """A random degree-d digraph lowers to exactly its König bound of d
+    rounds, checked in the compiled HLO; the offset lowering takes more."""
+    g = topo.RandomRegularDigraph(SIZE, degree, seed=seed)
     optimized = planlib.plan_from_topology(g, weighted=True)
     naive = planlib.plan_from_topology(g, weighted=True, method="offset")
     assert optimized.compile_info.method == "coloring"
-    assert len(optimized.rounds) == 2 < len(naive.rounds)
+    assert optimized.compile_info.lower_bound == degree
+    assert len(optimized.rounds) == degree < len(naive.rounds)
     n_stats = scaling.gossip_comm_stats(naive, 256)
     o_stats = scaling.gossip_comm_stats(optimized, 256)
-    assert o_stats["collective-permute"]["count"] == 2
+    assert o_stats["collective-permute"]["count"] == degree
     assert (
-        n_stats["collective-permute"]["count"] == len(naive.rounds) > 2
+        n_stats["collective-permute"]["count"] == len(naive.rounds) > degree
     )
 
 
@@ -511,27 +516,79 @@ def test_choose_chunks_env_override_and_forced_methods(
         ) == 1
 
 
-def test_choose_chunks_pareto_crossover(clean_cost_model):
-    """Under the (class-constant) cost model: small payloads stay at the
-    latency-optimal k=1, large payloads pipeline, and a calibration
-    that measured NO pipelining (pipeline_eff=0) never chunks — the
-    chooser can only pick what the fabric delivered."""
-    g = topo.RandomRegularDigraph(SIZE, 2, seed=3)
+# the payload sizes of the chunk chooser's sweep: 64 KiB to 100 MiB
+SWEEP_BYTES = (64 * 1024, 1 << 20, 16 << 20, 100 * 1024 * 1024)
+
+
+@pytest.mark.parametrize(
+    "payload", SWEEP_BYTES, ids=["64KiB", "1MiB", "16MiB", "100MiB"]
+)
+def test_choose_chunks_pareto_crossover(clean_cost_model, payload):
+    """On the degree-3 random digraph, at each payload of the sweep, the
+    chooser picks at least one chunk, and exactly the cost model's
+    cheapest power of two, under the class constants and under an
+    installed calibration alike. Small payloads stay at the
+    latency-optimal k=1, large payloads pipeline, and a calibration that
+    measured NO pipelining (pipeline_eff=0) never chunks — the chooser
+    can only pick what the fabric delivered."""
+    g = topo.RandomRegularDigraph(SIZE, 3, seed=1)
     compiled = planlib.plan_from_topology(g, weighted=True).compile_info
-    assert compiled.rounds >= 2
-    small, big = 64 * 1024, 100 * 1024 * 1024
-    assert compiler.choose_chunks(compiled, small, n_elems=small // 4) == 1
-    k_big = compiler.choose_chunks(compiled, big, n_elems=big // 4)
-    assert k_big > 1
-    # chunked cost at the chosen k beats the serial plan cost
+    assert compiled.rounds == 3
+    elems = payload // 4
     cong = compiled.congestion
-    assert compiler.pipelined_cost_s(big, k_big, cong) < (
-        compiler.pipelined_cost_s(big, 1, cong)
+
+    def cheapest_k():
+        kmax = min(compiler.MAX_CHUNKS, elems // compiler.CHUNK_ALIGN_ELEMS)
+        ks = [1]
+        while ks[-1] * 2 <= kmax:
+            ks.append(ks[-1] * 2)
+        return min(
+            ks, key=lambda k: (compiler.pipelined_cost_s(payload, k, cong), k)
+        )
+
+    k = compiler.choose_chunks(compiled, payload, n_elems=elems)
+    assert k >= 1 and k == cheapest_k()
+    if payload <= 64 * 1024:
+        assert k == 1
+    if payload >= 100 * 1024 * 1024:
+        assert k > 1
+        # chunked cost at the chosen k beats the serial plan cost
+        assert compiler.pipelined_cost_s(payload, k, cong) < (
+            compiler.pipelined_cost_s(payload, 1, cong)
+        )
+    # a calibration of the probe's shape (slower link, partial overlap)
+    # moves the pick to its own cheapest k
+    compiler.set_calibration(
+        2e-5, 5e9, pipeline_eff=0.5, source="measured-probe"
     )
+    k_cal = compiler.choose_chunks(compiled, payload, n_elems=elems)
+    assert k_cal >= 1 and k_cal == cheapest_k()
     compiler.set_calibration(
         1e-3, 1e9, pipeline_eff=0.0, source="test"
     )
-    assert compiler.choose_chunks(compiled, big, n_elems=big // 4) == 1
+    assert compiler.choose_chunks(compiled, payload, n_elems=elems) == 1
+
+
+def test_calibrate_probe_installs_measured_constants(clean_cost_model):
+    """``calibrate()`` probes the suite's mesh and installs what it
+    measured: positive alpha and beta, a pipelining share in [0, 1], and
+    the chooser prices with them from then on."""
+    cal = compiler.calibrate(
+        force=True, small_elems=1024, large_elems=1 << 15, steps=2,
+        windows=1,
+    )
+    assert cal["source"] == "measured-probe"
+    assert cal["probe_devices"] == min(len(jax.devices()), 8)
+    assert cal["alpha_s"] > 0 and cal["beta_bytes_per_s"] > 0
+    assert 0.0 <= cal["pipeline_eff"] <= 1.0
+    assert compiler.calibration() == cal
+    # an installed calibration is honored, not re-probed
+    assert compiler.calibrate() == cal
+    g = topo.RandomRegularDigraph(SIZE, 3, seed=1)
+    compiled = planlib.plan_from_topology(g, weighted=True).compile_info
+    big = 100 * 1024 * 1024
+    k, _cost = compiler.chunk_option(big, compiled.congestion, big // 4)
+    assert compiler.choose_chunks(compiled, big, n_elems=big // 4) == k
 
 
 def test_calibration_roundtrip(clean_cost_model):
@@ -663,49 +720,3 @@ def test_optimizer_chunked_trajectory_bitwise(order, wire, monkeypatch):
     t1, t4 = run(1), run(4)
     for a, b in zip(t1, t4):
         np.testing.assert_array_equal(a, b)
-
-
-# -- acceptance: 16-rank sparse digraph via BENCH_MODE=plan ------------------
-
-
-def test_bench_plan_mode_16_rank_bound():
-    """End-to-end acceptance: `BENCH_MODE=plan` on a 16-device virtual
-    mesh reports star / mesh2d / random lines; the degree-3 random
-    digraph lowers to exactly 3 rounds, verified from compiled HLO."""
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("XLA_FLAGS", "JAX_PLATFORMS")
-    }
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["BENCH_MODE"] = "plan"
-    env["BENCH_STEPS"] = "2"
-    env["BENCH_WINDOWS"] = "1"
-    env["BENCH_PLAN_PAYLOAD_ELEMS"] = "1024"
-    # keep the smoke fast: tiny payload sweep (the full 64KiB-100MiB
-    # sweep is the committed PLAN_SWEEP_EVIDENCE.json run)
-    env["BENCH_PLAN_SWEEP_BYTES"] = "65536,262144"
-    env["BENCH_PLAN_SWEEP_STEPS"] = "2"
-    env["BENCH_PLAN_SWEEP_WINDOWS"] = "1"
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=900, env=env, cwd=REPO,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = {
-        l["topology"]: l
-        for l in map(json.loads, out.stdout.splitlines())
-        if l.get("metric") == "plan_compiler"
-    }
-    assert {"star", "mesh2d", "random_d3"} <= set(lines)
-    for l in lines.values():
-        assert l["optimized_rounds"] <= l["naive_rounds"], l
-        assert l["hlo_collective_permutes"] == l["optimized_rounds"], l
-        assert l["optimized_ms_per_step"] > 0, l
-    rand = lines["random_d3"]
-    assert rand["n_workers"] == 16
-    assert rand["optimized_rounds"] == 3 == rand["lower_bound"], rand
-    assert rand["naive_rounds"] > 3, rand
-    assert rand["decomposition"] == "coloring", rand
-    # circulant fast path: exp2 keeps its offset rounds
-    assert lines["exp2"]["decomposition"] == "offset"
-    assert lines["exp2"]["optimized_rounds"] == lines["exp2"]["naive_rounds"]

@@ -692,6 +692,28 @@ def test_zero2_quantized_scatter_tiers(monkeypatch):
     assert resid > 0
 
 
+@pytest.mark.parametrize("wire,block_bytes", [
+    ("bf16", 1024), ("int8", 516), ("int4", 258),
+    ("int8_ef", 516), ("int4_ef", 258),
+])
+def test_scatter_wire_tiers_at_exact_block_scale_ratios(wire, block_bytes):
+    """Slots sit on the 512-element grid, so each quantized tier's
+    reduce-scatter bytes are EXACTLY its block-scale ratio of fp32's
+    (a 512-element block ships 512 payload bytes plus a 4-byte scale for
+    int8, 256 plus a 2-byte scale for int4), the error-feedback tiers
+    ship their base tier's bytes, and every tier stays under the fp32
+    allreduce it replaces."""
+    lay = sharding.build_layout([("float32", 262145)], range(SIZE), SIZE)
+    slot = lay.groups[0].slot
+    assert slot % 512 == 0
+    fp32 = scaling.reduce_scatter_bytes(((slot, 4),), SIZE)
+    assert fp32 == sharding.scatter_wire_bytes(lay)
+    b = scaling.reduce_scatter_bytes(((slot, 4),), SIZE, wire=wire)
+    # one 512-element block is 2048 bytes at fp32
+    assert b * 2048 == fp32 * block_bytes
+    assert b < fp32 < sharding.allreduce_wire_bytes(lay)
+
+
 def test_zero1_program_verbatim_when_grads_off(monkeypatch):
     """BLUEFOG_SHARD=1 WITHOUT gradient sharding is the PR-14 program
     verbatim: layout carries no grads flag, zero scatter-tagged cache

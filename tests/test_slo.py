@@ -161,6 +161,31 @@ def test_slow_ramp_caught_by_slow_window_not_fast():
     assert ticket.detail["burn"] == pytest.approx(2.0)
 
 
+def test_default_catalog_covers_every_tier_and_costs_nothing_idle():
+    """The shipped catalog holds one objective per tier (eight, names
+    unique, each serializable); on a session with every tier off each
+    resolver finds no signal, so sampling skips them all without
+    charging a budget or raising an alert."""
+    eng = slo.SLOEngine(interval=1, canary=False)
+    names = [o.name for o in eng.objectives]
+    assert len(names) >= 8 and len(set(names)) == len(names)
+    assert {"step_time", "mixing_efficiency", "param_age",
+            "mass_residual", "memory_headroom", "async_participation",
+            "ici_consensus", "dcn_consensus"} <= set(names)
+    for o in eng.objectives:
+        row = json.loads(json.dumps(o.to_json()))
+        assert row["name"] == o.name and "resolver" not in row
+        assert o.comparison in ("le", "ge")
+        assert o.fast_window < o.slow_window <= o.window
+    for t in range(5):
+        eng.observe(bf.get_context(), step=t)
+    rep = eng.report()
+    assert rep["samples_run"] == 5 and not rep["alerts"]
+    for snap in rep["objectives"]:
+        assert snap["last_value"] is None
+        assert snap["budget"]["spent"] == 0
+
+
 def test_none_and_non_finite_values_skip_without_budget_charge():
     eng = _engine()
     for t in range(40):

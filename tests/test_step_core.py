@@ -156,3 +156,49 @@ def test_epilogue_calls_each_hook_once_in_order(path, monkeypatch):
         assert [(name, at) for name, at, _ in calls] == [
             (name, 1) for name in HOOKS
         ]
+
+
+@pytest.mark.parametrize("path", ["fused", "opt_step"])
+@pytest.mark.parametrize("tier", ["health", "staleness", "autotune", "slo"])
+def test_a_tier_sampling_every_step_leaves_the_trajectory_bitwise(
+    tier, path
+):
+    """A tier on and sampling every communicating step only reads what
+    the dispatch returned: the trajectory equals the tier-off run to the
+    bit, and the run builds no more train-step programs than with the
+    tier off."""
+    module = {
+        "health": opt_mod.health_mod, "staleness": opt_mod.staleness_mod,
+        "autotune": opt_mod.autotune_mod, "slo": opt_mod.slo_mod,
+    }[tier]
+    ctx = bf.get_context()
+    params, c = _tree(0), _tree(1)
+
+    def train_keys():
+        return {
+            k for k in ctx.op_cache if isinstance(k, tuple) and k
+            and k[0] in ("opt_step", "opt_fused_step")
+        }
+
+    def run(on):
+        before = train_keys()
+        if on:
+            module.start(interval=1)
+        try:
+            opt = _optimizer("cta", "static")
+            step = _stepper(opt, path, c)
+            p, s = _copy(params), opt.init(params)
+            for _ in range(4):
+                p, s = step(p, s)
+            if on:
+                assert module.active().samples, "the tier never sampled"
+            built = len(train_keys() - before)
+            return jax.tree_util.tree_leaves((p, s)), built
+        finally:
+            module.stop()
+
+    off, built_off = run(False)
+    on, built_on = run(True)
+    assert built_on == built_off == 1
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

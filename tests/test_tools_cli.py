@@ -9,6 +9,7 @@ lazy-import regression guard — the time bound is what pins it.
 """
 
 import glob
+import json
 import os
 import subprocess
 import sys
@@ -82,11 +83,47 @@ def test_tools_enumerated():
     drops a tool from the smoke test should be deliberate)."""
     names = {os.path.basename(t) for t in TOOLS}
     assert {
-        "autotune_report.py", "bench_diff.py", "doctor.py",
+        "autotune_report.py", "doctor.py",
         "federation_report.py", "fleet_report.py",
         "fleetsim_report.py", "memory_report.py",
         "metrics_report.py",
         "shard_plan.py", "slo_report.py", "staleness_report.py",
         "trace_merge.py",
-        "hlo_overlap_scan.py", "hlo_dump.py", "perf_probe.py",
+        "hlo_overlap_scan.py", "hlo_dump.py",
     } <= names
+
+
+def test_metrics_report_summarizes_jsonl(tmp_path, capsys):
+    """tools/metrics_report.py digests a JSONL metrics file: min/max/last
+    per series, snapshot count, stall count — the CLI a fleet operator
+    points at BLUEFOG_METRICS_FILE output."""
+    from tools import metrics_report
+
+    path = tmp_path / "run.jsonl"
+    rows = [
+        {"ts": 1.0, "metrics": {
+            "bluefog.gossip.disagreement": {"type": "gauge", "value": 0.5},
+            "bluefog.stalls": {"type": "counter", "value": 0},
+            "bluefog.lat": {"type": "histogram", "count": 1, "sum": 2.0,
+                            "min": 2.0, "max": 2.0, "last": 2.0},
+        }},
+        {"ts": 2.0, "metrics": {
+            "bluefog.gossip.disagreement": {"type": "gauge", "value": 0.2},
+            "bluefog.stalls": {"type": "counter", "value": 3},
+        }},
+    ]
+    path.write_text(
+        "\n".join(json.dumps(r) for r in rows) + "\nnot-json\n"
+    )
+    assert metrics_report.main([str(path), "--json"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert report["snapshots"] == 2 and report["skipped_lines"] == 1
+    assert report["stall_count"] == 3
+    dis = report["series"]["bluefog.gossip.disagreement"]
+    assert dis["min"] == 0.2 and dis["max"] == 0.5 and dis["last"] == 0.2
+    assert report["series"]["bluefog.lat"]["last"] == 2.0
+    # human-readable mode renders a table without crashing
+    assert metrics_report.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "bluefog.gossip.disagreement" in out
+    assert "stalls:    3" in out

@@ -12,8 +12,7 @@ plus the pins that anchor both implementations to the shared numpy
 wire reference (``collective/wire_ref.py``), the exhaustive nibble
 sign-extension oracle, the cache-token semantics that keep toggles
 from dispatching stale programs, and the measured-scratch gate the
-kernels exist for (fused temp bytes below the fp32 row — the full
-evidence lives in QUANT_EVIDENCE's quant_kernel rows).
+kernels exist for (fused temp bytes below the fp32 row).
 """
 
 import numpy as np
@@ -358,7 +357,7 @@ def test_push_sum_mass_conserved_with_kernels_on(wire, monkeypatch):
 
 
 def test_fused_scratch_below_fp32_row(monkeypatch):
-    """Measured-XLA-scratch smoke of the QUANT_EVIDENCE gate: the fused
+    """Measured XLA scratch, int8 and int4: the fused
     combine's temp bytes land BELOW the uncompressed fp32 combine's
     (the full-width temporary never materializes), while the composite
     path still stages at least the full-width reconstruction."""

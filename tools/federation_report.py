@@ -1,31 +1,18 @@
 #!/usr/bin/env python
 # Copyright 2026. Licensed under the Apache License, Version 2.0.
-"""Reconstruct the two-level federation story from its artifacts.
+"""Render the live two-level federation fabric from a health dump.
 
 The hierarchical fabric (:mod:`bluefog_tpu.federation`,
-docs/federation.md) leaves its acceptance evidence in the committed
-``FEDERATE_EVIDENCE.json`` (the ``BENCH_MODE=federate`` JSON-lines
-family) and its live state in a health dump's ``federation`` block
-(``/fleet``, ``bf.health``). This tool renders either into the
-operator's first read:
-
-- the **calibration block** (per-link-class alpha-beta constants in
-  force when the artifact was produced — ici vs dcn),
-- the **period table** (every candidate DCN period the spectral
-  scorer priced, the chosen one, predicted vs measured composed rate),
-- the **wire block** (per-leg bytes per communicating step, the
-  matched-rate flat opponent, the DCN cut ratio),
-- the **pod-loss block** (repair events, loss class, gateway
-  re-election, stale dispatches),
-- the **dispatch block** (live per-leg counters and their
-  reconciliation),
-- a verdict line.
+docs/federation.md) publishes its live state in a health dump's
+``federation`` block (``/fleet``, ``bf.health``): the pod layout, the
+elected gateways, the DCN period and wire, and the predicted composed
+consensus rate. This tool renders that block as the operator's first
+read.
 
 Usage::
 
-    python tools/federation_report.py FEDERATE_EVIDENCE.json
     python tools/federation_report.py --health /tmp/health.json
-    python tools/federation_report.py FEDERATE_EVIDENCE.json --json
+    python tools/federation_report.py --health /tmp/health.json --json
 
 No jax import, no live fabric needed. Exit status 0 on a parseable
 input set, 2 when nothing could be read.
@@ -34,21 +21,7 @@ input set, 2 when nothing could be read.
 import argparse
 import json
 import sys
-from typing import List, Optional
-
-
-def load_lines(path: str) -> List[dict]:
-    rows = []
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                rows.append(json.loads(line))
-            except json.JSONDecodeError:
-                continue
-    return rows
+from typing import Optional
 
 
 def load_health_federation(path: str) -> Optional[dict]:
@@ -64,165 +37,29 @@ def load_health_federation(path: str) -> Optional[dict]:
     return None
 
 
-def build_report(rows: List[dict],
-                 health_fed: Optional[dict] = None) -> dict:
-    def first(metric):
-        return next(
-            (r for r in rows if r.get("metric") == metric), None
-        )
-
-    prov = first("provenance")
-    period = first("federate_period")
-    wire = first("federate_wire")
-    podloss = first("federate_podloss")
-    dispatch = first("federate_dispatch")
-    clean = None
-    if podloss is not None:
-        clean = (
-            podloss.get("repair_events") == 1
-            and podloss.get("stale_dispatches") == 0
-        )
-    reconciled = None
-    if dispatch is not None:
-        reconciled = dispatch.get("total_wire_bytes") == (
-            (dispatch.get("ici_wire_bytes") or 0)
-            + (dispatch.get("dcn_wire_bytes") or 0)
-        )
-    return {
-        "calibration": (
-            (prov or {}).get("calibration_link_classes") or {}
-        ),
-        "period": period,
-        "wire": wire,
-        "podloss": podloss,
-        "dispatch": dispatch,
-        "live": health_fed,
-        "verdict": {
-            "period_met": period.get("met") if period else None,
-            "rate_within_tolerance": (
-                period.get("abs_err", 0) <= period.get("tolerance", 0)
-                if period else None
-            ),
-            "dcn_cut_ratio_matched": (
-                wire.get("dcn_cut_ratio_matched") if wire else None
-            ),
-            "pod_loss_one_clean_event": clean,
-            "counters_reconcile": reconciled,
-        },
-    }
+def build_report(health_fed: Optional[dict]) -> dict:
+    return {"live": health_fed}
 
 
 def render(report: dict) -> str:
-    out = []
-    cal = report["calibration"]
-    if cal:
-        out.append("== calibration (per link class) ==")
-        for cls, c in sorted(cal.items()):
-            out.append(
-                f"  {cls:>4}: alpha={c.get('alpha_s')}s "
-                f"beta={c.get('beta_bytes_per_s'):.3g} B/s "
-                f"pipeline_eff={c.get('pipeline_eff')} "
-                f"source={c.get('source')}"
-            )
-        out.append("")
-    p = report["period"]
-    if p:
-        out.append(
-            f"== DCN period (target rate {p['target_rate']}, "
-            f"{p['pods']} pods of {p['n'] // p['pods']}) =="
-        )
-        out.append(f"{'T':>4}  {'rate/step':>10}  {'window slem':>12}")
-        for row in p.get("table", []):
-            mark = "  <-- chosen" if (
-                row["period"] == p["chosen_period"]
-            ) else ""
-            out.append(
-                f"{row['period']:>4}  {row['rate']:>10.6f}  "
-                f"{row['slem']:>12.6f}{mark}"
-            )
-        out.append(
-            f"predicted {p['predicted_rate']:.6f} vs measured "
-            f"{p['measured_rate']:.6f} (|err| {p['abs_err']} <= "
-            f"{p['tolerance']}: "
-            f"{p['abs_err'] <= p['tolerance']})"
-        )
-        out.append("")
-    w = report["wire"]
-    if w:
-        out.append("== wire (per communicating step) ==")
-        out.append(
-            f"federated DCN: {w['fed_dcn_bytes_per_step']:.0f} B on "
-            f"{w['dcn_wire']} every {w['dcn_period']} steps; flat "
-            f"opponent (every {w['flat_gossip_every']}th step, "
-            f"measured rate {w['measured_rate_flat_matched']} vs fed "
-            f"{w['measured_rate_fed']}): "
-            f"{w['flat_dcn_bytes_per_step_matched']:.0f} B over "
-            f"{w['flat_cross_pod_edges']} cross-pod edges"
-        )
-        out.append(
-            f"DCN cut at matched rate: "
-            f"x{w['dcn_cut_ratio_matched']} (all-int4 flat variant, "
-            f"unasserted: x{w['dcn_cut_ratio_flat_int4_unasserted']})"
-        )
-        out.append("")
-    pl = report["podloss"]
-    if pl:
-        out.append("== pod loss ==")
-        out.append(
-            f"pod {pl['pod_lost']} of {pl['pods']} "
-            f"({pl['ranks_lost']} ranks) lost: "
-            f"{pl['repair_events']} repair event(s) "
-            f"[{pl['loss_class']}], "
-            f"stale_dispatches={pl['stale_dispatches']}, "
-            f"gateways now {pl['gateways_after']} "
-            f"(changed: {pl['gateway_change']}), "
-            f"{pl['event_ms']} ms"
-        )
-        out.append("")
-    d = report["dispatch"]
-    if d:
-        out.append("== live dispatch ==")
-        out.append(
-            f"{d['devices']} devices / {d['pods']} pods, "
-            f"{d['steps']} steps ({d['dcn_events']} DCN events on "
-            f"{d['dcn_wire']}): ici={d['ici_wire_bytes']:.0f} B, "
-            f"dcn={d['dcn_wire_bytes']:.0f} B, "
-            f"total={d['total_wire_bytes']:.0f} B, "
-            f"mean_preserved={d['mean_preserved']}"
-        )
-        out.append("")
     live = report["live"]
-    if live:
-        out.append("== live fabric (health dump) ==")
-        layout = live.get("layout", {})
-        out.append(
-            f"{layout.get('n_pods')} pods over {layout.get('size')} "
-            f"ranks (spec {layout.get('spec')!r}); gateways "
-            f"{live.get('gateways')}; DCN every "
-            f"{live.get('dcn_period')} steps on {live.get('dcn_wire')}"
-            f"; predicted rate {live.get('predicted_rate')}"
-        )
-        out.append("")
-    v = report["verdict"]
-    out.append(
-        f"verdict: period_met={v['period_met']} "
-        f"rate_ok={v['rate_within_tolerance']} "
-        f"dcn_cut=x{v['dcn_cut_ratio_matched']} "
-        f"pod_loss_clean={v['pod_loss_one_clean_event']} "
-        f"counters_reconcile={v['counters_reconcile']}"
+    if not live:
+        return "no federation block (BLUEFOG_PODS unset on that run)"
+    layout = live.get("layout", {})
+    return (
+        "== live fabric (health dump) ==\n"
+        f"{layout.get('n_pods')} pods over {layout.get('size')} "
+        f"ranks (spec {layout.get('spec')!r}); gateways "
+        f"{live.get('gateways')}; DCN every "
+        f"{live.get('dcn_period')} steps on {live.get('dcn_wire')}"
+        f"; predicted rate {live.get('predicted_rate')}"
     )
-    return "\n".join(out)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
         formatter_class=argparse.RawDescriptionHelpFormatter,
-    )
-    ap.add_argument(
-        "evidence", nargs="*",
-        help="FEDERATE_EVIDENCE.json (or any JSON-lines evidence file "
-             "carrying federate_* rows)",
     )
     ap.add_argument(
         "--health", action="append", default=[],
@@ -235,14 +72,7 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
-    rows: List[dict] = []
     readable = 0
-    for path in args.evidence:
-        try:
-            rows.extend(load_lines(path))
-            readable += 1
-        except OSError as e:
-            print(f"unreadable: {path}: {e}", file=sys.stderr)
     health_fed = None
     for path in args.health:
         fed = load_health_federation(path)
@@ -252,7 +82,7 @@ def main(argv=None) -> int:
     if not readable:
         print("no readable inputs", file=sys.stderr)
         return 2
-    report = build_report(rows, health_fed)
+    report = build_report(health_fed)
     if args.json:
         print(json.dumps(report, indent=2))
     else:

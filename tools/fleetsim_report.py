@@ -1,16 +1,12 @@
 #!/usr/bin/env python
 # Copyright 2026. Licensed under the Apache License, Version 2.0.
-"""Reconstruct a fleet-simulator storm timeline from its artifacts.
+"""Reconstruct a fleet-simulator storm timeline from its event dump.
 
 The fleet simulator (:mod:`bluefog_tpu.fleetsim`, docs/fleetsim.md)
-leaves two artifact kinds: the committed ``FLEETSCALE_EVIDENCE.json``
-(the ``BENCH_MODE=fleetscale`` JSON-lines family) and the optional
-per-run JSONL event dump (``BLUEFOG_FLEETSIM_FILE``). This tool joins
-either — or both — into the storm timeline an operator reads first:
+appends one JSON line per virtual repair, rejoin, advisory and decision
+to its event dump (``BLUEFOG_FLEETSIM_FILE``). This tool joins one or
+more dumps into the storm timeline an operator reads first:
 
-- the **event scaling table** (per-membership-event repair cost over
-  the N sweep, growth exponent, dense-baseline extrapolation with its
-  disclosed model),
 - the **storm timeline** (step-ordered repairs with detected ranks,
   survivor count, epoch, topology version, per-event cost; whole-pod /
   whole-region outages rendered as their own loss class with the
@@ -18,14 +14,12 @@ either — or both — into the storm timeline an operator reads first:
   flagged),
 - the **decision block** (controller candidates, chosen topology,
   measured decision latency),
-- the headline verdict line: stale dispatches (must be 0), repairs,
-  survivor count.
+- the verdict line: repair events.
 
 Usage::
 
-    python tools/fleetsim_report.py FLEETSCALE_EVIDENCE.json
     python tools/fleetsim_report.py --dump /tmp/fleetsim.jsonl
-    python tools/fleetsim_report.py FLEETSCALE_EVIDENCE.json --json
+    python tools/fleetsim_report.py --dump /tmp/fleetsim.jsonl --json
 
 No jax import, no live fleet needed. Exit status 0 on a parseable
 input set, 2 when nothing could be read.
@@ -52,17 +46,7 @@ def load_lines(path: str) -> List[dict]:
 
 
 def build_report(rows: List[dict]) -> dict:
-    scaling = next(
-        (r for r in rows if r.get("metric") == "fleetscale_event_scaling"),
-        None,
-    )
-    storm = next(
-        (r for r in rows if r.get("metric") == "fleetscale_storm"), None
-    )
-    decisions = [
-        r for r in rows
-        if r.get("metric") in ("fleetscale_decision", "fleetsim_decision")
-    ]
+    decisions = [r for r in rows if r.get("metric") == "fleetsim_decision"]
     repairs = sorted(
         (r for r in rows if r.get("metric") == "fleetsim_repair"),
         key=lambda r: r.get("step", 0),
@@ -78,22 +62,13 @@ def build_report(rows: List[dict]) -> dict:
     for r in repairs:
         if worst is None or r.get("event_ms", 0) > worst.get("event_ms", 0):
             worst = r
-    stale = storm["stale_dispatches"] if storm else None
     return {
-        "scaling": scaling,
-        "storm": storm,
         "decisions": decisions,
         "repairs": repairs,
         "rejoins": rejoins,
         "advisories": advisories,
         "worst_event": worst,
-        "verdict": {
-            "stale_dispatches": stale,
-            "clean": (stale == 0) if stale is not None else None,
-            "repair_events": len(repairs) if repairs else (
-                storm.get("repair_events") if storm else 0
-            ),
-        },
+        "verdict": {"repair_events": len(repairs)},
     }
 
 
@@ -116,36 +91,6 @@ def _loss_label(r: dict) -> str:
 
 def render(report: dict) -> str:
     out = []
-    scaling = report["scaling"]
-    if scaling:
-        out.append("== event scaling "
-                   f"({scaling['topology']}, {scaling['policy']}) ==")
-        out.append(f"{'N':>6}  {'event_ms':>10}  {'max_ms':>10}")
-        for c in scaling["cells"]:
-            out.append(f"{c['n']:>6}  {c['event_ms_mean']:>10.4f}  "
-                       f"{c['event_ms_max']:>10.4f}")
-        out.append(
-            f"growth exponent {scaling['growth_exponent']} "
-            f"(sublinear: {scaling['sublinear']}); dense baseline "
-            f"extrapolated to N=1024: "
-            f"{scaling['dense_at_1024_ms_extrapolated']} ms "
-            f"(x{scaling['speedup_at_1024_extrapolated']} vs sparse)"
-        )
-        out.append(f"  model: {scaling['dense_extrapolation_model']}")
-        out.append("")
-    storm = report["storm"]
-    if storm:
-        out.append("== storm ==")
-        out.append(
-            f"N={storm['n']} killed={storm['killed']} "
-            f"({100 * storm['fraction']:.0f}%) "
-            f"live_after={storm['live_after']} "
-            f"repairs={storm['repair_events']} "
-            f"stale_dispatches={storm['stale_dispatches']} "
-            f"worst_event={storm['worst_event_ms']} ms"
-        )
-        out.append(f"advisories: {', '.join(storm['advisories']) or '-'}")
-        out.append("")
     if report["repairs"]:
         out.append("== repair timeline ==")
         for r in report["repairs"]:
@@ -181,10 +126,7 @@ def render(report: dict) -> str:
             )
     v = report["verdict"]
     out.append("")
-    out.append(
-        f"verdict: stale_dispatches={v['stale_dispatches']} "
-        f"clean={v['clean']} repair_events={v['repair_events']}"
-    )
+    out.append(f"verdict: repair_events={v['repair_events']}")
     return "\n".join(out)
 
 
@@ -192,11 +134,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
         formatter_class=argparse.RawDescriptionHelpFormatter,
-    )
-    ap.add_argument(
-        "evidence", nargs="*",
-        help="FLEETSCALE_EVIDENCE.json (or any JSON-lines evidence "
-             "file carrying fleetscale_* rows)",
     )
     ap.add_argument(
         "--dump", action="append", default=[],
@@ -211,7 +148,7 @@ def main(argv=None) -> int:
 
     rows: List[dict] = []
     readable = 0
-    for path in list(args.evidence) + list(args.dump):
+    for path in args.dump:
         try:
             rows.extend(load_lines(path))
             readable += 1

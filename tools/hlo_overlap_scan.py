@@ -20,7 +20,7 @@ compiled module itself rather than from wall-clock timing:
   overlap with the transfer. A delayed (one-step-stale) program shows
   near-total independence: its permutes consume only a carried buffer.
 
-Used by ``BENCH_MODE=overlap`` (bench.py) and ``tests/test_overlap.py``.
+Used by ``tests/test_overlap.py`` and ``tests/test_wire_kernels.py``.
 No JAX import: pure text analysis, cheap enough to run in-process
 anywhere.
 """
